@@ -1,6 +1,5 @@
-"""Kernel + engine microbenchmarks (CPU wall-clock, interpret-mode Pallas
-noted as such: TPU timing is out of scope in this container — see
-EXPERIMENTS.md §Roofline for the TPU-side analysis)."""
+"""Kernel + engine microbenchmarks: wall-clock on whatever backend JAX
+runs (a CPU number here measures XLA's CPU backend, not the chip)."""
 from __future__ import annotations
 
 import time
@@ -26,9 +25,10 @@ def _time(fn, *args, reps=5):
 
 
 def kernel_microbench():
-    """us/call for the jnp oracle paths (the XLA-compiled reference that the
-    Pallas kernels must beat on TPU; interpret-mode Pallas timings are not
-    meaningful and are excluded)."""
+    """us/call for the jnp ops of the search path: the walk's expansion
+    (gather + full-f32 dot, the same XLA program on every platform) and the
+    filter_eval oracle the Pallas kernel must beat on a TPU
+    (interpret-mode Pallas timings are not meaningful and are excluded)."""
     rng = np.random.default_rng(0)
     n, d, Q, R = 8192, 256, 32, 128
     corpus = jnp.asarray(rng.standard_normal((n, d)), jnp.float32)
@@ -39,10 +39,8 @@ def kernel_microbench():
     fields = jnp.asarray([0, 5, -1, -1], jnp.int32)
     allowed = jnp.asarray(rng.integers(0, 2, (4, 256)), jnp.uint8)
     out = {}
-    f1 = jax.jit(lambda a, b, c: ref.masked_cosine_topk(a, b, c, K))
-    out["masked_cosine_topk_ref"] = _time(f1, queries, corpus, bitmap)
-    f2 = jax.jit(ref.fiber_expand)
-    out["fiber_expand_ref"] = _time(f2, queries, corpus, ids, bitmap)
+    f2 = jax.jit(ref.fiber_expand_walk)
+    out["fiber_expand_walk"] = _time(f2, queries, corpus, ids, bitmap)
     f3 = jax.jit(ref.filter_eval)
     out["filter_eval_ref"] = _time(f3, meta, fields, allowed)
     return out
@@ -53,8 +51,8 @@ def anchor_select_bench(batch_sizes=(16, 64, 256), reps: int = 5):
     over ``AnchorAtlas.select_anchors`` vs one batched device call to
     ``DeviceAtlas.select_anchors_batch``, at Q in ``batch_sizes``. The
     device path is reported for both seeding backends ("sort" = one
-    lexicographic lax.sort; "topk" = the masked_cosine_topk route —
-    Pallas on TPU, jnp oracle here)."""
+    lexicographic lax.sort; "topk" = one masked lax.top_k per
+    yielding-cluster slot over the shared score sweep)."""
     ds, qs, idx_alpha, _, _ = get_indexes()
     atlas = idx_alpha.atlas
     datlas = atlas.to_device()

@@ -20,9 +20,12 @@ def _csv(name: str, us: float, derived: str) -> None:
     print(f"{name},{us:.1f},{derived}")
 
 
-def kernel_oracle_parity() -> list[str]:
-    """Fixed-shape parity probes: every Pallas entrypoint (interpret mode
-    off-TPU, Mosaic on) vs its jnp oracle. Returns a list of mismatch
+def kernel_oracle_parity(n: int = 800, d: int = 64) -> list[str]:
+    """Fixed-shape parity probes at corpus width ``d``: the filter_eval
+    Pallas entry points (interpret mode on the CPU, Mosaic on a TPU) vs
+    their jnp oracles, bit for bit, and the walk's expansion op vs a
+    float64 numpy gather+dot (full f32 on every platform: a TPU's default
+    f32 matmul would round to bf16). Returns a list of mismatch
     descriptions (empty = all good)."""
     import numpy as np
     import jax.numpy as jnp
@@ -33,12 +36,12 @@ def kernel_oracle_parity() -> list[str]:
     from repro.kernels import ops, ref
 
     rng = np.random.default_rng(0)
-    n, d, q_n, r = 800, 64, 6, 24
-    corpus = jnp.asarray(rng.standard_normal((n, d)), jnp.float32)
-    queries = jnp.asarray(rng.standard_normal((q_n, d)), jnp.float32)
-    bitmap = jnp.asarray(
-        rng.integers(0, 2**32, (q_n, (n + 31) // 32), dtype=np.uint32))
-    ids = jnp.asarray(rng.integers(-1, n, (q_n, r)), jnp.int32)
+    q_n, r = 6, 24
+    corpus_np = rng.standard_normal((n, d)).astype(np.float32)
+    queries_np = rng.standard_normal((q_n, d)).astype(np.float32)
+    bitmap_np = rng.integers(0, 2**32, (q_n, (n + 31) // 32),
+                             dtype=np.uint32)
+    ids_np = rng.integers(-1, n, (q_n, r)).astype(np.int32)
     meta = jnp.asarray(rng.integers(-1, 40, (n, 6)), jnp.int32)
     preds = [FilterPredicate.make({0: [3, 4], 2: [1]}),
              FilterPredicate.make({1: list(range(10))}),
@@ -55,21 +58,24 @@ def kernel_oracle_parity() -> list[str]:
         ok = (np.array_equal(got, want) if exact
               else np.allclose(got, want, rtol=1e-4, atol=1e-4))
         if not ok:
-            fails.append(f"{name}: kernel != oracle")
+            fails.append(f"{name} (n={n}, d={d}): kernel != oracle")
 
-    s_k, _ = ops.masked_cosine_topk(queries, corpus, bitmap, k=16)
-    s_r, _ = ref.masked_cosine_topk(queries, corpus, bitmap, 16)
-    _chk("masked_cosine_topk", s_k, s_r)
-    _chk("fiber_expand", ops.fiber_expand(queries, corpus, ids, bitmap),
-         ref.fiber_expand(queries, corpus, ids, bitmap))
-    wk = ops.fiber_expand_walk(queries, corpus, ids, bitmap)
-    wr = ref.fiber_expand_walk(queries, corpus, ids, bitmap)
-    _chk("fiber_expand_walk/sims", wk[0], wr[0])
-    _chk("fiber_expand_walk/sims_pass", wk[1], wr[1])
-    _chk("filter_eval", ops.filter_eval(meta, fields1, allowed1, tn=128),
+    sims, sims_p = ref.fiber_expand_walk(
+        jnp.asarray(queries_np), jnp.asarray(corpus_np),
+        jnp.asarray(ids_np), jnp.asarray(bitmap_np))
+    safe = np.maximum(ids_np, 0)
+    want = np.einsum("qrd,qd->qr", corpus_np[safe].astype(np.float64),
+                     queries_np.astype(np.float64))
+    bits = (np.take_along_axis(bitmap_np, safe >> 5, axis=1)
+            >> (safe & 31).astype(np.uint32)) & 1
+    _chk("fiber_expand_walk/sims", sims,
+         np.where(ids_np >= 0, want, -np.inf))
+    _chk("fiber_expand_walk/sims_pass", sims_p,
+         np.where((ids_np >= 0) & (bits == 1), want, -np.inf))
+    _chk("filter_eval", ops.filter_eval(meta, fields1, allowed1),
          ref.filter_eval(meta, fields1, allowed1), exact=True)
     _chk("filter_eval_batch",
-         ops.filter_eval_batch(meta, fields_b, allowed_b, tn=128),
+         ops.filter_eval_batch(meta, fields_b, allowed_b),
          ref.filter_eval_batch(meta, fields_b, allowed_b), exact=True)
 
     # disjunction path (DESIGN.md §8): DNF clause tables through the
@@ -85,8 +91,7 @@ def kernel_oracle_parity() -> list[str]:
     f_d, a_d, b_d, nd = pack_dnf(dnfs, v_cap=64)
     b_dj = None if b_d is None else jnp.asarray(b_d)
     out_dk = np.asarray(ops.filter_eval_batch(
-        meta, jnp.asarray(f_d), jnp.asarray(a_d), jnp.asarray(nd), b_dj,
-        tn=128))
+        meta, jnp.asarray(f_d), jnp.asarray(a_d), jnp.asarray(nd), b_dj))
     _chk("filter_eval_batch/dnf", out_dk,
          ref.filter_eval_batch(meta, jnp.asarray(f_d), jnp.asarray(a_d),
                                bounds=b_dj),
@@ -114,7 +119,7 @@ def kernel_oracle_parity() -> list[str]:
     f_i, a_i, b_i, nd_i = pack_dnf(iv_dnfs, v_cap=64)
     out_ik = np.asarray(ops.filter_eval_batch(
         meta_iv, jnp.asarray(f_i), jnp.asarray(a_i), jnp.asarray(nd_i),
-        jnp.asarray(b_i), tn=128))
+        jnp.asarray(b_i)))
     _chk("filter_eval_batch/interval", out_ik,
          ref.filter_eval_batch(meta_iv, jnp.asarray(f_i), jnp.asarray(a_i),
                                bounds=jnp.asarray(b_i)),
@@ -131,48 +136,39 @@ def kernel_oracle_parity() -> list[str]:
 
 
 def parity_gate() -> None:
-    fails = kernel_oracle_parity()
+    """Parity at the CI width and at the catalogue's d=2048 (several
+    filter_eval corpus tiles), on whatever backend JAX runs."""
+    fails = kernel_oracle_parity() + kernel_oracle_parity(n=16_384, d=2048)
     if fails:
         for f in fails:
             print(f"PARITY FAIL: {f}", file=sys.stderr)
         sys.exit(1)
-    print("[parity] all kernels match their oracles")
+    print(f"[parity {device_tag()}] all kernels match their oracles "
+          f"(d=64 and d=2048)")
 
 
-_SMOKE_CRASH_SCRIPT = """
-import os, sys
-import numpy as np
-from repro.core.search import SearchParams
-from repro.core.types import Dataset
-from repro.data.synth import make_selectivity_dataset
-from repro.serve.retrieval import RetrievalService
-root = sys.argv[1]
-ds = make_selectivity_dataset((0.5, 0.1, 0.02), n=420, d=16,
-                              n_components=6, seed=7)
-base = Dataset(ds.vectors[:360], ds.metadata[:360], ds.field_names,
-               ds.vocab_sizes)
-svc = RetrievalService.build(base, graph_k=8, r_max=24,
-                             params=SearchParams(k=5, max_hops=40),
-                             capacity=420)
-svc.enable_durability(root)
-svc.ingest(ds.vectors[360:390], ds.metadata[360:390])
-os.environ["FNS_FAULT"] = "ingest.post-slab-write"  # SIGKILL at the hook
-svc.ingest(ds.vectors[390:420], ds.metadata[390:420])
-print("SURVIVED", flush=True)
-sys.exit(3)
-"""
+def device_tag() -> str:
+    """platform/device_kind/count of the backend the numbers come from."""
+    from benchmarks.search_bench import device_info
+
+    info = device_info()
+    return f"{info['backend']}/{info['device_kind']}/{info['device_count']}"
 
 
 def durability_smoke() -> None:
-    """Crash-recovery smoke (DESIGN.md §10): a subprocess SIGKILLs itself
-    at the ``ingest.post-slab-write`` fault hook; this process recovers
-    from the surviving snapshot + journal, re-runs the kernel/oracle
-    parity gate, and checks filtered search on the recovered index."""
-    import subprocess
+    """Crash-recovery smoke (DESIGN.md §10), in this one process (a chip
+    belongs to one process, so no child may need JAX): a durable service
+    crashes at the ``ingest.post-slab-write`` fault hook (armed in-process,
+    after the batch was journaled), is abandoned, and a fresh service
+    recovers from the snapshot + journal it left; the kernels still match
+    their oracles and filtered search works on the recovered index."""
     import tempfile
 
     import numpy as np
 
+    from repro import faults
+    from repro.core.search import SearchParams
+    from repro.core.types import Dataset
     from repro.data.ground_truth import attach_ground_truth, recall_at_k
     from repro.data.synth import (make_selectivity_dataset,
                                   make_selectivity_queries)
@@ -180,30 +176,40 @@ def durability_smoke() -> None:
 
     t0 = time.time()
     root = tempfile.mkdtemp(prefix="fns_smoke_crash_")
-    proc = subprocess.run([sys.executable, "-c", _SMOKE_CRASH_SCRIPT, root],
-                          capture_output=True, text=True, timeout=300)
-    assert proc.returncode == -9, (
-        f"crash script should die by SIGKILL, got rc={proc.returncode}\n"
-        f"{proc.stdout}\n{proc.stderr}")
-    assert "SURVIVED" not in proc.stdout
-    svc = RetrievalService.recover(root)
-    rows = svc.staleness()["corpus_rows"]
-    # both ingests were journaled before the kill: nothing may be lost
-    assert rows == 420, svc.staleness()
-    parity_gate()  # the kernels still match their oracles post-recovery
     ds = make_selectivity_dataset((0.5, 0.1, 0.02), n=420, d=16,
                                   n_components=6, seed=7)
+    base = Dataset(ds.vectors[:360], ds.metadata[:360], ds.field_names,
+                   ds.vocab_sizes)
+    svc = RetrievalService.build(base, graph_k=8, r_max=24,
+                                 params=SearchParams(k=5, max_hops=40),
+                                 capacity=420)
+    svc.enable_durability(root)
+    svc.ingest(ds.vectors[360:390], ds.metadata[360:390])
+    faults.arm("ingest.post-slab-write")
+    try:
+        svc.ingest(ds.vectors[390:420], ds.metadata[390:420])
+        raise AssertionError("the armed fault point did not fire")
+    except faults.InjectedFault:
+        pass
+    finally:
+        faults.disarm()
+    del svc  # the crashed service is never touched again
+    rec_svc = RetrievalService.recover(root)
+    rows = rec_svc.staleness()["corpus_rows"]
+    # both ingests were journaled before the crash: nothing may be lost
+    assert rows == 420, rec_svc.staleness()
+    parity_gate()  # the kernels still match their oracles post-recovery
     qs = make_selectivity_queries(ds, 1, 4)
     attach_ground_truth(ds, qs, k=5)
-    ids, _ = svc.query_batch(np.stack([q.vector for q in qs]),
-                             [q.predicate for q in qs])
+    ids, _ = rec_svc.query_batch(np.stack([q.vector for q in qs]),
+                                 [q.predicate for q in qs])
     rec = float(np.mean([recall_at_k(np.asarray(i), q.gt_ids)
                          for i, q in zip(ids, qs)]))
     assert rec >= 0.5, f"recovered-index recall {rec:.3f} is broken"
     _csv("durability/smoke_recover", (time.time() - t0) * 1e6,
          f"recall={rec:.3f} rows={rows}")
     print(f"[durability smoke {time.time()-t0:.0f}s] "
-          f"SIGKILL -> recover -> parity OK (recall={rec:.3f})")
+          f"crash -> recover -> parity OK (recall={rec:.3f})")
 
 
 def smoke() -> None:
@@ -339,10 +345,10 @@ def main() -> None:
 
     t0 = time.time()
     results["kernels"] = kernel_microbench()
-    print("\n== Kernel microbench (XLA-compiled oracle path, CPU) ==")
+    print(f"\n== Kernel microbench (XLA ops, {device_tag()}) ==")
     for k, us in results["kernels"].items():
         print(f"{k:28s} {us:10.1f} us/call")
-        _csv(f"kernel/{k}", us, "cpu_oracle")
+        _csv(f"kernel/{k}", us, device_tag())
     results["anchor_select"] = anchor_select_bench()
     print("\n== Anchor selection: host loop vs device batch (qps) ==")
     for name, qps in results["anchor_select"].items():
@@ -350,7 +356,7 @@ def main() -> None:
         _csv(f"anchor_select/{name}", 1e6 / qps, f"qps={qps:.0f}")
     results["engine"] = engine_bench()
     e = results["engine"]
-    print("\n== Engine: sequential vs batched (CPU measured) ==")
+    print(f"\n== Engine: sequential vs batched ({device_tag()}) ==")
     print(f"reference: {e['reference_qps']:7.1f} qps recall={e['reference_recall']:.3f}")
     print(f"batched:   {e['batched_qps']:7.1f} qps recall={e['batched_recall']:.3f}")
     _csv("engine/reference", 1e6 / e["reference_qps"],
@@ -407,6 +413,9 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if "--smoke" in sys.argv:
         smoke()
     else:

@@ -131,6 +131,16 @@ def measure_batch(eng, batch, reps: int) -> dict:
     }
 
 
+def device_info() -> dict:
+    """The backend every number of a result was measured on: platform,
+    device kind and device count (a CPU number is not a chip number)."""
+    import jax
+
+    dev = jax.devices()[0]
+    return {"backend": dev.platform, "device_kind": dev.device_kind,
+            "device_count": len(jax.devices())}
+
+
 def search_bench(batch_sizes=BATCH_SIZES, selectivities=SELECTIVITIES, *,
                  n: int = 8000, d: int = 64, k: int = 10, reps: int = 20,
                  graph_k: int = 16, seed: int = 7,
@@ -153,7 +163,7 @@ def search_bench(batch_sizes=BATCH_SIZES, selectivities=SELECTIVITIES, *,
     if not key_prefix:
         out["config"] = {"n": n, "d": d, "k": k, "reps": reps,
                          "graph_k": cfg.graph.graph_k,
-                         "backend": __import__("jax").default_backend(),
+                         **device_info(),
                          "fingerprint": cfg.fingerprint(),
                          "knobs": cfg.flatten()}
     pools = make_query_pools(ds, selectivities, max(batch_sizes), k)
@@ -729,6 +739,10 @@ def main(smoke: bool = False) -> dict:
 
 if __name__ == "__main__":
     import sys
+
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     res = main(smoke="--smoke" in sys.argv)
     for name, r in res.items():
         if name == "config":

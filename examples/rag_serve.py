@@ -19,6 +19,7 @@ from repro.configs import get_config, reduced_config
 from repro.core.search import SearchParams
 from repro.core.types import Dataset, FilterPredicate
 from repro.data.ground_truth import filtered_topk, recall_at_k
+from repro.launch.mesh import make_mesh
 from repro.models.transformer import ShardEnv, encode, init_params
 from repro.serve.retrieval import EncodedRetriever, RetrievalService
 
@@ -29,7 +30,7 @@ ap.add_argument("--queries", type=int, default=32)
 args = ap.parse_args()
 
 cfg = get_config("smollm-135m") if args.full else reduced_config("smollm-135m")
-env = ShardEnv(jax.make_mesh((1, 1), ("data", "model")))
+env = ShardEnv(make_mesh((1, 1), ("data", "model")))
 params = init_params(cfg, jax.random.PRNGKey(0))
 enc = jax.jit(lambda p, b: encode(p, b, cfg, env))
 rng = np.random.default_rng(0)

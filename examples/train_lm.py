@@ -13,6 +13,7 @@ import jax
 
 from repro.configs import get_config, reduced_config
 from repro.data.tokens import TokenPipeline
+from repro.launch.mesh import make_mesh
 from repro.models.transformer import ShardEnv, init_params
 from repro.optim.adamw import AdamWConfig, init_opt_state, make_train_step
 from repro.train.loop import LoopConfig, TrainLoop
@@ -27,7 +28,7 @@ ap.add_argument("--ckpt-dir", default="/tmp/repro_train_ckpt")
 args = ap.parse_args()
 
 cfg = get_config(args.arch) if args.full else reduced_config(args.arch)
-mesh = jax.make_mesh((1, 1), ("data", "model"))
+mesh = make_mesh((1, 1), ("data", "model"))
 env = ShardEnv(mesh)
 params = init_params(cfg, jax.random.PRNGKey(0))
 n_params = sum(p.size for p in jax.tree.leaves(params))

@@ -152,8 +152,8 @@ class AnchorAtlas:
 
         When ``vectors`` is given, seeds are the NEAREST matching members of
         each yielding cluster (the paper's in-cluster brute-force cosine,
-        §4.3 — "negligible" cost, and what masked_cosine_topk accelerates on
-        TPU); otherwise a deterministic random sample.
+        §4.3 — "negligible" cost; the device atlas batches it as masked
+        top-k passes); otherwise a deterministic random sample.
 
         Returns (seed point ids, cluster ids consumed this round).
         """

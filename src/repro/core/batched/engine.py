@@ -4,8 +4,8 @@ Runs Q queries in lockstep as one ``lax.while_loop``: all walk state is
 fixed-shape (packed uint32 visited/in-results/pass bitmaps, V-sorted
 fixed-capacity frontier/beam queues, running top-k results), one iteration
 expands one node per active query, and every expansion distance comes from
-the ``fiber_expand_walk`` Pallas kernel on TPU (the jnp oracle elsewhere),
-which applies the packed pass bitmap in-kernel.
+one neighbour gather + full-f32 dot that also probes the packed pass bitmap
+(``ref.fiber_expand_walk``, the same XLA program on every platform).
 
 A whole filtered search batch is ONE device dispatch (``search_batch``):
 predicate evaluation (batched ``filter_eval``), the restart round loop
@@ -43,9 +43,11 @@ from repro.core.predicate import DNF, as_dnf, disjunct_selectivity
 from repro.core.search import FiberIndex, SearchParams
 from repro.core.types import FilterPredicate, Query
 from repro.kernels import ref
-from repro.kernels.ops import MAX_CLAUSES
+from repro.kernels.filter_eval import filter_eval_batch
+from repro.kernels.ops import MAX_CLAUSES, on_tpu
 
 INF = jnp.float32(3.4e38)
+HIGHEST = jax.lax.Precision.HIGHEST
 
 TERM_RUNNING, TERM_CONVERGED, TERM_EARLY, TERM_STALL, TERM_MAXHOP = 0, 1, 2, 3, 4
 
@@ -70,30 +72,19 @@ def _pop(q_v, q_i):
     return x_v, x_i, q_v, q_i
 
 
-def _expand_scores(q_vecs, vectors, nbrs, pass_bm):
-    """Neighbour gather + dot with the pass bitmap applied in the same pass:
-    the fiber_expand_walk Pallas kernel on TPU, the jnp oracle elsewhere
-    (DESIGN.md §3). Returns (sims, sims_pass), -inf masked."""
-    if jax.default_backend() == "tpu":
-        from repro.kernels.fiber_expand import fiber_expand_walk
-        return fiber_expand_walk(q_vecs, vectors, nbrs, pass_bm,
-                                 interpret=False)
-    return ref.fiber_expand_walk(q_vecs, vectors, nbrs, pass_bm)
-
-
 def _eval_passes(metadata, fields, allowed, bounds=None,
                  kcfg: KernelConfig | None = None):
     """Batched predicate evaluation -> packed (Q, ceil(n/32)) uint32 pass
-    bitmaps: the filter_eval Pallas corpus sweep on TPU, the jnp oracle
-    elsewhere. Disjunctive (Q, D, C) tables carry their live-disjunct
-    counts in the dead-disjunct sentinel; the kernels OR the per-disjunct
+    bitmaps: the filter_eval Pallas corpus sweep on a TPU, the jnp oracle
+    on the CPU (``ops.on_tpu`` refuses any other platform). Disjunctive
+    (Q, D, C) tables carry their live-disjunct counts in the
+    dead-disjunct sentinel; the kernels OR the per-disjunct
     conjunctive bitmaps in the same sweep (DESIGN.md §8). ``bounds``
     (Q, D, C, 2) marks interval clauses (evaluated as two comparisons,
     short-circuited rarest-first; None keeps legacy programs). ``kcfg``
     sizes the kernel's corpus tile (CPU oracle has no tiles)."""
     n_disj = table_n_disj(fields) if fields.ndim == 3 else None
-    if jax.default_backend() == "tpu":
-        from repro.kernels.filter_eval import filter_eval_batch
+    if on_tpu():
         tn = (kcfg or KernelConfig()).filter_tile
         return filter_eval_batch(metadata, fields, allowed, n_disj, bounds,
                                  tn=tn, interpret=False)
@@ -117,7 +108,8 @@ def walk_batch(vectors, adjacency, pass_bm, q_vecs, seeds,
 
     safe_seeds = jnp.maximum(seeds, 0)
     seed_valid = seeds >= 0
-    seed_sims = jnp.einsum("qsd,qd->qs", vectors[safe_seeds], q_vecs)
+    seed_sims = jnp.einsum("qsd,qd->qs", vectors[safe_seeds], q_vecs,
+                           precision=HIGHEST)
     seed_v = jnp.where(seed_valid, 1.0 - seed_sims, INF)
 
     visited = set_bits(jnp.zeros((Q, n_words(n)), jnp.uint32),
@@ -194,7 +186,7 @@ def walk_batch(vectors, adjacency, pass_bm, q_vecs, seeds,
         visited = set_bits(s["visited"], sn, new)
         # one gather+dot yields both traversal distances and pass-masked
         # candidates (the kernel probes the pass bitmap in the same pass)
-        sims, sims_p = _expand_scores(q_vecs, vectors, nbrs, pass_bm)
+        sims, sims_p = ref.fiber_expand_walk(q_vecs, vectors, nbrs, pass_bm)
         v_n = 1.0 - sims
         pass_r = jnp.isfinite(sims_p) & live[:, None]
         # results: merge new filtered, minus nodes a prior round already
@@ -205,7 +197,8 @@ def walk_batch(vectors, adjacency, pass_bm, q_vecs, seeds,
         res_v, res_i = _merge_queue(s["res_v"], s["res_i"], cand_v, nbrs, k)
         # local signals
         n_pass = pass_r.sum(1)
-        vx = 1.0 - jnp.einsum("qd,qd->q", vectors[xs], q_vecs)
+        vx = 1.0 - jnp.einsum("qd,qd->q", vectors[xs], q_vecs,
+                              precision=HIGHEST)
         drift = jnp.where(
             n_pass > 0,
             (jnp.where(pass_r, v_n, 0.0).sum(1) / jnp.maximum(n_pass, 1)) - vx,
@@ -285,7 +278,7 @@ def atlas_round(datlas: DeviceAtlas, vectors, adjacency, pass_bm, passes,
     seeds, used = datlas.select_anchors_batch(
         q_vecs, tables, gate, vectors, passes,
         n_seeds=p.n_seeds, c_max=p.c_max, backend=seed_backend,
-        disjunct_quota=p.disjunct_quota, kcfg=kcfg)
+        disjunct_quota=p.disjunct_quota)
     out = walk_batch(vectors, adjacency, pass_bm, q_vecs, seeds, p,
                      init_results=(res_v, res_i))
     found = (out["res_v"] < INF / 2).sum(axis=1)
@@ -468,7 +461,7 @@ class BatchedEngine:
     fetch results; ``dispatches`` counts compiled-callable invocations so
     tests can assert that. ``search_hostloop`` keeps the PR 1 host-driven
     round loop (one jitted ``atlas_round`` per round) as the parity and
-    migration baseline. On non-CPU backends the per-round state buffers
+    migration baseline. On the TPU the per-round state buffers
     (processed/need/res_v/res_i) are donated into the round call.
 
     ``serve.capacity`` (DESIGN.md §9) turns the device index into an
@@ -632,15 +625,15 @@ class BatchedEngine:
     def _init_programs(self, seed_backend: str) -> None:
         params = self.p
         kcfg = self.cfg.kernel
-        on_cpu = jax.default_backend() == "cpu"  # donation unsupported there
+        # the CPU backend ignores donation; search_batch's inputs have no
+        # same-shaped output to alias, so only the round state is donated
         self._round = jax.jit(
             functools.partial(atlas_round, p=params,
                               seed_backend=seed_backend, kcfg=kcfg),
-            donate_argnums=() if on_cpu else (8, 9, 10, 11))
+            donate_argnums=(8, 9, 10, 11) if on_tpu() else ())
         self._search = jax.jit(
             functools.partial(search_batch, p=params,
-                              seed_backend=seed_backend, kcfg=kcfg),
-            donate_argnums=() if on_cpu else (4, 5, 6))
+                              seed_backend=seed_backend, kcfg=kcfg))
         self._passes = jax.jit(functools.partial(_eval_passes, kcfg=kcfg))
         self.dispatches = 0
         self.publish_generation = getattr(self, "publish_generation", 0)

@@ -355,10 +355,8 @@ def insert_rows(state: InsertState, vectors: np.ndarray,
     if metadata.shape[1] != f_count:
         raise ValueError(f"insert metadata has {metadata.shape[1]} fields, "
                          f"index has {f_count}")
-    if metadata.max(initial=-1) >= state.v_cap:
-        raise ValueError(
-            f"insert metadata code {int(metadata.max())} out of the atlas "
-            f"value range [0, {state.v_cap}); rebuild with a larger v_cap")
+    # codes >= v_cap are legal: like the build, emit_device_atlas keeps them
+    # out of the presence bitmaps and serves them through the envelopes
     b = vectors.shape[0]
     if gids is None:
         gids = (state.next_gid + np.arange(b)).astype(np.int32)

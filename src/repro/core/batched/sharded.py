@@ -47,7 +47,6 @@ from repro.core.predicate import FilterExpr, derived_vocab_sizes
 from repro.core.types import Dataset, Query
 from repro.launch.mesh import index_axis_size, query_axis_name
 from repro.launch.shardings import index_shardings
-from repro.models.common import shard_map
 
 
 @dataclasses.dataclass
@@ -327,7 +326,7 @@ class ShardedEngine:
         in_specs = tuple([P(axis)] * (nl + 5) + [q_spec] * n_q)
         out_specs = dict(res_v=q_spec, res_i=q_spec,
                          hops=q_spec, walks=q_spec)
-        return jax.jit(shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+        return jax.jit(jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
                                  out_specs=out_specs, check_vma=False))
 
     def insert_batch(self, vectors: np.ndarray, metadata: np.ndarray, *,
@@ -523,17 +522,22 @@ class ShardedEngine:
         """Shard-at-a-time device program behind both the reference-mode
         ``dispatch`` and the ``search_reference`` oracle: the identical
         per-shard fused programs + the identical merge, no host sync."""
+        # every shard's slice moves to the default device: a program over
+        # mesh-sharded inputs would be partitioned, and a Pallas kernel
+        # cannot be partitioned automatically
+        local = functools.partial(jax.device_put, device=jax.devices()[0])
         per_v, per_i, hops, walks = [], [], 0, 0
         for s in range(self.n_shards):
             datlas = jax.tree_util.tree_unflatten(
-                self._tdef, [l[s] for l in self._leaves])
-            out = self._ref(datlas, self.vectors[s], self.adjacency[s],
-                            self.metadata[s], self.valid_bm[s],
+                self._tdef, [local(l[s]) for l in self._leaves])
+            out = self._ref(datlas, local(self.vectors[s]),
+                            local(self.adjacency[s]), local(self.metadata[s]),
+                            local(self.valid_bm[s]),
                             q_vecs, fields, allowed, bounds)
             per_v.append(out["res_v"])
             per_i.append(jnp.where(
                 out["res_i"] >= 0,
-                self.global_ids[s][jnp.maximum(out["res_i"], 0)], -1))
+                local(self.global_ids[s])[jnp.maximum(out["res_i"], 0)], -1))
             hops = hops + out["hops"]
             walks = walks + out["walks"]
         res_v, res_i = merge_topk(jnp.stack(per_v), jnp.stack(per_i),

@@ -100,16 +100,15 @@ class WalkConfig:
 
 @dataclasses.dataclass(frozen=True)
 class KernelConfig:
-    """Pallas kernel tile sizes and clause-table caps. Tile sizes are
+    """Pallas kernel tile size and clause-table caps. The tile size is
     validated against shapes at trace time (``filter_tile`` must be a
-    multiple of 32 for the bitmap pack; ``topk_nt`` likewise for the
-    in-kernel word unpack); the caps bucket compiled program shapes."""
+    multiple of 32 for the bitmap pack, and of 4096 = 32 rows x 128 lanes
+    when the corpus spans several tiles); the caps bucket compiled program
+    shapes."""
 
-    filter_tile: int = 1024    # filter_eval corpus-tile rows (was tn=1024)
+    filter_tile: int = 8192    # filter_eval corpus-tile rows
     max_clauses: int = 4       # clause-dim bucket floor (was MAX_CLAUSES)
     max_disjuncts: int = 8     # DNF compile cap (was predicate.MAX_DISJUNCTS)
-    topk_qt: int = 8           # masked_cosine_topk query tile
-    topk_nt: int = 512         # masked_cosine_topk corpus tile
 
 
 @dataclasses.dataclass(frozen=True)

@@ -19,9 +19,10 @@ device arrays so one jitted call selects anchors for all Q queries:
 ``select_anchors_batch`` reproduces ``AnchorAtlas.select_anchors`` exactly
 (same seed sets, same consumed clusters) for every query in the batch; the
 in-cluster nearest-matching-member scan runs either as one lexicographic
-``lax.sort`` over (cluster rank, cosine distance) ["sort" backend] or
-through the ``masked_cosine_topk`` kernel — Pallas on TPU, the jnp oracle
-on CPU — one call per yielding-cluster slot ["topk" backend].
+``lax.sort`` over (cluster rank, cosine distance) ["sort" backend] or as
+one masked ``lax.top_k`` over the shared score sweep per yielding-cluster
+slot ["topk" backend]. Scores are full-f32 matmuls on every platform (a
+TPU's default f32 matmul rounds operands to bf16).
 """
 from __future__ import annotations
 
@@ -33,15 +34,16 @@ import numpy as np
 
 from repro.core.batched.bitmap import pack_bits
 from repro.core.batched.bitmap import n_words as _n_words
-from repro.core.config import AtlasConfig, KernelConfig
+from repro.core.config import AtlasConfig
 from repro.core.predicate import Interval
 # sentinel + device-side count derivation live with the kernels that
 # consume the tables; re-exported here next to the packers that emit them
 from repro.kernels.filter_eval import DEAD_DISJUNCT, table_n_disj
-from repro.kernels.ops import V_CAP
 
 NEG = jnp.float32(-3.4e38)
+HIGHEST = jax.lax.Precision.HIGHEST
 _ACFG = AtlasConfig()
+V_CAP = _ACFG.v_cap_min
 # mirrors AnchorAtlas.cluster_members_matching's cap
 MEMBER_CAP = _ACFG.member_cap
 
@@ -325,7 +327,6 @@ class DeviceAtlas:
         processed: jax.Array, vectors: jax.Array, passes: jax.Array, *,
         n_seeds: int = 10, c_max: int = 5, member_cap: int = MEMBER_CAP,
         backend: str = "sort", disjunct_quota: int = 2,
-        kcfg: KernelConfig | None = None,
     ) -> tuple[jax.Array, jax.Array]:
         """One anchor-selection round for Q queries (Alg. 2 lines 3–14,
         batched). Exact host semantics: rank matching unprocessed clusters
@@ -369,7 +370,8 @@ class DeviceAtlas:
         match = (dmasks.any(axis=1) if dmasks is not None
                  else self.matching_clusters_batch(fields, allowed))
         avail = match & ~processed
-        scores = q_vecs @ self.centroids.T                    # (Q, K)
+        scores = jnp.matmul(q_vecs, self.centroids.T,
+                            precision=HIGHEST)                # (Q, K)
         order = jnp.argsort(-jnp.where(avail, scores, NEG), axis=1)
 
         cnt, rank_id = self._matched_counts(passes)
@@ -387,30 +389,25 @@ class DeviceAtlas:
 
         elig = passes & used[:, self.assign] & (rank_id < member_cap)
         # one dense (Q, n) score sweep shared by the seed backends and the
-        # disjunct-quota repair; the TPU topk backend replaces it with
-        # per-slot Pallas calls and skips the dense form entirely
-        on_tpu = jax.default_backend() == "tpu"
-        sims = (None if backend == "topk" and on_tpu
-                else jnp.einsum("qd,nd->qn", q_vecs, vectors))
+        # disjunct-quota repair
+        sims = jnp.einsum("qd,nd->qn", q_vecs, vectors, precision=HIGHEST)
         if backend == "sort":
             seeds = self._seed_by_sort(sims, elig, order, n_seeds)
         elif backend == "topk":
-            seeds = self._seed_by_topk(q_vecs, vectors, sims, elig, order,
-                                       cnt_r, visited_r, yld_r, n_seeds,
-                                       c_max, kcfg=kcfg)
+            seeds = self._seed_by_topk(sims, elig, order, cnt_r, visited_r,
+                                       yld_r, n_seeds, c_max)
         else:
             raise ValueError(f"unknown seed backend {backend!r}")
         if dmasks is not None and disjunct_quota > 0:
             seeds, used = self._apply_disjunct_quota(
-                q_vecs, dmasks, processed, vectors, sims, passes,
+                dmasks, processed, sims, passes,
                 rank_id, scores, used, seeds,
                 n_seeds=n_seeds, member_cap=member_cap,
                 quota=min(disjunct_quota, n_seeds))
         return seeds, used
 
-    def _apply_disjunct_quota(self, q_vecs, dmasks, processed,
-                              vectors, sims, passes, rank_id, scores, used,
-                              seeds,
+    def _apply_disjunct_quota(self, dmasks, processed, sims, passes,
+                              rank_id, scores, used, seeds,
                               *, n_seeds: int, member_cap: int, quota: int):
         """Starved-disjunct repair: force-visit each starved live
         disjunct's best available cluster and splice up to ``quota`` of its
@@ -424,7 +421,7 @@ class DeviceAtlas:
         but row-level per-disjunct seeding is a possible refinement
         (ROADMAP)."""
         q_n, k = used.shape
-        n = vectors.shape[0]
+        n = sims.shape[1]
         d_tab = dmasks.shape[1]
         dmask = dmasks & ~processed[:, None, :]             # (Q, D, K)
         best_c = jnp.argmax(jnp.where(dmask, scores[:, None, :], NEG),
@@ -435,8 +432,6 @@ class DeviceAtlas:
                        ).any(axis=1)
 
         def with_quota():
-            s = (sims if sims is not None
-                 else jnp.einsum("qd,nd->qn", q_vecs, vectors))
             big = jnp.int32(d_tab * quota + n_seeds)
             pos = jnp.arange(quota, dtype=jnp.int32)[None, :]
             q_ids, q_keys = [], []
@@ -444,7 +439,7 @@ class DeviceAtlas:
                 m = (passes & starved[:, dj, None]
                      & (self.assign[None, :] == best_c[:, dj, None])
                      & (rank_id < member_cap))
-                s_j, ids_j = jax.lax.top_k(jnp.where(m, s, -jnp.inf),
+                s_j, ids_j = jax.lax.top_k(jnp.where(m, sims, -jnp.inf),
                                            quota)
                 ok = jnp.isfinite(s_j)
                 q_ids.append(jnp.where(ok, ids_j.astype(jnp.int32), -1))
@@ -489,17 +484,12 @@ class DeviceAtlas:
         k1s, _, ids = jax.lax.sort((key1, key2, pid), num_keys=2)
         return jnp.where(k1s[:, :n_seeds] < k, ids[:, :n_seeds], -1)
 
-    def _seed_by_topk(self, q_vecs, vectors, sims, elig, order, cnt_r,
-                      visited_r, yld_r, n_seeds: int, c_max: int,
-                      kcfg: KernelConfig | None = None):
-        """Quota fill via masked cosine top-k: one top-k per
-        yielding-cluster slot (≤ c_max) over the corpus with the filter
-        bitmap restricted to that slot's cluster. On TPU each slot is a
-        ``masked_cosine_topk`` Pallas call (``sims`` is None); elsewhere
-        the slots share the caller's dense score matmul (the ref-oracle
-        math with the Q·n·d sweep amortized across slots)."""
-        q_n = q_vecs.shape[0]
-        on_tpu = sims is None
+    def _seed_by_topk(self, sims, elig, order, cnt_r, visited_r, yld_r,
+                      n_seeds: int, c_max: int):
+        """Quota fill via masked top-k: one ``lax.top_k`` per
+        yielding-cluster slot (≤ c_max) over the shared score sweep with
+        the eligibility mask restricted to that slot's cluster."""
+        q_n = sims.shape[0]
         # slot j (yield order) -> cluster id and its matched count
         slot_pos = jnp.where(visited_r & (yld_r > 0), _excl_cumsum(yld_r),
                              c_max)
@@ -513,18 +503,9 @@ class DeviceAtlas:
         pos = jnp.arange(n_seeds, dtype=jnp.int32)[None, :]
         for j in range(c_max):
             mask = elig & (self.assign[None, :] == slot_cluster[:, j, None])
-            if on_tpu:
-                from repro.kernels.masked_cosine_topk import \
-                    masked_cosine_topk
-                kc = kcfg or KernelConfig()
-                _, ids_j = masked_cosine_topk(q_vecs, vectors,
-                                              pack_bitmap(mask), k=n_seeds,
-                                              qt=kc.topk_qt, nt=kc.topk_nt,
-                                              interpret=False)
-            else:
-                s_j, ids_j = jax.lax.top_k(
-                    jnp.where(mask, sims, -jnp.inf), n_seeds)
-                ids_j = jnp.where(jnp.isfinite(s_j), ids_j, -1)
+            s_j, ids_j = jax.lax.top_k(
+                jnp.where(mask, sims, -jnp.inf), n_seeds)
+            ids_j = jnp.where(jnp.isfinite(s_j), ids_j, -1)
             keep = pos < take[:, j, None]
             all_keys.append(jnp.where(keep, j * n_seeds + pos,
                                       jnp.int32(c_max * n_seeds)))

@@ -20,6 +20,24 @@ def filtered_topk(vectors: np.ndarray, q: np.ndarray, passes: np.ndarray,
     return ids[sel], sims[sel].astype(np.float32)
 
 
+def filtered_topk_shared(vectors: np.ndarray, qs: np.ndarray,
+                         passes: np.ndarray, k: int) -> list[np.ndarray]:
+    """``filtered_topk`` ids for a batch of queries ``qs`` (Q, d) that share
+    one pass mask: the passing rows are gathered and scored once, as one
+    matmul, instead of once per query."""
+    ids = np.nonzero(passes)[0]
+    if ids.size == 0:
+        return [np.empty(0, np.int64) for _ in range(len(qs))]
+    sims = vectors[ids] @ np.asarray(qs, np.float32).T          # (m, Q)
+    k = min(k, ids.size)
+    out = []
+    for j in range(sims.shape[1]):
+        col = sims[:, j]
+        sel = np.argpartition(-col, k - 1)[:k]
+        out.append(ids[sel[np.argsort(-col[sel])]])
+    return out
+
+
 def attach_ground_truth(ds: Dataset, queries: list[Query], k: int = 25,
                         block: int = 4096) -> None:
     """Compute exact filtered top-k for each query in place. The pass mask

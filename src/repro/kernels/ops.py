@@ -1,23 +1,21 @@
-"""Jit'd public wrappers for the Pallas kernels.
+"""Jit'd public wrappers for the Pallas kernels, and the one platform choice.
 
-On CPU the kernels execute in interpret mode (the kernel body runs under the
-Pallas interpreter — bit-exact semantics, no Mosaic); on TPU they lower to
-Mosaic. ``predicate_tables`` converts a core FilterPredicate into the dense
-clause tables the filter_eval kernel consumes.
+The search path runs Pallas kernels on a TPU and their jnp oracles
+(``kernels/ref.py``) on the CPU; ``on_tpu`` is the single place that
+decides, and it refuses any other platform rather than silently running a
+CPU formulation there. On the CPU these wrappers execute the kernel body
+under the Pallas interpreter (bit-exact semantics, no Mosaic) — the form
+the tests check against the oracles. ``predicate_tables`` converts a core
+FilterPredicate into the dense clause tables ``filter_eval`` consumes.
 """
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.core.config import AtlasConfig, KernelConfig
-from repro.kernels.fiber_expand import fiber_expand as _fiber_expand
-from repro.kernels.fiber_expand import fiber_expand_walk as _fiber_expand_walk
 from repro.kernels.filter_eval import filter_eval as _filter_eval
 from repro.kernels.filter_eval import filter_eval_batch as _filter_eval_batch
-from repro.kernels.masked_cosine_topk import \
-    masked_cosine_topk as _masked_cosine_topk
 
 # legacy module-level names, derived from the one config origin
 # (core/config.py); kept as importable aliases for existing callers
@@ -26,35 +24,28 @@ MAX_CLAUSES = _KCFG.max_clauses
 V_CAP = AtlasConfig().v_cap_min
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-def masked_cosine_topk(queries, corpus, bitmap, *, k: int = 32,
-                       qt: int = _KCFG.topk_qt, nt: int = _KCFG.topk_nt):
-    return _masked_cosine_topk(queries, corpus, bitmap, k=k, qt=qt, nt=nt,
-                               interpret=_interpret())
-
-
-def fiber_expand(q_vecs, corpus, ids, bitmap):
-    return _fiber_expand(q_vecs, corpus, ids, bitmap,
-                         interpret=_interpret())
-
-
-def fiber_expand_walk(q_vecs, corpus, ids, bitmap):
-    return _fiber_expand_walk(q_vecs, corpus, ids, bitmap,
-                              interpret=_interpret())
+def on_tpu() -> bool:
+    """True on a TPU backend (Pallas kernels lower to Mosaic), False on
+    the CPU (interpret mode / jnp oracles). Any other platform is an
+    error: no CPU formulation may stand in for a device it was not
+    written for."""
+    platform = jax.default_backend()
+    if platform not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"unsupported JAX platform {platform!r}: the search path runs "
+            f"on 'tpu' (Pallas kernels) or 'cpu' (tests, interpret mode)")
+    return platform == "tpu"
 
 
 def filter_eval(metadata, fields, allowed, *, tn: int = _KCFG.filter_tile):
     return _filter_eval(metadata, fields, allowed, tn=tn,
-                        interpret=_interpret())
+                        interpret=not on_tpu())
 
 
 def filter_eval_batch(metadata, fields, allowed, n_disj=None, bounds=None, *,
                       tn: int = _KCFG.filter_tile):
     return _filter_eval_batch(metadata, fields, allowed, n_disj, bounds,
-                              tn=tn, interpret=_interpret())
+                              tn=tn, interpret=not on_tpu())
 
 
 def predicate_tables(pred, n_fields: int,

@@ -1,14 +1,12 @@
-"""Pure-jnp oracles for every kernel in this package (the allclose targets).
+"""Pure-jnp oracles for the kernels in this package, and the jnp ops the
+search path runs on every platform.
 
-Semantics contract shared by kernel and oracle:
-* masked_cosine_topk: scores = Q @ X^T; positions whose filter bit is 0 (or
-  column >= n) score -inf; per-query top-k (sims desc, ids).
-* fiber_expand: sims[q, r] = q_vec[q] . X[ids[q, r]] when id >= 0 AND the
-  id's filter bit is set, else -inf.
-* fiber_expand_walk: same gather+dot but TWO outputs per (q, r) — sims
-  masked only by id validity (the walk's traversal distances) and sims
-  additionally masked by the filter bit (the result-queue candidates) —
-  so the hot loop never loads a separate bool pass mask.
+Semantics contract:
+* fiber_expand_walk (the walk's expansion op; no kernel — the same XLA
+  gather + full-f32 dot on every platform): sims[q, r] = q_vec[q] .
+  X[ids[q, r]], TWO outputs per (q, r) — sims masked only by id validity
+  (the walk's traversal distances) and sims additionally masked by the
+  filter bit (the result-queue candidates).
 * filter_eval: packed uint32 bitmap of conjunctive predicate over int codes;
   code -1 (unpopulated) fails any clause on that field.
 * filter_eval_batch: filter_eval for Q queries at once, consuming the
@@ -35,36 +33,6 @@ def bitmap_get(bitmap: jax.Array, idx: jax.Array) -> jax.Array:
     return ((word >> (idx & 31).astype(jnp.uint32)) & 1).astype(bool)
 
 
-def masked_cosine_topk(queries: jax.Array, corpus: jax.Array,
-                       bitmap: jax.Array, k: int):
-    """queries (Q, d); corpus (n, d); bitmap (Q, ceil(n/32)) uint32.
-
-    Returns (sims (Q, k) f32 desc, ids (Q, k) i32; -inf/-1 where fewer than
-    k pass)."""
-    n = corpus.shape[0]
-    scores = (queries.astype(jnp.float32) @ corpus.astype(jnp.float32).T)
-    cols = jnp.arange(n, dtype=jnp.int32)
-    words = bitmap[:, cols >> 5]
-    bits = ((words >> (cols & 31).astype(jnp.uint32)) & 1).astype(bool)
-    scores = jnp.where(bits, scores, NEG)
-    sims, ids = jax.lax.top_k(scores, k)
-    ids = jnp.where(jnp.isfinite(sims), ids, -1).astype(jnp.int32)
-    return sims, ids
-
-
-def fiber_expand(q_vecs: jax.Array, corpus: jax.Array, ids: jax.Array,
-                 bitmap: jax.Array):
-    """q_vecs (Q, d); corpus (n, d); ids (Q, R) i32 (-1 pad);
-    bitmap (Q, n_words) uint32. Returns sims (Q, R) f32 (-inf masked)."""
-    safe = jnp.maximum(ids, 0)
-    rows = corpus[safe].astype(jnp.float32)            # (Q, R, d)
-    sims = jnp.einsum("qrd,qd->qr", rows, q_vecs.astype(jnp.float32))
-    words = jnp.take_along_axis(bitmap, (safe >> 5).astype(jnp.int32), axis=1)
-    bits = ((words >> (safe & 31).astype(jnp.uint32)) & 1).astype(bool)
-    ok = (ids >= 0) & bits
-    return jnp.where(ok, sims, NEG)
-
-
 def fiber_expand_walk(q_vecs: jax.Array, corpus: jax.Array, ids: jax.Array,
                       bitmap: jax.Array):
     """q_vecs (Q, d); corpus (n, d); ids (Q, R) i32 (-1 pad);
@@ -73,7 +41,8 @@ def fiber_expand_walk(q_vecs: jax.Array, corpus: jax.Array, ids: jax.Array,
     where the id's filter bit is 0."""
     safe = jnp.maximum(ids, 0)
     rows = corpus[safe].astype(jnp.float32)            # (Q, R, d)
-    sims = jnp.einsum("qrd,qd->qr", rows, q_vecs.astype(jnp.float32))
+    sims = jnp.einsum("qrd,qd->qr", rows, q_vecs.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST)
     words = jnp.take_along_axis(bitmap, (safe >> 5).astype(jnp.int32), axis=1)
     bits = ((words >> (safe & 31).astype(jnp.uint32)) & 1).astype(bool)
     valid = ids >= 0

@@ -8,13 +8,23 @@ from __future__ import annotations
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, *, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``: the model and search code
+    shards through ``with_sharding_constraint``/``shard_map`` specs, which
+    ``jax.make_mesh``'s default ``Explicit`` axes refuse."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single pod (256 chips) or 2x16x16 multi-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh(data: int = 1, model: int = 1):

@@ -129,8 +129,6 @@ def flash_decode_sharded(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.models.common import shard_map
-
     n_shards = mesh.shape[seq_axis]
     S = k_cache.shape[1]
     S_loc = S // n_shards
@@ -161,7 +159,7 @@ def flash_decode_sharded(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
         out = pv_g / jnp.maximum(l_g, 1e-30)[..., None]
         return out.reshape(B, 1, H, hd).astype(qb.dtype)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(), P(None, seq_axis, None, None),
                   P(None, seq_axis, None, None), P()),

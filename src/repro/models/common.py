@@ -7,35 +7,11 @@ Conventions:
 """
 from __future__ import annotations
 
-import inspect
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 CDT = jnp.bfloat16  # compute dtype
-
-try:  # public API (jax >= 0.4.35-ish); experimental module before that
-    from jax import shard_map as _shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-# the replication-check kwarg was renamed check_rep -> check_vma
-# independently of the public-API move, so pick it by signature
-_CHECK_KW = next((k for k in ("check_vma", "check_rep")
-                  if k in inspect.signature(_shard_map).parameters), None)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-    """Version-compat shard_map: new-API keyword names, any jax."""
-    kw = {_CHECK_KW: check_vma} if _CHECK_KW else {}
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
-
-
-def use_mesh(mesh):
-    """Ambient-mesh context: jax.set_mesh on jax >= 0.6; older jax Mesh
-    objects are their own context manager."""
-    return jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
 
 
 def cast(x):

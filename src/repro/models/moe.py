@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.models.common import CDT, shard_map
+from repro.models.common import CDT
 
 
 @dataclasses.dataclass(frozen=True)
@@ -146,7 +146,7 @@ def _moe_a2a(x, params, dims, mesh, model_axis, data_axes):
 
     x_sh = jax.lax.with_sharding_constraint(
         x, jax.sharding.NamedSharding(mesh, in_spec))
-    y = shard_map(
+    y = jax.shard_map(
         local, mesh=mesh,
         in_specs=(in_spec, P(), P(model_axis, None, None),
                   P(model_axis, None, None), P(model_axis, None, None)),
@@ -185,7 +185,7 @@ def _moe_replicated(x, params, dims, mesh, model_axis, data_axes):
         y = jax.lax.psum(y, model_axis)
         return y.reshape(xb.shape).astype(xb.dtype)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(data_spec, P(), P(model_axis, None, None),
                   P(model_axis, None, None), P(model_axis, None, None)),

@@ -393,11 +393,20 @@ class RetrievalService:
             raise ValueError(
                 f"ingest metadata has {metadata.shape[1]} fields, the "
                 f"index declares {f_count}")
-        if metadata.size and int(metadata.max()) >= st.v_cap:
+        # a field's codes may reach its declared vocabulary (a large-domain
+        # field such as a timestamp serves codes >= v_cap through interval
+        # clauses and the atlas envelopes), else the value-bitmap width
+        limit = np.full(f_count, st.v_cap, np.int64)
+        if eng.vocab_sizes is not None:
+            limit = np.maximum(limit, np.asarray(eng.vocab_sizes, np.int64))
+        over = metadata >= limit[None, :]
+        if over.any():
+            f = int(np.nonzero(over.any(axis=0))[0][0])
             raise ValueError(
-                f"ingest metadata code {int(metadata.max())} is outside "
-                f"the declared vocab domain [0, {st.v_cap}); rebuild with "
-                f"a larger v_cap to serve it")
+                f"ingest metadata code {int(metadata[:, f].max())} in field "
+                f"{f} is outside the declared vocab domain "
+                f"[0, {int(limit[f])}); rebuild with a larger v_cap or "
+                f"vocabulary to serve it")
         return vectors, metadata
 
     def _validate_gids(self, gids, rows: int, st) -> np.ndarray:

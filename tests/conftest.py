@@ -14,6 +14,14 @@ from repro.data.synth import SynthSpec, make_dataset, make_queries
 
 SELECTIVITIES = (0.5, 0.1, 0.02)
 
+try:  # property tests draw the same examples on every run and worker
+    from hypothesis import settings as _hypothesis_settings
+
+    _hypothesis_settings.register_profile("deterministic", derandomize=True)
+    _hypothesis_settings.load_profile("deterministic")
+except ImportError:  # tests/_hypothesis_fallback.py is seeded already
+    pass
+
 
 @pytest.fixture(scope="session")
 def small_ds():

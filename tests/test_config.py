@@ -61,8 +61,9 @@ def test_flatten_with_knobs_roundtrip():
     flat = cfg.flatten()
     assert flat["walk.beam_width"] == 4
     assert flat["graph.graph_k"] == 32
-    cfg2 = cfg.with_knobs({"walk.beam_width": 8, "kernel.topk_nt": 256})
-    assert cfg2.walk.beam_width == 8 and cfg2.kernel.topk_nt == 256
+    cfg2 = cfg.with_knobs({"walk.beam_width": 8,
+                           "kernel.filter_tile": 4096})
+    assert cfg2.walk.beam_width == 8 and cfg2.kernel.filter_tile == 4096
     assert cfg.walk.beam_width == 4  # frozen: with_knobs never mutates
     assert FnsConfig.from_flat(cfg2.flatten()) == cfg2
     # tolerant of unknown keys (configs from newer releases)
@@ -144,7 +145,6 @@ def test_kernel_tile_knobs_validated():
     import jax.numpy as jnp
 
     from repro.kernels.filter_eval import filter_eval_batch
-    from repro.kernels.masked_cosine_topk import masked_cosine_topk
 
     meta = jnp.zeros((64, 2), jnp.int32)
     fields = jnp.zeros((1, 1, 4), jnp.int32)
@@ -153,14 +153,11 @@ def test_kernel_tile_knobs_validated():
         filter_eval_batch(meta, fields, allowed, tn=100)  # not 32-aligned
     with pytest.raises(ValueError, match="filter_tile"):
         filter_eval_batch(meta, fields, allowed, tn=0)
-    q = jnp.zeros((4, 8)); v = jnp.zeros((64, 8))
-    mask = jnp.zeros((4, 2), jnp.uint32)
-    with pytest.raises(ValueError, match="topk_nt"):
-        masked_cosine_topk(q, v, mask, k=2, nt=100)
-    with pytest.raises(ValueError, match="topk_qt"):
-        masked_cosine_topk(q, v, mask, k=2, qt=0)
-    assert KernelConfig().filter_tile % 32 == 0
-    assert KernelConfig().topk_nt % 32 == 0
+    big = jnp.zeros((5000, 2), jnp.int32)          # several corpus tiles
+    with pytest.raises(ValueError, match="filter_tile"):
+        # not lane-aligned: refused for Mosaic before any lowering
+        filter_eval_batch(big, fields, allowed, tn=1024, interpret=False)
+    assert KernelConfig().filter_tile % (32 * 128) == 0
 
 
 # -- config through the durability snapshot ----------------------------------
@@ -266,3 +263,27 @@ def test_knob_guard_passes_on_repo():
         [sys.executable, os.path.join(REPO, "tools", "knob_guard.py")],
         capture_output=True, text=True)
     assert proc.returncode == 0, proc.stdout + proc.stderr
+
+
+def test_compile_cache_dir_rule(monkeypatch):
+    """The persistent compilation cache lives where JAX_COMPILATION_CACHE_DIR
+    says (JAX reads it; the code names no other path), else at one fixed
+    directory in the checkout."""
+    from pathlib import Path
+
+    import jax
+
+    from repro.launch import compile_cache as cc
+
+    old = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv(cc.ENV_VAR, "/elsewhere/cache")
+        assert cc.enable_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == old
+        monkeypatch.delenv(cc.ENV_VAR)
+        path = cc.enable_compile_cache()
+        assert path == cc.enable_compile_cache()
+        assert Path(path) == Path(__file__).resolve().parents[1] / ".jax_cache"
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", old)

@@ -34,3 +34,20 @@ def test_query_selectivity_spread(small_queries):
     sels = np.asarray([q.selectivity for q in small_queries])
     assert sels.min() < 0.02 and sels.max() > 0.1   # spans paper's range
     assert all(q.gt_ids.size > 0 for q in small_queries)
+
+
+def test_filtered_topk_shared_matches_per_query(small_ds):
+    """The shared-mask batch form returns the per-query ground truth, and
+    an empty mask gives every query an empty answer."""
+    from repro.data.ground_truth import filtered_topk, filtered_topk_shared
+
+    rng = np.random.default_rng(3)
+    qs = small_ds.vectors[rng.integers(0, small_ds.n, 6)]
+    passes = small_ds.metadata[:, 0] == 1
+    got = filtered_topk_shared(small_ds.vectors, qs, passes, 10)
+    for q, ids in zip(qs, got):
+        np.testing.assert_array_equal(
+            ids, filtered_topk(small_ds.vectors, q, passes, 10)[0])
+    none = filtered_topk_shared(small_ds.vectors, qs,
+                                np.zeros(small_ds.n, bool), 10)
+    assert [a.size for a in none] == [0] * len(qs)

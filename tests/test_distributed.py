@@ -15,9 +15,9 @@ SCRIPT = textwrap.dedent("""
     import sys; sys.path.insert(0, "src")
     from repro.models.moe import MoEDims, moe_ffn
     from repro.models.attention import decode_attention, flash_decode_sharded
-    from repro.models.common import use_mesh
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     E, K, d, f = 8, 2, 16, 32
     key = jax.random.PRNGKey(0)
     ks = jax.random.split(key, 5)
@@ -34,7 +34,7 @@ SCRIPT = textwrap.dedent("""
         jnp.einsum("td,edf->tef", xt, params["w3"])
     y_all = jnp.einsum("tef,efd->ted", h, params["w2"])
     ref = (jnp.take_along_axis(y_all, ti[:, :, None], 1) * w[..., None]).sum(1).reshape(x.shape)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         xs = jax.device_put(x, NamedSharding(mesh, P("data", None, None)))
         ps = {k: jax.device_put(v, NamedSharding(mesh, P("model", None, None))
                                 if k != "router" else NamedSharding(mesh, P()))
@@ -51,8 +51,8 @@ SCRIPT = textwrap.dedent("""
     vc = jax.random.normal(jax.random.fold_in(key, 2), (B, S, KV, hd))
     clen = jnp.asarray(50, jnp.int32)
     ref2 = decode_attention(q, kc, vc, clen)
-    seq_mesh = jax.make_mesh((1, 8), ("data", "model"))
-    with use_mesh(seq_mesh):
+    seq_mesh = make_mesh((1, 8), ("data", "model"))
+    with jax.set_mesh(seq_mesh):
         kcs = jax.device_put(kc, NamedSharding(seq_mesh, P(None, "model", None, None)))
         vcs = jax.device_put(vc, NamedSharding(seq_mesh, P(None, "model", None, None)))
         out2 = jax.jit(lambda a, b, c, l: flash_decode_sharded(

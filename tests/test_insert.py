@@ -27,6 +27,7 @@ except ImportError:  # container without hypothesis: deterministic shim
 
 from repro.core import AnchorAtlas, FiberIndex, build_alpha_knn
 from repro.core.batched.engine import BatchedEngine, BatchedParams
+from repro.core.predicate import Range
 from repro.core.types import Dataset, FilterPredicate, Query
 
 MULTI = len(jax.devices()) >= 4
@@ -414,10 +415,16 @@ def test_insert_capacity_and_vocab_guards():
     assert gids.size == 20
     assert eng.insert_stats["slab_growths"] == 1
     assert eng.cfg.serve.capacity == eng.state.shards[0].cap > 260
-    with pytest.raises(ValueError, match="value range"):
-        eng.insert_batch(ds.vectors[250:251],
-                         np.full((1, ds.metadata.shape[1]), 10 ** 6,
-                                 np.int32))
+    # codes past the atlas value bitmaps are legal, as at build time: the
+    # per-cluster envelopes serve them and the engine's domains grow to
+    # cover them (the service checks its declared vocabularies instead)
+    g_big = eng.insert_batch(ds.vectors[250:251],
+                             np.full((1, ds.metadata.shape[1]), 10 ** 6,
+                                     np.int32))
+    assert min(eng.vocab_sizes) > 10 ** 6
+    ids, _ = eng.search([Query(vector=ds.vectors[250],
+                               predicate=Range(0, 10 ** 6, 10 ** 6))])
+    assert np.asarray(ids[0]).tolist() == [int(g_big[0])]
     # an engine without capacity refuses inserts with guidance
     fixed = _build_single_engine(ds.vectors[:250], ds.metadata[:250],
                                  tuple(ds.vocab_sizes))

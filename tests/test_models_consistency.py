@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 
 from repro.configs import reduced_config
+from repro.launch.mesh import make_mesh
 from repro.models.attention import chunked_attention, decode_attention
 from repro.models.moe import MoEDims, moe_ffn
 from repro.models.transformer import (ShardEnv, decode_step, forward_loss,
@@ -13,7 +14,7 @@ from repro.models.transformer import (ShardEnv, decode_step, forward_loss,
 
 
 def _env():
-    return ShardEnv(jax.make_mesh((1, 1), ("data", "model")))
+    return ShardEnv(make_mesh((1, 1), ("data", "model")))
 
 
 @pytest.mark.parametrize("arch", ["llama3.2-1b", "gemma3-1b", "rwkv6-3b",
@@ -74,7 +75,7 @@ def test_decode_attention_matches_full():
 
 
 def test_moe_matches_dense_oracle():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     E, K, d, f = 8, 2, 16, 32
     key = jax.random.PRNGKey(0)
     ks = jax.random.split(key, 5)
@@ -104,7 +105,7 @@ def test_moe_matches_dense_oracle():
 def test_capacity_drops_are_bounded():
     """With cf=1.0 and adversarially skewed routing, output degrades
     gracefully (dropped tokens fall back to residual = zero delta)."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     E, K, d, f = 4, 1, 8, 16
     key = jax.random.PRNGKey(0)
     params = {"router": jnp.zeros((d, E)).at[:, 0].set(10.0),  # all -> e0

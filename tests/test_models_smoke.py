@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 
 from repro.configs import ARCH_NAMES, reduced_config
+from repro.launch.mesh import make_mesh
 from repro.models.common import pad_vocab
 from repro.models.transformer import (ShardEnv, decode_step, forward_loss,
                                       init_params, prefill)
@@ -14,7 +15,7 @@ B, S = 2, 64
 
 
 def _env():
-    return ShardEnv(jax.make_mesh((1, 1), ("data", "model")))
+    return ShardEnv(make_mesh((1, 1), ("data", "model")))
 
 
 def _batch(cfg, key):

@@ -6,6 +6,7 @@ import pytest
 
 from repro.configs import reduced_config
 from repro.core.search import SearchParams
+from repro.launch.mesh import make_mesh
 from repro.models.transformer import ShardEnv, init_params
 from repro.serve.engine import ServeEngine
 from repro.serve.retrieval import EncodedRetriever, RetrievalService
@@ -14,7 +15,7 @@ from repro.serve.retrieval import EncodedRetriever, RetrievalService
 @pytest.fixture(scope="module")
 def tiny_model():
     cfg = reduced_config("llama3.2-1b")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     env = ShardEnv(mesh)
     params = init_params(cfg, jax.random.PRNGKey(0))
     return cfg, env, params

@@ -47,6 +47,10 @@ SCRIPT = textwrap.dedent("""
     assert np.array_equal(st_m["walks"], st_r["walks"])
     assert np.array_equal(st_m["hops"], st_r["hops"])
     assert sum(np.asarray(i).size > 0 for i in ids_m) == len(queries)
+    # the reference runs on the default device alone, never partitioned
+    ref_out = eng._run_reference(*eng._pack_queries(queries))
+    assert {d for v in ref_out.values() for d in v.devices()} == \
+        {jax.devices()[0]}
     print("sharded-parity ok")
 """)
 

@@ -9,6 +9,7 @@ import pytest
 
 from repro.configs import reduced_config
 from repro.data.tokens import TokenPipeline
+from repro.launch.mesh import make_mesh
 from repro.models.transformer import ShardEnv, init_params
 from repro.optim.adamw import AdamWConfig, init_opt_state, make_train_step
 from repro.train.loop import LoopConfig, TrainLoop
@@ -17,7 +18,7 @@ from repro.train.loop import LoopConfig, TrainLoop
 @pytest.fixture(scope="module")
 def setup():
     cfg = reduced_config("smollm-135m")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     env = ShardEnv(mesh)
     params = init_params(cfg, jax.random.PRNGKey(0))
     opt = init_opt_state(params)

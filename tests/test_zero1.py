@@ -17,10 +17,10 @@ SCRIPT = textwrap.dedent("""
                                         param_shardings)
     from repro.models.transformer import ShardEnv, init_params
     from repro.optim.adamw import AdamWConfig, init_opt_state, make_train_step
-    from repro.models.common import use_mesh
+    from repro.launch.mesh import make_mesh
 
     cfg = reduced_config("llama3.2-1b")
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     env = ShardEnv(mesh, policy="dp")
     params = init_params(cfg, jax.random.PRNGKey(0))
     opt = init_opt_state(params)
@@ -34,7 +34,7 @@ SCRIPT = textwrap.dedent("""
         p_sh = param_shardings(cfg, mesh, jax.eval_shape(lambda: params), policy="dp")
         o_sh = opt_shardings(cfg, mesh, jax.eval_shape(lambda: opt), policy="dp",
                              zero1=zero1)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             fn = jax.jit(step, in_shardings=(p_sh, o_sh,
                                              batch_shardings(cfg, mesh, jax.eval_shape(lambda: batch), policy="dp")),
                          out_shardings=(p_sh, o_sh, None))

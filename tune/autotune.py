@@ -43,7 +43,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from benchmarks.search_bench import (SELECTIVITIES, BatchedEngine,  # noqa: E402
                                      bench_config, build_search_fixture,
-                                     make_query_pools, measure_batch)
+                                     device_info, make_query_pools,
+                                     measure_batch)
 
 # the searched subspace: every axis is a walk.* knob (runtime-tunable by
 # definition — see core/config.py SHAPE_BAKED), with a small monotone
@@ -144,9 +145,8 @@ def autotune(*, lam: float = 0.003, latency_budget: float = 1.20,
             break
         log(f"[autotune] final re-measure over budget; reverting "
             f"{history.pop()['axis']}")
-    import jax
     return {
-        "backend": jax.default_backend(),
+        **device_info(),
         "lambda": lam,
         "latency_budget": latency_budget,
         "fixture": {"n": n, "d": d, "k": k, "graph_k": graph_k,
@@ -165,6 +165,9 @@ def autotune(*, lam: float = 0.003, latency_budget: float = 1.20,
 
 
 def main(argv=None) -> dict:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--lam", type=float, default=0.003,
                     help="latency weight: recall units per p50 ms")
