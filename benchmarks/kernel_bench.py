@@ -49,10 +49,7 @@ def kernel_microbench():
 def anchor_select_bench(batch_sizes=(16, 64, 256), reps: int = 5):
     """Anchor-selection throughput (queries/s): host per-query Python loop
     over ``AnchorAtlas.select_anchors`` vs one batched device call to
-    ``DeviceAtlas.select_anchors_batch``, at Q in ``batch_sizes``. The
-    device path is reported for both seeding backends ("sort" = one
-    lexicographic lax.sort; "topk" = one masked lax.top_k per
-    yielding-cluster slot over the shared score sweep)."""
+    ``DeviceAtlas.select_anchors_batch``, at Q in ``batch_sizes``."""
     ds, qs, idx_alpha, _, _ = get_indexes()
     atlas = idx_alpha.atlas
     datlas = atlas.to_device()
@@ -73,18 +70,14 @@ def anchor_select_bench(batch_sizes=(16, 64, 256), reps: int = 5):
                                      n_seeds=10, c_max=5,
                                      vectors=ds.vectors)
         out[f"host_q{q_n}"] = q_n * reps / (time.time() - t0)
-        for backend in ("sort", "topk"):
-            fn = jax.jit(lambda qv, pr, ps, b=backend:
-                         datlas.select_anchors_batch(
-                             qv, ct, pr, vectors, ps, n_seeds=10, c_max=5,
-                             backend=b))
-            jax.block_until_ready(fn(q_vecs, proc, passes))  # compile
-            t0 = time.time()
-            for _ in range(reps):
-                res = fn(q_vecs, proc, passes)
-            jax.block_until_ready(res)
-            out[f"device_{backend}_q{q_n}"] = (
-                q_n * reps / (time.time() - t0))
+        fn = jax.jit(lambda qv, pr, ps: datlas.select_anchors_batch(
+            qv, ct, pr, vectors, ps, n_seeds=10, c_max=5))
+        jax.block_until_ready(fn(q_vecs, proc, passes))  # compile
+        t0 = time.time()
+        for _ in range(reps):
+            res = fn(q_vecs, proc, passes)
+        jax.block_until_ready(res)
+        out[f"device_q{q_n}"] = q_n * reps / (time.time() - t0)
     return out
 
 

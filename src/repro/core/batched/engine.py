@@ -261,7 +261,7 @@ def walk_batch(vectors, adjacency, pass_bm, q_vecs, seeds,
 
 def atlas_round(datlas: DeviceAtlas, vectors, adjacency, pass_bm, passes,
                 q_vecs, fields, allowed, processed, need, res_v, res_i,
-                p: BatchedParams, seed_backend: str, bounds=None,
+                p: BatchedParams, bounds=None,
                 kcfg: KernelConfig | None = None):
     """One full restart round for all Q queries on device: batched anchor
     selection from the packed atlas, then the lockstep walk. ``pass_bm``
@@ -277,8 +277,7 @@ def atlas_round(datlas: DeviceAtlas, vectors, adjacency, pass_bm, passes,
               else (fields, allowed, bounds))
     seeds, used = datlas.select_anchors_batch(
         q_vecs, tables, gate, vectors, passes,
-        n_seeds=p.n_seeds, c_max=p.c_max, backend=seed_backend,
-        disjunct_quota=p.disjunct_quota)
+        n_seeds=p.n_seeds, c_max=p.c_max, disjunct_quota=p.disjunct_quota)
     out = walk_batch(vectors, adjacency, pass_bm, q_vecs, seeds, p,
                      init_results=(res_v, res_i))
     found = (out["res_v"] < INF / 2).sum(axis=1)
@@ -288,7 +287,7 @@ def atlas_round(datlas: DeviceAtlas, vectors, adjacency, pass_bm, passes,
 
 
 def search_batch(datlas: DeviceAtlas, vectors, adjacency, metadata, q_vecs,
-                 fields, allowed, p: BatchedParams, seed_backend: str,
+                 fields, allowed, p: BatchedParams,
                  valid_bm=None, bounds=None,
                  kcfg: KernelConfig | None = None):
     """A whole filtered search batch as ONE device program: batched
@@ -330,8 +329,7 @@ def search_batch(datlas: DeviceAtlas, vectors, adjacency, metadata, q_vecs,
     def body(c):
         out = atlas_round(datlas, vectors, adjacency, pass_bm, passes,
                           q_vecs, fields, allowed, c["processed"], c["need"],
-                          c["res_v"], c["res_i"], p=p,
-                          seed_backend=seed_backend, bounds=bounds,
+                          c["res_v"], c["res_i"], p=p, bounds=bounds,
                           kcfg=kcfg)
         seeded = out["seeded"]
         any_seeded = seeded.any()
@@ -480,7 +478,7 @@ class BatchedEngine:
     """
 
     def __init__(self, index: FiberIndex, config=None,
-                 seed_backend: str | None = None, v_cap: int | None = None,
+                 v_cap: int | None = None,
                  vocab_sizes=None, capacity: int | None = None,
                  graph_k: int | None = None, alpha: float | None = None,
                  params: BatchedParams | None = None):
@@ -499,10 +497,8 @@ class BatchedEngine:
                              "graph.alpha": alpha},
                             where="BatchedEngine",
                             defaults={"graph.graph_k": 16})
-        # non-knob plumbing args (backend choice, bitmap width, domains)
-        # stay first-class: fold without deprecation noise
-        if seed_backend is not None:
-            cfg = cfg.with_knobs({"serve.seed_backend": seed_backend})
+        # non-knob plumbing args (bitmap width, domains) stay
+        # first-class: fold without deprecation noise
         if v_cap is not None:
             cfg = cfg.with_knobs({"atlas.v_cap": v_cap})
         self.cfg = cfg
@@ -551,11 +547,10 @@ class BatchedEngine:
         self.vocab_sizes = (tuple(int(v) for v in vocab_sizes)
                             if vocab_sizes is not None
                             else index.vocab_sizes())
-        self._init_programs(cfg.serve.seed_backend)
+        self._init_programs()
 
     @classmethod
-    def from_state(cls, state, config=None, seed_backend: str | None = None,
-                   vocab_sizes=None,
+    def from_state(cls, state, config=None, vocab_sizes=None,
                    params: BatchedParams | None = None) -> "BatchedEngine":
         """Reconstruct a live capacity-slab engine from a restored
         ``InsertState`` (DESIGN.md §10) with ZERO graph/atlas rebuild: the
@@ -588,8 +583,6 @@ class BatchedEngine:
             cfg = cfg.with_knobs({"graph.graph_k": state.graph_k,
                                   "graph.alpha": state.alpha,
                                   "atlas.v_cap": state.v_cap})
-        if seed_backend is not None:
-            cfg = cfg.with_knobs({"serve.seed_backend": seed_backend})
         slab = state.shards[0]
         eng = cls.__new__(cls)
         eng.cfg = cfg
@@ -604,7 +597,7 @@ class BatchedEngine:
                            if vocab_sizes is not None
                            else eng.index.vocab_sizes())
         eng.index.extend_vocab(eng.vocab_sizes)
-        eng._init_programs(cfg.serve.seed_backend)
+        eng._init_programs()
         return eng
 
     def _refresh_from_slab(self, v_cap: int) -> None:
@@ -622,18 +615,16 @@ class BatchedEngine:
         # the counters exist
         self.publish_generation = getattr(self, "publish_generation", 0) + 1
 
-    def _init_programs(self, seed_backend: str) -> None:
+    def _init_programs(self) -> None:
         params = self.p
         kcfg = self.cfg.kernel
         # the CPU backend ignores donation; search_batch's inputs have no
         # same-shaped output to alias, so only the round state is donated
         self._round = jax.jit(
-            functools.partial(atlas_round, p=params,
-                              seed_backend=seed_backend, kcfg=kcfg),
+            functools.partial(atlas_round, p=params, kcfg=kcfg),
             donate_argnums=(8, 9, 10, 11) if on_tpu() else ())
         self._search = jax.jit(
-            functools.partial(search_batch, p=params,
-                              seed_backend=seed_backend, kcfg=kcfg))
+            functools.partial(search_batch, p=params, kcfg=kcfg))
         self._passes = jax.jit(functools.partial(_eval_passes, kcfg=kcfg))
         self.dispatches = 0
         self.publish_generation = getattr(self, "publish_generation", 0)

@@ -224,7 +224,7 @@ class ShardedEngine:
     """
 
     def __init__(self, sindex: ShardedIndex, mesh, config=None,
-                 seed_backend: str | None = None, axis: str = "data",
+                 axis: str = "data",
                  params: BatchedParams | None = None):
         s = sindex.n_shards
         if mesh is not None and index_axis_size(mesh, axis) != s:
@@ -234,11 +234,8 @@ class ShardedEngine:
         if config is None:
             config = params
         cfg = coerce_config(config, {}, where="ShardedEngine")
-        if seed_backend is not None:
-            cfg = cfg.with_knobs({"serve.seed_backend": seed_backend})
         self.cfg = cfg
         self.mesh, self.axis, self.p = mesh, axis, cfg.walk
-        self._seed_backend = cfg.serve.seed_backend
         self._istate = sindex.insert_state
         # 2D query×data layout (DESIGN.md §13): when the mesh carries a
         # second axis of size > 1 from cfg.mesh.query_axes (a dedicated
@@ -283,14 +280,13 @@ class ShardedEngine:
         self._ref = jax.jit(
             lambda datlas, vec, adj, meta, vbm, qv, f, a, b: search_batch(
                 datlas, vec, adj, meta, qv, f, a, cfg.walk,
-                cfg.serve.seed_backend, valid_bm=vbm, bounds=b,
-                kcfg=cfg.kernel))
+                valid_bm=vbm, bounds=b, kcfg=cfg.kernel))
         self.dispatches = 0
         self.publish_generation = 0
         self.fence_retries = 0
 
     def _build_program(self, has_bounds: bool):
-        axis, p, sb = self.axis, self.p, self._seed_backend
+        axis, p = self.axis, self.p
         kcfg = self.cfg.kernel
         nl, tdef = len(self._leaves), self._tdef
 
@@ -302,7 +298,7 @@ class ShardedEngine:
             datlas = jax.tree_util.tree_unflatten(
                 tdef, [l[0] for l in leaves])
             out = search_batch(datlas, vectors[0], adjacency[0], metadata[0],
-                               q_vecs, fields, allowed, p, sb,
+                               q_vecs, fields, allowed, p,
                                valid_bm=valid_bm[0], bounds=bounds,
                                kcfg=kcfg)
             gids = jnp.where(out["res_i"] >= 0,

@@ -113,11 +113,10 @@ class KernelConfig:
 
 @dataclasses.dataclass(frozen=True)
 class ServeConfig:
-    """Serving-layer knobs: batch bucketing, seed backend, append room,
-    and the admission-queue/pipeline knobs (DESIGN.md §13)."""
+    """Serving-layer knobs: batch bucketing, append room, and the
+    admission-queue/pipeline knobs (DESIGN.md §13)."""
 
     min_bucket: int = 4        # smallest padded batch bucket (was MIN_BUCKET)
-    seed_backend: str = "topk"
     capacity: int | None = None  # append-slab rows; None = build-once
     # admission queue (serve/pipeline.py): the batch-former cuts a batch
     # when it holds queue_max_batch queries OR the oldest admitted query

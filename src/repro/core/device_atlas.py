@@ -18,11 +18,10 @@ device arrays so one jitted call selects anchors for all Q queries:
 
 ``select_anchors_batch`` reproduces ``AnchorAtlas.select_anchors`` exactly
 (same seed sets, same consumed clusters) for every query in the batch; the
-in-cluster nearest-matching-member scan runs either as one lexicographic
-``lax.sort`` over (cluster rank, cosine distance) ["sort" backend] or as
-one masked ``lax.top_k`` over the shared score sweep per yielding-cluster
-slot ["topk" backend]. Scores are full-f32 matmuls on every platform (a
-TPU's default f32 matmul rounds operands to bf16).
+in-cluster nearest-matching-member scan is one masked ``lax.top_k`` over
+a shared (Q, n) score sweep per yielding-cluster slot. Scores are
+full-f32 matmuls on every platform (a TPU's default f32 matmul rounds
+operands to bf16).
 """
 from __future__ import annotations
 
@@ -326,7 +325,7 @@ class DeviceAtlas:
         self, q_vecs: jax.Array, clause_tables: tuple,
         processed: jax.Array, vectors: jax.Array, passes: jax.Array, *,
         n_seeds: int = 10, c_max: int = 5, member_cap: int = MEMBER_CAP,
-        backend: str = "sort", disjunct_quota: int = 2,
+        disjunct_quota: int = 2,
     ) -> tuple[jax.Array, jax.Array]:
         """One anchor-selection round for Q queries (Alg. 2 lines 3–14,
         batched). Exact host semantics: rank matching unprocessed clusters
@@ -388,16 +387,11 @@ class DeviceAtlas:
         used = jnp.zeros((q_n, k), bool).at[qidx, order].set(visited_r)
 
         elig = passes & used[:, self.assign] & (rank_id < member_cap)
-        # one dense (Q, n) score sweep shared by the seed backends and the
+        # one dense (Q, n) score sweep shared by the quota fill and the
         # disjunct-quota repair
         sims = jnp.einsum("qd,nd->qn", q_vecs, vectors, precision=HIGHEST)
-        if backend == "sort":
-            seeds = self._seed_by_sort(sims, elig, order, n_seeds)
-        elif backend == "topk":
-            seeds = self._seed_by_topk(sims, elig, order, cnt_r, visited_r,
-                                       yld_r, n_seeds, c_max)
-        else:
-            raise ValueError(f"unknown seed backend {backend!r}")
+        seeds = self._seed_by_topk(sims, elig, order, cnt_r, visited_r,
+                                   yld_r, n_seeds, c_max)
         if dmasks is not None and disjunct_quota > 0:
             seeds, used = self._apply_disjunct_quota(
                 dmasks, processed, sims, passes,
@@ -467,22 +461,6 @@ class DeviceAtlas:
         # pays for the batch, none starved pays only the mask algebra)
         seeds = jax.lax.cond(starved.any(), with_quota, lambda: seeds)
         return seeds, used
-
-    def _seed_by_sort(self, sims, elig, order, n_seeds: int):
-        """Quota fill via one lexicographic sort: ordering every eligible
-        point by (its cluster's rank, cosine distance) and taking the first
-        n_seeds reproduces the host's cluster-by-cluster nearest-first fill,
-        including the final cluster's truncated quota."""
-        q_n, k = order.shape
-        n = sims.shape[1]
-        qidx = jnp.arange(q_n)[:, None]
-        ranks = jnp.broadcast_to(jnp.arange(k, dtype=jnp.int32), (q_n, k))
-        cluster_rank = jnp.zeros((q_n, k), jnp.int32).at[qidx, order].set(ranks)
-        key1 = jnp.where(elig, cluster_rank[:, self.assign], k)
-        key2 = jnp.where(elig, -sims, jnp.float32(jnp.inf))
-        pid = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32), (q_n, n))
-        k1s, _, ids = jax.lax.sort((key1, key2, pid), num_keys=2)
-        return jnp.where(k1s[:, :n_seeds] < k, ids[:, :n_seeds], -1)
 
     def _seed_by_topk(self, sims, elig, order, cnt_r, visited_r, yld_r,
                       n_seeds: int, c_max: int):

@@ -43,7 +43,7 @@ import numpy as np
 from repro.core.config import AtlasConfig, KernelConfig
 
 # fallback per-field domain for Not/Range when no vocab_sizes is given;
-# matches the kernels' default value-bitmap capacity (kernels.ops.V_CAP)
+# matches the kernels' default value-bitmap capacity (core.device_atlas.V_CAP)
 DEFAULT_DOMAIN = AtlasConfig().v_cap_min
 
 

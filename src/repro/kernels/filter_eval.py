@@ -136,7 +136,7 @@ def _kernel(fields_ref, words_ref, bounds_ref, ndisj_ref, meta_ref, out_ref,
 
 @functools.partial(jax.jit, static_argnames=("tn", "interpret"))
 def filter_eval_batch(metadata, fields, allowed, n_disj=None, bounds=None, *,
-                      tn: int = 8192, interpret: bool = True):
+                      tn: int, interpret: bool = True):
     """Batched corpus sweep: metadata (n, F) i32; fields (Q, C) i32 (-1
     inactive); allowed (Q, C, ceil(v_cap/32)) uint32 value bitmaps (the
     ``pack_predicates`` clause-table format) -> (Q, ceil(n/32)) uint32.
@@ -216,7 +216,7 @@ def filter_eval_batch(metadata, fields, allowed, n_disj=None, bounds=None, *,
     return out
 
 
-def filter_eval(metadata, fields, allowed, *, tn: int = 8192,
+def filter_eval(metadata, fields, allowed, *, tn: int,
                 interpret: bool = True):
     """Single-query form: metadata (n, F) i32; fields (C,) i32 (-1
     inactive); allowed (C, V_cap) uint8 dense table -> (ceil(n/32),)

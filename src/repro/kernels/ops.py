@@ -17,11 +17,10 @@ from repro.core.config import AtlasConfig, KernelConfig
 from repro.kernels.filter_eval import filter_eval as _filter_eval
 from repro.kernels.filter_eval import filter_eval_batch as _filter_eval_batch
 
-# legacy module-level names, derived from the one config origin
-# (core/config.py); kept as importable aliases for existing callers
+# legacy module-level name, derived from the one config origin
+# (core/config.py); kept as an importable alias for existing callers
 _KCFG = KernelConfig()
 MAX_CLAUSES = _KCFG.max_clauses
-V_CAP = AtlasConfig().v_cap_min
 
 
 def on_tpu() -> bool:
@@ -50,7 +49,8 @@ def filter_eval_batch(metadata, fields, allowed, n_disj=None, bounds=None, *,
 
 def predicate_tables(pred, n_fields: int,
                      max_clauses: int = MAX_CLAUSES,
-                     v_cap: int = V_CAP) -> tuple[np.ndarray, np.ndarray]:
+                     v_cap: int = AtlasConfig().v_cap_min
+                     ) -> tuple[np.ndarray, np.ndarray]:
     """FilterPredicate -> (fields (C,) i32, allowed (C, v_cap) u8)."""
     fields = np.full(max_clauses, -1, np.int32)
     allowed = np.zeros((max_clauses, v_cap), np.uint8)

@@ -339,7 +339,7 @@ def pad_state(state: InsertState, n_shards: int) -> InsertState:
 
 def engine_from_state(state: InsertState, *, mesh=None, config=None,
                       params: BatchedParams | None = None,
-                      seed_backend: str | None = None, vocab_sizes=None):
+                      vocab_sizes=None):
     """Reconstruct a live engine from a restored state on whatever mesh
     this process has — zero graph/atlas rebuild on every path:
 
@@ -357,9 +357,9 @@ def engine_from_state(state: InsertState, *, mesh=None, config=None,
     ``config`` (an ``FnsConfig``) is the one knob source; when given, its
     shape-baked knobs are validated against the state (``ConfigMismatch``
     on conflict — graph_k/v_cap/capacity are baked into the slabs and
-    cannot be changed by a restore). The legacy ``params``/
-    ``seed_backend`` kwargs remain as deprecation shims (folded by the
-    engine constructors, which warn once)."""
+    cannot be changed by a restore). The legacy ``params`` kwarg remains
+    as a deprecation shim (folded by the engine constructors, which warn
+    once)."""
     if isinstance(config, FnsConfig):
         check_state_config(
             config, graph_k=state.graph_k, v_cap=state.v_cap,
@@ -373,13 +373,12 @@ def engine_from_state(state: InsertState, *, mesh=None, config=None,
         if target > s:
             pad_state(state, target)
         return ShardedEngine(index_from_state(state, vocab_sizes=vocab_sizes),
-                             mesh, config=eff, seed_backend=seed_backend)
+                             mesh, config=eff)
     if s == 1:
         return BatchedEngine.from_state(state, config=eff,
-                                        seed_backend=seed_backend,
                                         vocab_sizes=vocab_sizes)
     return ShardedEngine(index_from_state(state, vocab_sizes=vocab_sizes),
-                         None, config=eff, seed_backend=seed_backend)
+                         None, config=eff)
 
 
 # -- the store: snapshots dir + journal under one root ----------------------

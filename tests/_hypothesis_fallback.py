@@ -4,7 +4,7 @@ policy forbids adding deps). Each ``@given`` test runs ``max_examples``
 times with examples drawn from a per-example seeded numpy Generator, so
 failures are reproducible. Shrinking and the full strategy algebra are
 out of scope — only what the tests import: integers, floats, lists,
-permutations, composite, given, settings.
+permutations, composite, given, settings, example.
 """
 from __future__ import annotations
 
@@ -66,9 +66,20 @@ def settings(max_examples: int = 20, deadline=None, **_ignored):
     return deco
 
 
+def example(**kwargs):
+    """An explicit case, run before the drawn ones (as hypothesis does)."""
+    def deco(fn):
+        fn._fallback_examples = [kwargs] + getattr(fn, "_fallback_examples",
+                                                   [])
+        return fn
+    return deco
+
+
 def given(*strats):
     def deco(fn):
         def run():
+            for kwargs in getattr(fn, "_fallback_examples", []):
+                fn(**kwargs)
             # read at call time: ``@settings`` is conventionally stacked
             # ABOVE ``@given``, so it decorates (and tags) the wrapper
             # after this closure is built

@@ -20,15 +20,13 @@ def _host_round(atlas, q, processed, vectors):
                                 n_seeds=10, c_max=5, vectors=vectors)
 
 
-def _device_round(datlas, qs, ct, proc, vectors, passes, backend):
+def _device_round(datlas, qs, ct, proc, vectors, passes):
     q_vecs = jnp.asarray(np.stack([q.vector for q in qs]))
     return datlas.select_anchors_batch(q_vecs, ct, proc, vectors, passes,
-                                       n_seeds=10, c_max=5, backend=backend)
+                                       n_seeds=10, c_max=5)
 
 
-@pytest.mark.parametrize("backend", ["sort", "topk"])
-def test_single_query_seed_parity(small_ds, small_atlas, small_queries,
-                                  backend):
+def test_single_query_seed_parity(small_ds, small_atlas, small_queries):
     """select_anchors_batch at Q=1 == host select_anchors: same seed sets
     and same consumed clusters, across the full multi-round processed-set
     evolution of Algorithm 2."""
@@ -43,7 +41,7 @@ def test_single_query_seed_parity(small_ds, small_atlas, small_queries,
             seeds_h, used_h = _host_round(small_atlas, q, processed,
                                           small_ds.vectors)
             seeds_d, used_d = _device_round(datlas, [q], ct, proc, vectors,
-                                            passes, backend)
+                                            passes)
             sd = np.asarray(seeds_d[0])
             assert set(sd[sd >= 0].tolist()) == set(seeds_h)
             ud = np.nonzero(np.asarray(used_d[0]))[0]
@@ -68,7 +66,7 @@ def test_batch_seed_parity(small_ds, small_atlas, small_queries):
     proc = jnp.zeros((len(qs), small_atlas.n_clusters), bool)
     for _ in range(3):
         seeds_d, used_d = _device_round(datlas, qs, ct, proc, vectors,
-                                        passes, "sort")
+                                        passes)
         seeds_d, used_d = np.asarray(seeds_d), np.asarray(used_d)
         for qi, q in enumerate(qs):
             seeds_h, used_h = _host_round(small_atlas, q, processed[qi],
@@ -130,24 +128,10 @@ def test_high_cardinality_vocab_auto_v_cap():
                pack_predicates([q.predicate], v_cap=datlas.v_cap))
     passes = jnp.asarray(q.predicate.mask(meta)[None])
     proc = jnp.zeros((1, atlas.n_clusters), bool)
-    seeds_d, used_d = _device_round(datlas, [q], ct, proc, vec_j, passes,
-                                    "sort")
+    seeds_d, used_d = _device_round(datlas, [q], ct, proc, vec_j, passes)
     seeds_h, used_h = _host_round(atlas, q, set(), ds.vectors)
     sd = np.asarray(seeds_d[0])
     assert set(sd[sd >= 0].tolist()) == set(seeds_h)
     assert set(np.nonzero(np.asarray(used_d[0]))[0].tolist()) == set(used_h)
     with pytest.raises(ValueError, match="larger v_cap"):
         atlas.to_device(v_cap=256)
-
-
-def test_engine_backends_agree(sel_sweep):
-    """The sort- and kernel-routed seeding backends drive the engine to
-    identical results."""
-    _, index, queries = sel_sweep
-    sub = queries[::4]
-    a, _ = BatchedEngine(index, BatchedParams(k=10, beam_width=4),
-                         seed_backend="sort").search(sub)
-    b, _ = BatchedEngine(index, BatchedParams(k=10, beam_width=4),
-                         seed_backend="topk").search(sub)
-    for x, y in zip(a, b):
-        assert np.array_equal(np.asarray(x), np.asarray(y))

@@ -4,9 +4,9 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 try:
-    from hypothesis import given, settings, strategies as st
+    from hypothesis import example, given, settings, strategies as st
 except ImportError:  # container without hypothesis: deterministic shim
-    from _hypothesis_fallback import given, settings, strategies as st
+    from _hypothesis_fallback import example, given, settings, strategies as st
 
 from repro.core.types import FilterPredicate
 from repro.kernels import ops, ref
@@ -23,6 +23,7 @@ def _mk(n, d, Q, seed=0, dtype=np.float32):
 
 @given(st.integers(10, 300), st.integers(1, 3), st.integers(0, 100))
 @settings(max_examples=15, deadline=None)
+@example(n=65, n_clauses=1, seed=0)  # a failure hypothesis once found
 def test_filter_eval_matches_core_mask(n, n_clauses, seed):
     rng = np.random.default_rng(seed)
     F = 6

@@ -24,9 +24,9 @@ import pytest
 import jax
 
 try:
-    from hypothesis import given, settings, strategies as st
+    from hypothesis import example, given, settings, strategies as st
 except ImportError:  # container without hypothesis: deterministic shim
-    from _hypothesis_fallback import given, settings, strategies as st
+    from _hypothesis_fallback import example, given, settings, strategies as st
 
 from test_durability import _corpus, _labeled_queries, _query
 from test_insert import (GRAPH, PARAMS, _build_single_engine, _full_dataset,
@@ -249,6 +249,7 @@ def _tiny_engine(vectors, metadata, vocab, capacity=None):
                                  "search"]),
                 min_size=3, max_size=6),
        st.integers(min_value=0, max_value=2**16))
+@example(ops=["delete", "delete", "delete"], seed=59)  # once found failing
 def test_property_lifecycle_interleavings(ops, seed):
     """Random insert/delete/compact/maintain/search schedules: (a) recall
     parity vs rebuild over live rows at every search point and at the
@@ -261,13 +262,18 @@ def test_property_lifecycle_interleavings(ops, seed):
     base_n = 200
     eng = _tiny_engine(ds.vectors[:base_n], ds.metadata[:base_n], vocab,
                        capacity=ds.n)
+    # graph_k=8 is thin: relink every compacted row left below graph_k
+    # edges. At the default 0.5 the compacted graph stays sparser than a
+    # rebuild's and trails it past the bound on some delete schedules.
+    # 50 queries per code: at 5, one missed neighbour alone is 0.02.
     eng.cfg = eng.cfg.with_knobs({"maintenance.defer_repair": True,
                                   "maintenance.compact_min_rows": 4,
-                                  "maintenance.compact_tombstone_frac": 0.05})
+                                  "maintenance.compact_tombstone_frac": 0.05,
+                                  "maintenance.min_degree_frac": 1.0})
     loop = MaintenanceLoop(eng, eng.cfg.maintenance)
     rng = np.random.default_rng(seed)
     labeled = [("sel", q) for code in (0, 1)
-               for q in make_selectivity_queries(ds, code, 5)]
+               for q in make_selectivity_queries(ds, code, 50)]
     written = base_n
     live = set(range(base_n))
 

@@ -97,7 +97,6 @@ def test_fused_search_lowers_for_v5e(spec, monkeypatch):
         spec((F, k), jnp.int32), spec((F, k), jnp.int32), v_cap=WV * 32)
     fields, allowed, _, _ = _tables(spec, "rank2")
     fn = jax.jit(functools.partial(engine.search_batch, p=cfg.walk,
-                                   seed_backend=cfg.serve.seed_backend,
                                    kcfg=cfg.kernel))
     compiled = fn.lower(datlas, spec((n, D), jnp.float32),
                         spec((n, r), jnp.int32), spec((n, F), jnp.int32),

@@ -27,8 +27,8 @@ import sys
 # each may only appear outside core/config.py as a value DERIVED from a
 # config instance (attribute access), never as a literal again
 KNOB_REGISTRY = frozenset({
-    "MAX_CLAUSES", "V_CAP",                   # kernels/ops.py
-    "MEMBER_CAP", "AUTO_V_CAP_MAX",           # core/device_atlas.py
+    "MAX_CLAUSES",                            # kernels/ops.py
+    "V_CAP", "MEMBER_CAP", "AUTO_V_CAP_MAX",  # core/device_atlas.py
     "MAX_DISJUNCTS", "DEFAULT_DOMAIN",        # core/predicate.py
     "MIN_BUCKET", "GRAPH_BUILD_DEFAULTS",     # serve/retrieval.py
 })
