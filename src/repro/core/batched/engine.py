@@ -31,6 +31,7 @@ import functools
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro import faults
 from repro.core.batched.bitmap import (n_words, pack_bits, popcount,
@@ -39,6 +40,7 @@ from repro.core.config import (FnsConfig, KernelConfig, WalkConfig,
                                check_state_config, coerce_config)
 from repro.core.device_atlas import (DeviceAtlas, pack_dnf, pack_predicates,
                                      table_n_disj)
+from repro.core.batched.scopes import dispatch_program
 from repro.core.predicate import DNF, as_dnf, disjunct_selectivity
 from repro.core.search import FiberIndex, SearchParams
 from repro.core.types import FilterPredicate, Query
@@ -82,13 +84,16 @@ def _eval_passes(metadata, fields, allowed, bounds=None,
     conjunctive bitmaps in the same sweep (DESIGN.md §8). ``bounds``
     (Q, D, C, 2) marks interval clauses (evaluated as two comparisons,
     short-circuited rarest-first; None keeps legacy programs). ``kcfg``
-    sizes the kernel's corpus tile (CPU oracle has no tiles)."""
+    sizes the kernel's corpus tile (CPU oracle has no tiles). Its ops sit
+    under the device scope ``filter_eval``."""
     n_disj = table_n_disj(fields) if fields.ndim == 3 else None
-    if on_tpu():
-        tn = (kcfg or KernelConfig()).filter_tile
-        return filter_eval_batch(metadata, fields, allowed, n_disj, bounds,
-                                 tn=tn, interpret=False)
-    return ref.filter_eval_batch(metadata, fields, allowed, n_disj, bounds)
+    with jax.named_scope("filter_eval"):
+        if on_tpu():
+            tn = (kcfg or KernelConfig()).filter_tile
+            return filter_eval_batch(metadata, fields, allowed, n_disj,
+                                     bounds, tn=tn, interpret=False)
+        return ref.filter_eval_batch(metadata, fields, allowed, n_disj,
+                                     bounds)
 
 
 def walk_batch(vectors, adjacency, pass_bm, q_vecs, seeds,
@@ -97,9 +102,11 @@ def walk_batch(vectors, adjacency, pass_bm, q_vecs, seeds,
 
     vectors (n, d) f32; adjacency (n, R) i32 (-1 pad); pass_bm
     (Q, ceil(n/32)) uint32 packed filter bitmaps; q_vecs (Q, d); seeds
-    (Q, S) i32 (-1 pad). Returns dict of results + diagnostics. All
-    per-point walk state (visited / in-results / pass) is bitmap-packed:
-    O(Q*n/32) bytes instead of three dense (Q, n) bool masks.
+    (Q, S) i32 (-1 pad). Returns dict of results + diagnostics, among
+    them ``iters``, the lockstep iterations the loop ran. All per-point
+    walk state (visited / in-results / pass) is bitmap-packed: O(Q*n/32)
+    bytes instead of three dense (Q, n) bool masks. Each iteration's ops
+    sit under the device scope ``walk_hop``.
     """
     n, d = vectors.shape
     Q = q_vecs.shape[0]
@@ -149,7 +156,7 @@ def walk_batch(vectors, adjacency, pass_bm, q_vecs, seeds,
     def cond(s):
         return (s["t"] < p.max_hops) & jnp.any(s["term"] == TERM_RUNNING)
 
-    def body(s):
+    def hop(s):
         active = s["term"] == TERM_RUNNING
         phase = s["phase"]
         f_empty = s["frontier_v"][:, 0] >= INF / 2
@@ -252,11 +259,15 @@ def walk_batch(vectors, adjacency, pass_bm, q_vecs, seeds,
                     res_v=res_v, res_i=res_i, phase=new_phase, stall=stall,
                     term=term, hops=hops, p1_hops=p1_hops, t=s["t"] + 1)
 
+    def body(s):
+        with jax.named_scope("walk_hop"):
+            return hop(s)
+
     out = jax.lax.while_loop(cond, body, state)
     term = jnp.where(out["term"] == TERM_RUNNING, TERM_MAXHOP, out["term"])
     return dict(res_v=out["res_v"], res_i=out["res_i"], term=term,
                 hops=out["hops"], p1_hops=out["p1_hops"],
-                visited_bm=out["visited"])
+                visited_bm=out["visited"], iters=out["t"])
 
 
 def atlas_round(datlas: DeviceAtlas, vectors, adjacency, pass_bm, passes,
@@ -271,19 +282,23 @@ def atlas_round(datlas: DeviceAtlas, vectors, adjacency, pass_bm, passes,
     round. Queries with ``need`` false see an all-processed atlas and so
     get no seeds; a query with no seeds converges on its first walk
     iteration with its results untouched. ``bounds`` rides with the clause
-    tables for interval clauses (None = pure value-set batch)."""
+    tables for interval clauses (None = pure value-set batch). The
+    selection's ops sit under the device scope ``anchor_select``."""
     gate = processed | ~need[:, None]
     tables = ((fields, allowed) if bounds is None
               else (fields, allowed, bounds))
-    seeds, used = datlas.select_anchors_batch(
-        q_vecs, tables, gate, vectors, passes,
-        n_seeds=p.n_seeds, c_max=p.c_max, disjunct_quota=p.disjunct_quota)
+    with jax.named_scope("anchor_select"):
+        seeds, used = datlas.select_anchors_batch(
+            q_vecs, tables, gate, vectors, passes,
+            n_seeds=p.n_seeds, c_max=p.c_max,
+            disjunct_quota=p.disjunct_quota)
     out = walk_batch(vectors, adjacency, pass_bm, q_vecs, seeds, p,
                      init_results=(res_v, res_i))
     found = (out["res_v"] < INF / 2).sum(axis=1)
     return dict(res_v=out["res_v"], res_i=out["res_i"],
                 processed=processed | used, need=need & (found < p.k),
-                seeded=seeds[:, 0] >= 0, hops=out["hops"])
+                seeded=seeds[:, 0] >= 0, hops=out["hops"],
+                iters=out["iters"])
 
 
 def search_batch(datlas: DeviceAtlas, vectors, adjacency, metadata, q_vecs,
@@ -321,7 +336,8 @@ def search_batch(datlas: DeviceAtlas, vectors, adjacency, metadata, q_vecs,
         res_v=jnp.full((Q, p.k), INF),
         res_i=jnp.full((Q, p.k), -1, jnp.int32),
         hops=jnp.zeros(Q, jnp.int32), walks=jnp.zeros(Q, jnp.int32),
-        r=jnp.asarray(0, jnp.int32), go=jnp.asarray(True))
+        iters=jnp.asarray(0, jnp.int32), r=jnp.asarray(0, jnp.int32),
+        go=jnp.asarray(True))
 
     def cond(c):
         return c["go"] & (c["r"] < rounds)
@@ -341,12 +357,12 @@ def search_batch(datlas: DeviceAtlas, vectors, adjacency, metadata, q_vecs,
         walks = c["walks"] + jnp.where(any_seeded,
                                        seeded.astype(jnp.int32), 0)
         return dict(processed=processed, need=need, res_v=res_v, res_i=res_i,
-                    hops=hops, walks=walks, r=c["r"] + 1,
-                    go=any_seeded & need.any())
+                    hops=hops, walks=walks, iters=c["iters"] + out["iters"],
+                    r=c["r"] + 1, go=any_seeded & need.any())
 
     out = jax.lax.while_loop(cond, body, init)
     return dict(res_v=out["res_v"], res_i=out["res_i"], hops=out["hops"],
-                walks=out["walks"])
+                walks=out["walks"], rounds=out["r"], iters=out["iters"])
 
 
 def clause_dim(n_clauses: int) -> int:
@@ -431,7 +447,7 @@ def pack_query_batch(queries: list[Query], *, v_cap: int,
     return q_vecs, jnp.asarray(f_np), jnp.asarray(a_np), bounds
 
 
-def _fence_pack(eng, queries: list[Query]):
+def _fence_pack(eng, queries: list[Query], batch: int):
     """Publish-generation fence (DESIGN.md §13), shared by both engines.
 
     Pack the batch, then check the engine's ``publish_generation`` — the
@@ -441,14 +457,52 @@ def _fence_pack(eng, queries: list[Query]):
     to bind may be mid-swap: re-pack against the new state and try again.
     ``faults.fire("serve.pre-dispatch")`` sits in the window so tests can
     script the interleaving. Returns ``(packed, generation)`` with
-    ``generation == eng.publish_generation`` at return time."""
-    while True:
-        gen = eng.publish_generation
-        packed = eng._pack_queries(queries)
-        faults.fire("serve.pre-dispatch")
-        if eng.publish_generation == gen:
-            return packed, gen
-        eng.fence_retries += 1
+    ``generation == eng.publish_generation`` at return time. The host
+    span ``fns.pack`` covers it, with the batch's fence ``retries``."""
+    retries = 0
+    with TraceAnnotation("fns.pack", batch=batch) as span:
+        while True:
+            gen = eng.publish_generation
+            packed = eng._pack_queries(queries)
+            faults.fire("serve.pre-dispatch")
+            if eng.publish_generation == gen:
+                span.set_metadata(retries=retries)
+                return packed, gen
+            retries += 1
+            eng.fence_retries += 1
+
+
+def fetch_results(token: dict, finish=None):
+    """Sync an in-flight batch, shared by both engines: the batch's one
+    host sync (host span ``fns.fetch``), then the result unpack (host span
+    ``fns.unpack``, which carries the batch's ``rounds`` and ``iters``).
+
+    ``token`` holds the program's output (``out``), the query count to
+    keep (``q_n``), the batch number (``batch``), and optionally the
+    global-id map (``gids``) and the publish generation (``generation``).
+    The per-lane ``rounds``/``iters`` of a sharded program reduce to their
+    max. ``finish(ids, stats)``, when given, runs inside the unpack span
+    and its value is returned."""
+    batch = token["batch"]
+    with TraceAnnotation("fns.fetch", batch=batch):
+        host = jax.device_get(token["out"])
+    rounds = int(np.max(host["rounds"]))
+    iters = int(np.max(host["iters"]))
+    with TraceAnnotation("fns.unpack", batch=batch, rounds=rounds,
+                         iters=iters):
+        q_n = token["q_n"]
+        res_v, res_i = host["res_v"], host["res_i"]
+        ids = [res_i[i][res_v[i] < INF / 2] for i in range(q_n)]
+        g = token.get("gids")
+        if g is not None:
+            ids = [g[i] for i in ids]
+        # [:q_n] drops the inert lane-pad rows a 2D dispatch may append
+        stats = {"walks": host["walks"][:q_n].astype(np.int32),
+                 "hops": host["hops"][:q_n].astype(np.int64),
+                 "rounds": rounds, "iters": iters}
+        if "generation" in token:
+            stats["generation"] = token["generation"]
+        return (ids, stats) if finish is None else finish(ids, stats)
 
 
 class BatchedEngine:
@@ -623,8 +677,11 @@ class BatchedEngine:
         self._round = jax.jit(
             functools.partial(atlas_round, p=params, kcfg=kcfg),
             donate_argnums=(8, 9, 10, 11) if on_tpu() else ())
-        self._search = jax.jit(
-            functools.partial(search_batch, p=params, kcfg=kcfg))
+        # named after search_batch, so the trace's module says which
+        # program ran and its recorded scopes (scopes.py) are its own
+        self._search = jax.jit(functools.update_wrapper(
+            functools.partial(search_batch, p=params, kcfg=kcfg),
+            search_batch))
         self._passes = jax.jit(functools.partial(_eval_passes, kcfg=kcfg))
         self.dispatches = 0
         self.publish_generation = getattr(self, "publish_generation", 0)
@@ -716,51 +773,51 @@ class BatchedEngine:
         g = self._state.shards[0].global_ids
         return [g[i] for i in ids]
 
-    def dispatch(self, queries: list[Query], seed: int = 0) -> dict:
+    def dispatch(self, queries: list[Query], seed: int = 0, *,
+                 batch: int = -1) -> dict:
         """Fenced pack + ONE jitted call; returns an in-flight token
         without syncing the host. jax's async dispatch means the device
         crunches batch N while the host packs batch N+1 — the overlap the
         serve pipeline (serve/pipeline.py) is built on. The token snapshots
         the global-id map and the publish generation, so a compaction that
         remaps rows between dispatch and collect can't mistranslate the
-        in-flight batch's results."""
+        in-flight batch's results. ``batch`` is the caller's batch number,
+        carried by the host spans (``fns.pack``, ``fns.dispatch``, and at
+        collect ``fns.fetch``, ``fns.unpack``); -1 outside a service."""
         del seed
-        (q_vecs, fields, allowed, bounds), gen = _fence_pack(self, queries)
-        out = self._search(self.datlas, self.vectors, self.adjacency,
-                           self.metadata, q_vecs, fields, allowed,
-                           valid_bm=self._valid_bm, bounds=bounds)
+        (q_vecs, fields, allowed, bounds), gen = _fence_pack(self, queries,
+                                                             batch)
+        out = dispatch_program(self._search, batch, self.datlas,
+                               self.vectors, self.adjacency, self.metadata,
+                               q_vecs, fields, allowed,
+                               valid_bm=self._valid_bm, bounds=bounds)
         self.dispatches += 1
         gids = (self._state.shards[0].global_ids.copy()
                 if self._state is not None else None)
         return {"out": out, "q_n": len(queries), "generation": gen,
-                "gids": gids}
+                "gids": gids, "batch": batch}
 
-    def collect(self, token: dict):
+    def collect(self, token: dict, finish=None):
         """Sync an in-flight ``dispatch`` token: the batch's single host
-        sync + result/stat post-processing. ``stats["generation"]`` is the
-        scalar publish generation the batch was dispatched against."""
-        host = jax.device_get(token["out"])
-        q_n = token["q_n"]
-        res_v, res_i = host["res_v"], host["res_i"]
-        raw = [res_i[i][res_v[i] < INF / 2] for i in range(q_n)]
-        g = token["gids"]
-        ids = raw if g is None else [g[i] for i in raw]
-        stats = {"walks": host["walks"][:q_n].astype(np.int32),
-                 "hops": host["hops"][:q_n].astype(np.int64),
-                 "generation": token["generation"]}
-        return ids, stats
+        sync + result/stat post-processing (``fetch_results``).
+        ``stats["generation"]`` is the scalar publish generation the batch
+        was dispatched against; ``stats["rounds"]``/``stats["iters"]`` the
+        restart rounds and lockstep iterations the program ran."""
+        return fetch_results(token, finish)
 
-    def search(self, queries: list[Query], seed: int = 0):
+    def search(self, queries: list[Query], seed: int = 0, *,
+               batch: int = -1, finish=None):
         """Filtered top-k for a batch: one device dispatch, one host sync.
         ``seed`` is kept for API compat; the device path is deterministic
         (seeds are nearest matching members, never random samples)."""
         del seed
-        return self.collect(self.dispatch(queries))
+        return self.collect(self.dispatch(queries, batch=batch), finish)
 
     def search_hostloop(self, queries: list[Query], seed: int = 0):
         """PR 1 semantics: host round loop, one jitted select+walk call and
         two scalar syncs per round. Kept as the exact-parity baseline for
-        ``search`` (tests) and for incremental debugging."""
+        ``search`` (tests) and for incremental debugging; it counts
+        ``rounds`` and ``iters`` from its own loop."""
         del seed
         p = self.p
         Q = len(queries)
@@ -774,12 +831,15 @@ class BatchedEngine:
         need = popcount(pass_bm) > 0  # mirror search_batch's need init
         res_v = jnp.full((Q, p.k), INF)
         res_i = jnp.full((Q, p.k), -1, jnp.int32)
-        stats = {"walks": np.zeros(Q, np.int32), "hops": np.zeros(Q, np.int64)}
+        stats = {"walks": np.zeros(Q, np.int32), "hops": np.zeros(Q, np.int64),
+                 "rounds": 0, "iters": 0}
         for _ in range(p.jump_budget + 1):
             out = self._round(self.datlas, self.vectors, self.adjacency,
                               pass_bm, passes, q_vecs, fields, allowed,
                               processed, need, res_v, res_i, bounds=bounds)
             self.dispatches += 1
+            stats["rounds"] += 1
+            stats["iters"] += int(out["iters"])
             seeded = np.asarray(out["seeded"])
             # the buffers donated into the call are dead now: rebind results
             # before any break (a no-seed round leaves them bitwise

@@ -31,15 +31,18 @@ import functools
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 from jax.sharding import PartitionSpec as P
 
 from repro.core.atlas import AnchorAtlas
 from repro.core.batched.bitmap import pack_bits
-from repro.core.batched.engine import (INF, BatchedParams, _fence_pack,
-                                       pack_query_batch, search_batch)
+from repro.core.batched.engine import (BatchedParams, _fence_pack,
+                                       fetch_results, pack_query_batch,
+                                       search_batch)
 from repro.core.config import FnsConfig, coerce_config
 from repro.core.batched.insert import (InsertState, emit_device_atlas,
                                        insert_rows, make_shard_state)
+from repro.core.batched.scopes import dispatch_program
 from repro.core.device_atlas import (DeviceAtlas, auto_v_cap,
                                      stack_atlases)
 from repro.core.graph import build_shard_graphs
@@ -290,7 +293,7 @@ class ShardedEngine:
         kcfg = self.cfg.kernel
         nl, tdef = len(self._leaves), self._tdef
 
-        def fn(*args):
+        def sharded_search(*args):
             leaves, rest = args[:nl], args[nl:]
             vectors, adjacency, metadata, global_ids, valid_bm = rest[:5]
             q_vecs, fields, allowed = rest[5:8]
@@ -306,9 +309,13 @@ class ShardedEngine:
             all_v = jax.lax.all_gather(out["res_v"], axis)
             all_i = jax.lax.all_gather(gids, axis)
             res_v, res_i = merge_topk(all_v, all_i, p.k)
+            # the slowest shard's rounds and iterations, one per query lane
+            # so that they follow the queries' layout
             return dict(res_v=res_v, res_i=res_i,
                         hops=jax.lax.psum(out["hops"], axis),
-                        walks=jax.lax.psum(out["walks"], axis))
+                        walks=jax.lax.psum(out["walks"], axis),
+                        rounds=jax.lax.pmax(out["rounds"], axis)[None],
+                        iters=jax.lax.pmax(out["iters"], axis)[None])
 
         # index leaves are partitioned row-wise over the data axis; the
         # query tensors (and the bounds table, when the batch carries
@@ -320,10 +327,11 @@ class ShardedEngine:
         q_spec = P(self.q_axis) if self.q_axis is not None else P()
         n_q = 4 if has_bounds else 3
         in_specs = tuple([P(axis)] * (nl + 5) + [q_spec] * n_q)
-        out_specs = dict(res_v=q_spec, res_i=q_spec,
-                         hops=q_spec, walks=q_spec)
-        return jax.jit(jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False))
+        out_specs = dict(res_v=q_spec, res_i=q_spec, hops=q_spec,
+                         walks=q_spec, rounds=q_spec, iters=q_spec)
+        return jax.jit(jax.shard_map(sharded_search, mesh=self.mesh,
+                                     in_specs=in_specs, out_specs=out_specs,
+                                     check_vma=False))
 
     def insert_batch(self, vectors: np.ndarray, metadata: np.ndarray, *,
                      gids: np.ndarray | None = None) -> np.ndarray:
@@ -445,14 +453,6 @@ class ShardedEngine:
         """Ingest/staleness accounting, or None on a build-once index."""
         return self._istate.stats() if self._istate is not None else None
 
-    def _fetch(self, out, q_n: int):
-        host = jax.device_get(out)  # the batch's single host sync
-        res_v, res_i = host["res_v"], host["res_i"]
-        ids = [res_i[i][res_v[i] < INF / 2] for i in range(q_n)]
-        # [:q_n] drops the inert lane-pad rows a 2D dispatch may append
-        return ids, {"walks": host["walks"][:q_n].astype(np.int32),
-                     "hops": host["hops"][:q_n].astype(np.int64)}
-
     def _pack_queries(self, queries: list[Query]):
         return pack_query_batch(queries, v_cap=self.v_cap,
                                 vocab_sizes=self.vocab_sizes)
@@ -471,48 +471,55 @@ class ShardedEngine:
         dummy = Query(vector=basis, predicate=FilterExpr.never())
         return list(queries) + [dummy] * (self.q_lanes - rem)
 
-    def dispatch(self, queries: list[Query], seed: int = 0) -> dict:
+    def dispatch(self, queries: list[Query], seed: int = 0, *,
+                 batch: int = -1) -> dict:
         """Fenced pack + ONE jitted shard_map call; returns an in-flight
-        token without syncing the host (see BatchedEngine.dispatch). The
-        packed query tensors are staged onto the mesh's query sharding
-        explicitly, so batch N+1's host->device transfer overlaps batch
-        N's device time. Reference mode (mesh=None) dispatches the
-        shard-at-a-time program instead — same token contract."""
+        token without syncing the host (see BatchedEngine.dispatch, also
+        for ``batch``). The packed query tensors are staged onto the mesh's
+        query sharding explicitly, so batch N+1's host->device transfer
+        overlaps batch N's device time. Reference mode (mesh=None)
+        dispatches the shard-at-a-time program instead — same token
+        contract."""
         del seed
         q_n = len(queries)
         padded = self._pad_to_lanes(queries)
-        packed, gen = _fence_pack(self, padded)
+        packed, gen = _fence_pack(self, padded, batch)
         q_vecs, fields, allowed, bounds = packed
+        token = {"q_n": q_n, "generation": gen, "batch": batch}
         if self.mesh is None:
-            out = self._run_reference(q_vecs, fields, allowed, bounds)
+            with TraceAnnotation("fns.dispatch", batch=batch):
+                token["out"] = self._run_reference(q_vecs, fields, allowed,
+                                                   bounds)
             self.dispatches += self.n_shards
-            return {"out": out, "q_n": q_n, "generation": gen}
+            return token
         q_args = [self._q_put(a) for a in (q_vecs, fields, allowed)]
         args = (*self._leaves, self.vectors, self.adjacency,
                 self.metadata, self.global_ids, self.valid_bm, *q_args)
         if bounds is None:
-            out = self._search(*args)
+            program = self._search
         else:
             if self._search_iv is None:
                 self._search_iv = self._build_program(has_bounds=True)
-            out = self._search_iv(*args, self._q_put(bounds))
+            program = self._search_iv
+            args = (*args, self._q_put(bounds))
+        token["out"] = dispatch_program(program, batch, *args)
         self.dispatches += 1
-        return {"out": out, "q_n": q_n, "generation": gen}
+        return token
 
-    def collect(self, token: dict):
+    def collect(self, token: dict, finish=None):
         """Sync an in-flight ``dispatch`` token: one host sync + result
-        post-processing. ``stats["generation"]`` is the scalar publish
-        generation the batch was dispatched against."""
-        ids, stats = self._fetch(token["out"], token["q_n"])
-        stats["generation"] = token["generation"]
-        return ids, stats
+        post-processing (``engine.fetch_results``). ``stats["generation"]``
+        is the scalar publish generation the batch was dispatched against;
+        ``stats["rounds"]``/``stats["iters"]`` the slowest shard's."""
+        return fetch_results(token, finish)
 
-    def search(self, queries: list[Query], seed: int = 0):
+    def search(self, queries: list[Query], seed: int = 0, *,
+               batch: int = -1, finish=None):
         """Filtered top-k for a batch across all shards: one device
         dispatch, one host sync. Stats sum device work over shards (every
         shard walks every query)."""
         del seed
-        return self.collect(self.dispatch(queries))
+        return self.collect(self.dispatch(queries, batch=batch), finish)
 
     def _run_reference(self, q_vecs, fields, allowed, bounds):
         """Shard-at-a-time device program behind both the reference-mode
@@ -522,7 +529,7 @@ class ShardedEngine:
         # mesh-sharded inputs would be partitioned, and a Pallas kernel
         # cannot be partitioned automatically
         local = functools.partial(jax.device_put, device=jax.devices()[0])
-        per_v, per_i, hops, walks = [], [], 0, 0
+        per_v, per_i, hops, walks, rounds, iters = [], [], 0, 0, 0, 0
         for s in range(self.n_shards):
             datlas = jax.tree_util.tree_unflatten(
                 self._tdef, [local(l[s]) for l in self._leaves])
@@ -536,9 +543,12 @@ class ShardedEngine:
                 local(self.global_ids[s])[jnp.maximum(out["res_i"], 0)], -1))
             hops = hops + out["hops"]
             walks = walks + out["walks"]
+            rounds = jnp.maximum(rounds, out["rounds"])
+            iters = jnp.maximum(iters, out["iters"])
         res_v, res_i = merge_topk(jnp.stack(per_v), jnp.stack(per_i),
                                   self.p.k)
-        return dict(res_v=res_v, res_i=res_i, hops=hops, walks=walks)
+        return dict(res_v=res_v, res_i=res_i, hops=hops, walks=walks,
+                    rounds=rounds, iters=iters)
 
     def search_reference(self, queries: list[Query]):
         """Single-device fused baseline: the identical per-shard
@@ -547,5 +557,5 @@ class ShardedEngine:
         The mesh path must match this bit-for-bit (tested at selectivities
         {0.5, 0.1, 0.02} on 1D and 2D meshes)."""
         q_vecs, fields, allowed, bounds = self._pack_queries(queries)
-        return self._fetch(self._run_reference(q_vecs, fields, allowed,
-                                               bounds), len(queries))
+        out = self._run_reference(q_vecs, fields, allowed, bounds)
+        return fetch_results({"out": out, "q_n": len(queries), "batch": -1})

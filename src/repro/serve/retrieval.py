@@ -8,9 +8,11 @@ metadata-filtered nearest-neighbour requests with drift-guided search.
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ArchConfig
 from repro.core.atlas import AnchorAtlas
@@ -81,6 +83,9 @@ class RetrievalService:
     # background maintenance (DESIGN.md §12), built lazily on first
     # maintenance_step — owns the deferred-repair/compaction schedule
     _mloop: object | None = dataclasses.field(default=None, repr=False)
+    # batches formed so far: the next batch's number, which every host
+    # span of that batch (fns.form ... fns.unpack) carries as ``batch``
+    _batches: int = dataclasses.field(default=0, repr=False)
 
     @staticmethod
     def build(ds: Dataset, *, config: FnsConfig | None = None,
@@ -265,16 +270,18 @@ class RetrievalService:
         formed = self._form_batch(vectors, predicates, bucket=bucket)
         if formed is None:
             return [], {}
-        eng, queries, q_real, errors = formed
-        ids, stats = eng.search(queries)
-        return self._finish_batch(eng, ids, stats, q_real, len(queries),
-                                  errors)
+        eng, queries, q_real, errors, batch = formed
+        return eng.search(queries, batch=batch,
+                          finish=functools.partial(
+                              self._finish_batch, eng, q_real=q_real,
+                              q_padded=len(queries), errors=errors))
 
     def _form_batch(self, vectors, predicates, *, bucket: bool):
         """Shared batch former for ``query_batch`` and ``dispatch_batch``:
         validate, per-query predicate compile (failures isolated into the
-        errors list), normalize, and bucket-pad. Returns
-        (engine, queries, q_real, errors), or None for an empty batch."""
+        errors list), normalize, and bucket-pad, under the host span
+        ``fns.form``. Returns (engine, queries, q_real, errors, batch
+        number), or None for an empty batch."""
         if len(vectors) != len(predicates):
             raise ValueError(
                 f"query_batch got {len(vectors)} vectors but "
@@ -283,44 +290,51 @@ class RetrievalService:
         q_real = len(predicates)
         if q_real == 0:
             return None
-        eng = self._live_engine()
-        v_cap = eng.v_cap if hasattr(eng, "v_cap") else eng.datlas.v_cap
-        errors: list[str | None] = [None] * q_real
-        checked = []
-        for i, p in enumerate(predicates):
-            try:
-                _compile_query_dnf(p, eng.vocab_sizes, v_cap)
-                checked.append(p)
-            except ValueError as e:
-                errors[i] = str(e)
-                checked.append(FilterExpr.never())
-        queries = [Query(vector=v, predicate=p)
-                   for v, p in zip(normalize(vectors), checked)]
-        if bucket:
-            lanes = getattr(eng, "q_lanes", 1)
-            target = max(MIN_BUCKET, 1 << (q_real - 1).bit_length())
-            # round the bucket UP to a multiple of the query-axis size so
-            # a 2D-mesh dispatch needs no extra lane padding and every
-            # lane walks the same block height (DESIGN.md §13)
-            target = -(-target // lanes) * lanes
-            if target > q_real:
-                # unit basis vector, NOT zeros: a zero vector has zero
-                # norm, so cosine normalization would turn it into NaNs
-                # that poison the lane's all-gather top-k merge; the pad
-                # stays inert through FilterExpr.never() regardless
-                basis = np.zeros_like(queries[0].vector)
-                basis[0] = 1.0
-                dummy = Query(vector=basis, predicate=FilterExpr.never())
-                queries = queries + [dummy] * (target - q_real)
-        return eng, queries, q_real, errors
+        batch = self._batches
+        self._batches += 1
+        with TraceAnnotation("fns.form", batch=batch,
+                             queries=q_real) as span:
+            eng = self._live_engine()
+            v_cap = eng.v_cap if hasattr(eng, "v_cap") else eng.datlas.v_cap
+            errors: list[str | None] = [None] * q_real
+            checked = []
+            for i, p in enumerate(predicates):
+                try:
+                    _compile_query_dnf(p, eng.vocab_sizes, v_cap)
+                    checked.append(p)
+                except ValueError as e:
+                    errors[i] = str(e)
+                    checked.append(FilterExpr.never())
+            queries = [Query(vector=v, predicate=p)
+                       for v, p in zip(normalize(vectors), checked)]
+            if bucket:
+                lanes = getattr(eng, "q_lanes", 1)
+                target = max(MIN_BUCKET, 1 << (q_real - 1).bit_length())
+                # round the bucket UP to a multiple of the query-axis size
+                # so a 2D-mesh dispatch needs no extra lane padding and
+                # every lane walks the same block height (DESIGN.md §13)
+                target = -(-target // lanes) * lanes
+                if target > q_real:
+                    # unit basis vector, NOT zeros: a zero vector has zero
+                    # norm, so cosine normalization would turn it into NaNs
+                    # that poison the lane's all-gather top-k merge; the
+                    # pad stays inert through FilterExpr.never() regardless
+                    basis = np.zeros_like(queries[0].vector)
+                    basis[0] = 1.0
+                    dummy = Query(vector=basis,
+                                  predicate=FilterExpr.never())
+                    queries = queries + [dummy] * (target - q_real)
+            span.set_metadata(lanes=len(queries))
+        return eng, queries, q_real, errors, batch
 
-    def _finish_batch(self, eng, ids, stats, q_real: int, q_padded: int,
+    def _finish_batch(self, eng, ids, stats, *, q_real: int, q_padded: int,
                       errors):
         """Shared result post-processing: slice ONLY the stats that carry
         a per-query leading axis back to the real queries — scalar and
         aggregate stats (the publish generation, maintenance lag) pass
         through untouched, where the old blanket ``v[:q_real]`` mangled
-        them — then attach the service-level stats."""
+        them — then attach the service-level stats. The engine runs it
+        inside the batch's ``fns.unpack`` span."""
         stats = {k: (v[:q_real]
                      if isinstance(v, np.ndarray) and v.ndim >= 1
                      and len(v) == q_padded else v)
@@ -346,8 +360,8 @@ class RetrievalService:
         formed = self._form_batch(vectors, predicates, bucket=bucket)
         if formed is None:
             return None
-        eng, queries, q_real, errors = formed
-        return {"eng": eng, "token": eng.dispatch(queries),
+        eng, queries, q_real, errors, batch = formed
+        return {"eng": eng, "token": eng.dispatch(queries, batch=batch),
                 "q_real": q_real, "q_padded": len(queries),
                 "errors": errors}
 
@@ -359,10 +373,10 @@ class RetrievalService:
         corrupt this batch's results."""
         if ticket is None:
             return [], {}
-        ids, stats = ticket["eng"].collect(ticket["token"])
-        return self._finish_batch(ticket["eng"], ids, stats,
-                                  ticket["q_real"], ticket["q_padded"],
-                                  ticket["errors"])
+        eng = ticket["eng"]
+        return eng.collect(ticket["token"], finish=functools.partial(
+            self._finish_batch, eng, q_real=ticket["q_real"],
+            q_padded=ticket["q_padded"], errors=ticket["errors"]))
 
     def _validate_ingest(self, vectors, metadata,
                          eng) -> tuple[np.ndarray, np.ndarray]:

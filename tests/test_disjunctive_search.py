@@ -292,11 +292,14 @@ def test_bucket_pads_are_never_and_inert_under_disjunctions():
     captured = {}
     orig = eng.search
 
-    def spy(queries, **kw):
-        out = orig(queries, **kw)
+    def spy(queries, finish=None, **kw):
+        # keep the engine's own output, before the service slices it
+        def keep(ids, stats):
+            captured["out"] = (ids, stats)
+            return finish(ids, stats)
+
         captured["queries"] = queries
-        captured["out"] = out
-        return out
+        return orig(queries, finish=keep, **kw)
 
     eng.search = spy
     try:

@@ -14,8 +14,8 @@ from conftest import SELECTIVITIES
 
 def test_fused_matches_hostloop_exactly(sel_sweep):
     """search (one fused dispatch) == search_hostloop (PR 1 per-round jit):
-    same ids in the same order, same per-query walks and hops, at every
-    selectivity in the sweep."""
+    same ids in the same order, same per-query walks and hops, the same
+    rounds and lockstep iterations, at every selectivity in the sweep."""
     _, index, queries = sel_sweep
     eng = BatchedEngine(index, BatchedParams(k=10, beam_width=4))
     ids_f, st_f = eng.search(queries)
@@ -26,6 +26,12 @@ def test_fused_matches_hostloop_exactly(sel_sweep):
             (i, queries[i].selectivity)
     np.testing.assert_array_equal(st_f["walks"], st_h["walks"])
     np.testing.assert_array_equal(st_f["hops"], st_h["hops"])
+    # the device's own loop counters equal the host loop's count of its
+    # rounds and of the lockstep iterations each round's walk ran
+    assert (st_f["rounds"], st_f["iters"]) == (st_h["rounds"],
+                                               st_h["iters"])
+    assert 1 <= st_f["rounds"] <= eng.p.jump_budget + 1
+    assert st_f["iters"] >= int(st_f["hops"].max())
     # the sweep exercises all three selectivity levels and restarts
     sels = sorted({q.selectivity for q in queries}, reverse=True)
     for got, want in zip(sels, SELECTIVITIES):
