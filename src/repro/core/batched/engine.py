@@ -21,7 +21,10 @@ and validated for recall parity in tests):
 * queues hold only first-seen nodes (a node enters exactly one queue once);
 * the phase-1 -> 2 fallback seeds the beam from (frontier ∪ this
   expansion's neighbours) rather than "all seen unexpanded nodes";
-* converged queries idle (masked) until the batch drains.
+* the walk narrows in stages (Q, Q/2, ... down to 8 lanes): once the
+  running lanes fit in half the width they are gathered into it, so a
+  finished query leaves the hop instead of idling masked until the
+  batch drains.
 """
 from __future__ import annotations
 
@@ -52,6 +55,8 @@ INF = jnp.float32(3.4e38)
 HIGHEST = jax.lax.Precision.HIGHEST
 
 TERM_RUNNING, TERM_CONVERGED, TERM_EARLY, TERM_STALL, TERM_MAXHOP = 0, 1, 2, 3, 4
+# the narrowest stage of the walk: the f32 sublane count
+LANE_FLOOR = 8
 
 # the walk-budget section of the unified config tree (core/config.py) IS
 # the engine's parameter object; the historical name stays importable and
@@ -96,6 +101,23 @@ def _eval_passes(metadata, fields, allowed, bounds=None,
                                      bounds)
 
 
+def stage_widths(Q: int) -> tuple[int, ...]:
+    """Lane widths of the walk's stages for a batch of Q lanes: Q, then
+    halves while a half holds ``LANE_FLOOR`` lanes."""
+    widths = [Q]
+    while widths[-1] // 2 >= LANE_FLOOR:
+        widths.append(widths[-1] // 2)
+    return tuple(widths)
+
+
+def _running_first(term, width: int):
+    """Indices of ``width`` lanes: the running ones, then the rest, each in
+    lane order (a stable compaction)."""
+    W = term.shape[0]
+    key = jnp.where(term == TERM_RUNNING, W, 0) + (W - 1 - jnp.arange(W))
+    return jax.lax.top_k(key, width)[1]
+
+
 def walk_batch(vectors, adjacency, pass_bm, q_vecs, seeds,
                p: BatchedParams, init_results=None):
     """One lockstep walk round.
@@ -103,10 +125,20 @@ def walk_batch(vectors, adjacency, pass_bm, q_vecs, seeds,
     vectors (n, d) f32; adjacency (n, R) i32 (-1 pad); pass_bm
     (Q, ceil(n/32)) uint32 packed filter bitmaps; q_vecs (Q, d); seeds
     (Q, S) i32 (-1 pad). Returns dict of results + diagnostics, among
-    them ``iters``, the lockstep iterations the loop ran. All per-point
-    walk state (visited / in-results / pass) is bitmap-packed: O(Q*n/32)
-    bytes instead of three dense (Q, n) bool masks. Each iteration's ops
-    sit under the device scope ``walk_hop``.
+    them ``iters``, the lockstep iterations the loop ran, and ``slots``,
+    the lanes those iterations computed (Σ of each iteration's width).
+    All per-point walk state (visited / in-results / pass) is
+    bitmap-packed: O(Q*n/32) bytes instead of three dense (Q, n) bool
+    masks. Each iteration's ops sit under the device scope ``walk_hop``.
+
+    The walk narrows in stages (``stage_widths``): a stage at width W
+    runs while more than the next stage's width of its lanes are running;
+    then the running lanes, in lane order, are gathered into the next
+    stage (one whose lanes already fit runs no iteration and hands them
+    on), and the narrow state is scattered back when it ends.
+    Lanes are independent and a finished lane's returned state is frozen,
+    so every output is what one full-width loop gives. A lane with no
+    valid seed starts converged, with hops 0 and its results untouched.
     """
     n, d = vectors.shape
     Q = q_vecs.shape[0]
@@ -145,18 +177,20 @@ def walk_batch(vectors, adjacency, pass_bm, q_vecs, seeds,
     res_v, res_i = _merge_queue(res0_v, res0_i,
                                 jnp.where(seed_pass, seed_v, INF), seeds, k)
 
-    state = dict(
+    lanes = dict(
         visited=visited, frontier_v=frontier_v, frontier_i=frontier_i,
         beam_v=beam_v, beam_i=beam_i, res_v=res_v, res_i=res_i,
         phase=jnp.ones((Q,), jnp.int32), stall=jnp.zeros((Q,), jnp.int32),
-        term=jnp.zeros((Q,), jnp.int32), hops=jnp.zeros((Q,), jnp.int32),
-        p1_hops=jnp.zeros((Q,), jnp.int32), t=jnp.asarray(0, jnp.int32),
+        # an unseeded lane would converge at its first check, untouched
+        term=jnp.where(seed_valid.any(axis=1), TERM_RUNNING,
+                       TERM_CONVERGED).astype(jnp.int32),
+        hops=jnp.zeros((Q,), jnp.int32), p1_hops=jnp.zeros((Q,), jnp.int32),
     )
+    # the per-lane constants the hop reads, gathered with the lanes
+    consts = dict(q_vecs=q_vecs, pass_bm=pass_bm, in_res=in_res)
 
-    def cond(s):
-        return (s["t"] < p.max_hops) & jnp.any(s["term"] == TERM_RUNNING)
-
-    def hop(s):
+    def hop(s, c):
+        q_vecs, pass_bm, in_res = c["q_vecs"], c["pass_bm"], c["in_res"]
         active = s["term"] == TERM_RUNNING
         phase = s["phase"]
         f_empty = s["frontier_v"][:, 0] >= INF / 2
@@ -257,17 +291,39 @@ def walk_batch(vectors, adjacency, pass_bm, q_vecs, seeds,
         return dict(visited=visited, frontier_v=frontier_v,
                     frontier_i=frontier_i, beam_v=beam_v, beam_i=beam_i,
                     res_v=res_v, res_i=res_i, phase=new_phase, stall=stall,
-                    term=term, hops=hops, p1_hops=p1_hops, t=s["t"] + 1)
+                    term=term, hops=hops, p1_hops=p1_hops)
 
-    def body(s):
-        with jax.named_scope("walk_hop"):
-            return hop(s)
+    def stage(s, c, t, slots, widths):
+        """Walk at width widths[0] while more lanes run than the next
+        width holds, then hand the running lanes to the next stage."""
+        W = widths[0]
+        nxt = widths[1] if len(widths) > 1 else 0
 
-    out = jax.lax.while_loop(cond, body, state)
+        def cond(carry):
+            s, t, _ = carry
+            running = (s["term"] == TERM_RUNNING).sum()
+            return (t < p.max_hops) & (running > nxt)
+
+        def body(carry):
+            s, t, slots = carry
+            with jax.named_scope("walk_hop"):
+                return hop(s, c), t + 1, slots + W
+
+        s, t, slots = jax.lax.while_loop(cond, body, (s, t, slots))
+        if not nxt:
+            return s, t, slots
+        idx = _running_first(s["term"], nxt)
+        take = functools.partial(jax.tree.map, lambda x: x[idx])
+        sub, t, slots = stage(take(s), take(c), t, slots, widths[1:])
+        return (jax.tree.map(lambda x, y: x.at[idx].set(y), s, sub),
+                t, slots)
+
+    zero = jnp.asarray(0, jnp.int32)
+    out, t, slots = stage(lanes, consts, zero, zero, stage_widths(Q))
     term = jnp.where(out["term"] == TERM_RUNNING, TERM_MAXHOP, out["term"])
     return dict(res_v=out["res_v"], res_i=out["res_i"], term=term,
                 hops=out["hops"], p1_hops=out["p1_hops"],
-                visited_bm=out["visited"], iters=out["t"])
+                visited_bm=out["visited"], iters=t, slots=slots)
 
 
 def atlas_round(datlas: DeviceAtlas, vectors, adjacency, pass_bm, passes,
@@ -280,8 +336,8 @@ def atlas_round(datlas: DeviceAtlas, vectors, adjacency, pass_bm, passes,
     ``passes`` is its dense (Q, n) bool unpack for the selection math —
     round-invariant, so callers unpack once per batch instead of once per
     round. Queries with ``need`` false see an all-processed atlas and so
-    get no seeds; a query with no seeds converges on its first walk
-    iteration with its results untouched. ``bounds`` rides with the clause
+    get no seeds; a query with no seeds starts the walk converged, with
+    its results untouched. ``bounds`` rides with the clause
     tables for interval clauses (None = pure value-set batch). The
     selection's ops sit under the device scope ``anchor_select``."""
     gate = processed | ~need[:, None]
@@ -298,7 +354,7 @@ def atlas_round(datlas: DeviceAtlas, vectors, adjacency, pass_bm, passes,
     return dict(res_v=out["res_v"], res_i=out["res_i"],
                 processed=processed | used, need=need & (found < p.k),
                 seeded=seeds[:, 0] >= 0, hops=out["hops"],
-                iters=out["iters"])
+                iters=out["iters"], slots=out["slots"])
 
 
 def search_batch(datlas: DeviceAtlas, vectors, adjacency, metadata, q_vecs,
@@ -336,8 +392,8 @@ def search_batch(datlas: DeviceAtlas, vectors, adjacency, metadata, q_vecs,
         res_v=jnp.full((Q, p.k), INF),
         res_i=jnp.full((Q, p.k), -1, jnp.int32),
         hops=jnp.zeros(Q, jnp.int32), walks=jnp.zeros(Q, jnp.int32),
-        iters=jnp.asarray(0, jnp.int32), r=jnp.asarray(0, jnp.int32),
-        go=jnp.asarray(True))
+        iters=jnp.asarray(0, jnp.int32), slots=jnp.asarray(0, jnp.int32),
+        r=jnp.asarray(0, jnp.int32), go=jnp.asarray(True))
 
     def cond(c):
         return c["go"] & (c["r"] < rounds)
@@ -358,11 +414,13 @@ def search_batch(datlas: DeviceAtlas, vectors, adjacency, metadata, q_vecs,
                                        seeded.astype(jnp.int32), 0)
         return dict(processed=processed, need=need, res_v=res_v, res_i=res_i,
                     hops=hops, walks=walks, iters=c["iters"] + out["iters"],
-                    r=c["r"] + 1, go=any_seeded & need.any())
+                    slots=c["slots"] + out["slots"], r=c["r"] + 1,
+                    go=any_seeded & need.any())
 
     out = jax.lax.while_loop(cond, body, init)
     return dict(res_v=out["res_v"], res_i=out["res_i"], hops=out["hops"],
-                walks=out["walks"], rounds=out["r"], iters=out["iters"])
+                walks=out["walks"], rounds=out["r"], iters=out["iters"],
+                slots=out["slots"])
 
 
 def clause_dim(n_clauses: int) -> int:
@@ -475,22 +533,25 @@ def _fence_pack(eng, queries: list[Query], batch: int):
 def fetch_results(token: dict, finish=None):
     """Sync an in-flight batch, shared by both engines: the batch's one
     host sync (host span ``fns.fetch``), then the result unpack (host span
-    ``fns.unpack``, which carries the batch's ``rounds`` and ``iters``).
+    ``fns.unpack``, which carries the batch's ``rounds``, ``iters``,
+    ``slots`` and its queries' Σ ``hops``).
 
     ``token`` holds the program's output (``out``), the query count to
     keep (``q_n``), the batch number (``batch``), and optionally the
     global-id map (``gids``) and the publish generation (``generation``).
-    The per-lane ``rounds``/``iters`` of a sharded program reduce to their
-    max. ``finish(ids, stats)``, when given, runs inside the unpack span
+    The per-lane ``rounds``/``iters``/``slots`` of a sharded program
+    reduce to their max. ``finish(ids, stats)``, when given, runs inside the unpack span
     and its value is returned."""
     batch = token["batch"]
     with TraceAnnotation("fns.fetch", batch=batch):
         host = jax.device_get(token["out"])
+    q_n = token["q_n"]
     rounds = int(np.max(host["rounds"]))
     iters = int(np.max(host["iters"]))
+    slots = int(np.max(host["slots"]))
+    hops = int(host["hops"][:q_n].sum())
     with TraceAnnotation("fns.unpack", batch=batch, rounds=rounds,
-                         iters=iters):
-        q_n = token["q_n"]
+                         iters=iters, slots=slots, hops=hops):
         res_v, res_i = host["res_v"], host["res_i"]
         ids = [res_i[i][res_v[i] < INF / 2] for i in range(q_n)]
         g = token.get("gids")
@@ -499,7 +560,7 @@ def fetch_results(token: dict, finish=None):
         # [:q_n] drops the inert lane-pad rows a 2D dispatch may append
         stats = {"walks": host["walks"][:q_n].astype(np.int32),
                  "hops": host["hops"][:q_n].astype(np.int64),
-                 "rounds": rounds, "iters": iters}
+                 "rounds": rounds, "iters": iters, "slots": slots}
         if "generation" in token:
             stats["generation"] = token["generation"]
         return (ids, stats) if finish is None else finish(ids, stats)
@@ -802,7 +863,8 @@ class BatchedEngine:
         sync + result/stat post-processing (``fetch_results``).
         ``stats["generation"]`` is the scalar publish generation the batch
         was dispatched against; ``stats["rounds"]``/``stats["iters"]`` the
-        restart rounds and lockstep iterations the program ran."""
+        restart rounds and lockstep iterations the program ran,
+        ``stats["slots"]`` the lanes those iterations computed."""
         return fetch_results(token, finish)
 
     def search(self, queries: list[Query], seed: int = 0, *,
@@ -817,7 +879,7 @@ class BatchedEngine:
         """PR 1 semantics: host round loop, one jitted select+walk call and
         two scalar syncs per round. Kept as the exact-parity baseline for
         ``search`` (tests) and for incremental debugging; it counts
-        ``rounds`` and ``iters`` from its own loop."""
+        ``rounds``, ``iters`` and ``slots`` from its own loop."""
         del seed
         p = self.p
         Q = len(queries)
@@ -832,7 +894,7 @@ class BatchedEngine:
         res_v = jnp.full((Q, p.k), INF)
         res_i = jnp.full((Q, p.k), -1, jnp.int32)
         stats = {"walks": np.zeros(Q, np.int32), "hops": np.zeros(Q, np.int64),
-                 "rounds": 0, "iters": 0}
+                 "rounds": 0, "iters": 0, "slots": 0}
         for _ in range(p.jump_budget + 1):
             out = self._round(self.datlas, self.vectors, self.adjacency,
                               pass_bm, passes, q_vecs, fields, allowed,
@@ -840,6 +902,7 @@ class BatchedEngine:
             self.dispatches += 1
             stats["rounds"] += 1
             stats["iters"] += int(out["iters"])
+            stats["slots"] += int(out["slots"])
             seeded = np.asarray(out["seeded"])
             # the buffers donated into the call are dead now: rebind results
             # before any break (a no-seed round leaves them bitwise

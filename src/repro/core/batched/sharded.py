@@ -309,13 +309,14 @@ class ShardedEngine:
             all_v = jax.lax.all_gather(out["res_v"], axis)
             all_i = jax.lax.all_gather(gids, axis)
             res_v, res_i = merge_topk(all_v, all_i, p.k)
-            # the slowest shard's rounds and iterations, one per query lane
-            # so that they follow the queries' layout
+            # the slowest shard's rounds, iterations and slots, one per
+            # query lane so that they follow the queries' layout
             return dict(res_v=res_v, res_i=res_i,
                         hops=jax.lax.psum(out["hops"], axis),
                         walks=jax.lax.psum(out["walks"], axis),
                         rounds=jax.lax.pmax(out["rounds"], axis)[None],
-                        iters=jax.lax.pmax(out["iters"], axis)[None])
+                        iters=jax.lax.pmax(out["iters"], axis)[None],
+                        slots=jax.lax.pmax(out["slots"], axis)[None])
 
         # index leaves are partitioned row-wise over the data axis; the
         # query tensors (and the bounds table, when the batch carries
@@ -328,7 +329,8 @@ class ShardedEngine:
         n_q = 4 if has_bounds else 3
         in_specs = tuple([P(axis)] * (nl + 5) + [q_spec] * n_q)
         out_specs = dict(res_v=q_spec, res_i=q_spec, hops=q_spec,
-                         walks=q_spec, rounds=q_spec, iters=q_spec)
+                         walks=q_spec, rounds=q_spec, iters=q_spec,
+                         slots=q_spec)
         return jax.jit(jax.shard_map(sharded_search, mesh=self.mesh,
                                      in_specs=in_specs, out_specs=out_specs,
                                      check_vma=False))
@@ -510,7 +512,8 @@ class ShardedEngine:
         """Sync an in-flight ``dispatch`` token: one host sync + result
         post-processing (``engine.fetch_results``). ``stats["generation"]``
         is the scalar publish generation the batch was dispatched against;
-        ``stats["rounds"]``/``stats["iters"]`` the slowest shard's."""
+        ``stats["rounds"]``/``stats["iters"]``/``stats["slots"]`` the
+        slowest shard's."""
         return fetch_results(token, finish)
 
     def search(self, queries: list[Query], seed: int = 0, *,
@@ -529,7 +532,8 @@ class ShardedEngine:
         # mesh-sharded inputs would be partitioned, and a Pallas kernel
         # cannot be partitioned automatically
         local = functools.partial(jax.device_put, device=jax.devices()[0])
-        per_v, per_i, hops, walks, rounds, iters = [], [], 0, 0, 0, 0
+        per_v, per_i, hops, walks = [], [], 0, 0
+        rounds = iters = slots = 0
         for s in range(self.n_shards):
             datlas = jax.tree_util.tree_unflatten(
                 self._tdef, [local(l[s]) for l in self._leaves])
@@ -545,10 +549,11 @@ class ShardedEngine:
             walks = walks + out["walks"]
             rounds = jnp.maximum(rounds, out["rounds"])
             iters = jnp.maximum(iters, out["iters"])
+            slots = jnp.maximum(slots, out["slots"])
         res_v, res_i = merge_topk(jnp.stack(per_v), jnp.stack(per_i),
                                   self.p.k)
         return dict(res_v=res_v, res_i=res_i, hops=hops, walks=walks,
-                    rounds=rounds, iters=iters)
+                    rounds=rounds, iters=iters, slots=slots)
 
     def search_reference(self, queries: list[Query]):
         """Single-device fused baseline: the identical per-shard

@@ -47,10 +47,13 @@ SCRIPT = textwrap.dedent("""
     assert np.array_equal(st_m["walks"], st_r["walks"])
     assert np.array_equal(st_m["hops"], st_r["hops"])
     # the slowest shard's loop counters, the same on both paths
-    assert (st_m["rounds"], st_m["iters"]) == (st_r["rounds"],
-                                               st_r["iters"])
+    assert (st_m["rounds"], st_m["iters"], st_m["slots"]) == (
+        st_r["rounds"], st_r["iters"], st_r["slots"])
     # hops sum over the 4 shards, each at most its shard's iterations
     assert st_m["rounds"] >= 1 and 4 * st_m["iters"] >= st_m["hops"].max()
+    # and each shard's hops at most its slots, at most Q lanes an iteration
+    assert 4 * st_m["slots"] >= st_m["hops"].sum()
+    assert st_m["slots"] <= len(queries) * st_m["iters"]
     assert sum(np.asarray(i).size > 0 for i in ids_m) == len(queries)
     # the reference runs on the default device alone, never partitioned
     ref_out = eng._run_reference(*eng._pack_queries(queries))
@@ -137,8 +140,9 @@ def test_sharded_matches_reference_exactly(sharded_setup):
             (i, queries[i].selectivity)
     np.testing.assert_array_equal(st_m["walks"], st_r["walks"])
     np.testing.assert_array_equal(st_m["hops"], st_r["hops"])
-    assert (st_m["rounds"], st_m["iters"]) == (st_r["rounds"],
-                                               st_r["iters"])
+    assert (st_m["rounds"], st_m["iters"], st_m["slots"]) == (
+        st_r["rounds"], st_r["iters"], st_r["slots"])
+    assert 4 * st_m["slots"] >= st_m["hops"].sum()
 
 
 def test_sharded_single_dispatch(sharded_setup):

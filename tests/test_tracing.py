@@ -1,6 +1,6 @@
 """The search path's own measurement: host spans (``fns.*``) on the
 profiler's clock, device scopes in the compiled program's op metadata, and
-the program's loop counters (``rounds``, ``iters``)."""
+the program's loop counters (``rounds``, ``iters``, ``slots``)."""
 import re
 
 import numpy as np
@@ -89,13 +89,18 @@ def test_spans_of_a_batch_share_its_number_and_counters(service, tmp_path):
         assert (int(unpack["rounds"]), int(unpack["iters"])) == (
             stats["rounds"], stats["iters"])
         assert stats["rounds"] >= 1 and stats["iters"] >= stats["hops"].max()
+        # the lanes the walk computed, and the hops of the batch's queries
+        assert (int(unpack["slots"]), int(unpack["hops"])) == (
+            stats["slots"], int(stats["hops"].sum()))
+        assert stats["hops"].sum() <= stats["slots"] <= 8 * stats["iters"]
 
 
 def test_stats_carry_the_counters_without_a_trace(service):
     svc, queries, preds = service
     ids, stats = svc.query_batch(queries, preds)
-    assert len(ids) == 6 and {"rounds", "iters"} <= set(stats)
+    assert len(ids) == 6 and {"rounds", "iters", "slots"} <= set(stats)
     assert isinstance(stats["rounds"], int)
+    assert isinstance(stats["slots"], int)
 
 
 def _op_names(hlo: str) -> list[tuple[str, str]]:
