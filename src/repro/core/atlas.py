@@ -2,7 +2,9 @@
 statistics + inverted cluster index for O(|S|) candidate-cluster retrieval.
 
 Storage is O(n·F) (Lemma 4.1): each point contributes one ``members`` entry
-and at most one ``cluster_index`` insertion per populated field.
+and at most one ``cluster_index`` insertion per populated field. A tag-set
+field (``core.tags``) is indexed by label: a row is a member under each of
+its labels, so a cluster holds a label iff one of its rows carries it.
 """
 from __future__ import annotations
 
@@ -12,6 +14,7 @@ import numpy as np
 
 from repro.core.kmeans import kmeans
 from repro.core.predicate import Interval
+from repro.core.tags import TagFields, label_postings, tag_columns
 from repro.core.types import Dataset, FilterPredicate
 
 
@@ -51,6 +54,8 @@ class AnchorAtlas:
     members: list[dict[int, dict[int, np.ndarray]]]
     # cluster_index[f][v] -> np.ndarray of cluster ids (inverted index)
     cluster_index: list[dict[int, np.ndarray]]
+    # the schema's tag-set fields, indexed by label (core.tags)
+    tag_fields: TagFields = ()
 
     @property
     def n_clusters(self) -> int:
@@ -62,25 +67,37 @@ class AnchorAtlas:
               seed: int = 0) -> "AnchorAtlas":
         k = n_clusters or int(np.ceil(np.sqrt(ds.n)))
         centroids, assign = kmeans(ds.vectors, k, iters=iters, seed=seed)
-        return AnchorAtlas.from_assignment(centroids, assign, ds.metadata)
+        return AnchorAtlas.from_assignment(centroids, assign, ds.metadata,
+                                           ds.tag_fields)
 
     @staticmethod
     def from_assignment(centroids: np.ndarray, assign: np.ndarray,
-                        metadata: np.ndarray) -> "AnchorAtlas":
+                        metadata: np.ndarray,
+                        tag_fields: TagFields = ()) -> "AnchorAtlas":
         """Build the members / inverted-index tables for a GIVEN clustering
         (the single O(n·F) pass of Lemma 4.1). This is the one shared
         construction: ``build`` feeds it a fresh kmeans, the dynamic-insert
-        path feeds it the incrementally maintained assignment."""
+        path feeds it the incrementally maintained assignment. A tag-set
+        field's first column is indexed by label; its other word columns
+        stay empty."""
         k = centroids.shape[0]
         F = metadata.shape[1]
         members: list[dict[int, dict[int, np.ndarray]]] = [
             {f: {} for f in range(F)} for _ in range(k)]
         cluster_index: list[dict[int, list[int]]] = [{} for _ in range(F)]
         order = np.argsort(assign, kind="stable")
+        tagged = tag_columns(tag_fields)
         for f in range(F):
-            col = metadata[:, f]
-            for i in order:
-                v = int(col[i])
+            if f in tagged:
+                if tagged[f][0] != f:
+                    continue  # a later word of a tag-set field
+                rows, labels = label_postings(metadata[order], f,
+                                              tagged[f][1])
+                pairs = zip(order[rows].tolist(), labels.tolist())
+            else:
+                pairs = ((i, int(v)) for i, v in
+                         zip(order.tolist(), metadata[order, f].tolist()))
+            for i, v in pairs:
                 if v < 0:
                     continue  # unpopulated field
                 c = int(assign[i])
@@ -94,7 +111,8 @@ class AnchorAtlas:
                     members[c][f][v] = np.asarray(lst, dtype=np.int32)
         cindex = [{v: np.unique(np.asarray(lst, dtype=np.int32))
                    for v, lst in cluster_index[f].items()} for f in range(F)]
-        return AnchorAtlas(centroids, assign.astype(np.int32), members, cindex)
+        return AnchorAtlas(centroids, assign.astype(np.int32), members, cindex,
+                           tuple(tag_fields))
 
     # -- query-time operations ----------------------------------------------
     def _matching_clusters_conj(self, clauses) -> np.ndarray:

@@ -44,8 +44,11 @@ from repro.core.config import (FnsConfig, KernelConfig, WalkConfig,
 from repro.core.device_atlas import (DeviceAtlas, pack_dnf, pack_predicates,
                                      table_n_disj)
 from repro.core.batched.scopes import dispatch_program
-from repro.core.predicate import DNF, as_dnf, disjunct_selectivity
+from repro.core.predicate import (DNF, And, AnyOf, FilterExpr, HasAny, Not,
+                                  Or, as_dnf, disjunct_selectivity)
 from repro.core.search import FiberIndex, SearchParams
+from repro.core.tags import (check_tag_fields, first_columns, tag_columns,
+                             value_max)
 from repro.core.types import FilterPredicate, Query
 from repro.kernels import ref
 from repro.kernels.filter_eval import filter_eval_batch
@@ -80,7 +83,7 @@ def _pop(q_v, q_i):
 
 
 def _eval_passes(metadata, fields, allowed, bounds=None,
-                 kcfg: KernelConfig | None = None):
+                 kcfg: KernelConfig | None = None, tag_cols=()):
     """Batched predicate evaluation -> packed (Q, ceil(n/32)) uint32 pass
     bitmaps: the filter_eval Pallas corpus sweep on a TPU, the jnp oracle
     on the CPU (``ops.on_tpu`` refuses any other platform). Disjunctive
@@ -89,16 +92,31 @@ def _eval_passes(metadata, fields, allowed, bounds=None,
     conjunctive bitmaps in the same sweep (DESIGN.md §8). ``bounds``
     (Q, D, C, 2) marks interval clauses (evaluated as two comparisons,
     short-circuited rarest-first; None keeps legacy programs). ``kcfg``
-    sizes the kernel's corpus tile (CPU oracle has no tiles). Its ops sit
-    under the device scope ``filter_eval``."""
+    sizes the kernel's corpus tile (CPU oracle has no tiles). ``tag_cols``
+    (static) names the schema's tag-set fields by their first column: a
+    clause on one takes the tag test. Its ops sit under the device scope
+    ``filter_eval``."""
     n_disj = table_n_disj(fields) if fields.ndim == 3 else None
     with jax.named_scope("filter_eval"):
         if on_tpu():
             tn = (kcfg or KernelConfig()).filter_tile
             return filter_eval_batch(metadata, fields, allowed, n_disj,
-                                     bounds, tn=tn, interpret=False)
+                                     bounds, tn=tn, interpret=False,
+                                     tag_cols=tag_cols)
         return ref.filter_eval_batch(metadata, fields, allowed, n_disj,
-                                     bounds)
+                                     bounds, tag_cols)
+
+
+def clause_passes(fields, allowed, bounds=None):
+    """The vector passes ``filter_eval``'s sweep makes for a batch, by the
+    kernel's cost model (a clause costs one pass per value word it uses):
+    over every active clause of every live disjunct, the non-zero bitmap
+    words of a value-set or tag clause, one for an interval clause. An
+    int32 scalar."""
+    words = (allowed != 0).sum(axis=-1, dtype=jnp.int32)
+    if bounds is not None:
+        words = jnp.where(bounds[..., 0] <= bounds[..., 1], 1, words)
+    return jnp.where(fields >= 0, words, 0).sum(dtype=jnp.int32)
 
 
 def stage_widths(Q: int) -> tuple[int, ...]:
@@ -360,7 +378,7 @@ def atlas_round(datlas: DeviceAtlas, vectors, adjacency, pass_bm, passes,
 def search_batch(datlas: DeviceAtlas, vectors, adjacency, metadata, q_vecs,
                  fields, allowed, p: BatchedParams,
                  valid_bm=None, bounds=None,
-                 kcfg: KernelConfig | None = None):
+                 kcfg: KernelConfig | None = None, tag_cols=()):
     """A whole filtered search batch as ONE device program: batched
     predicate evaluation, then a ``lax.while_loop`` over restart rounds
     (each round = ``atlas_round``). "Anyone seeded?" / "anyone still short
@@ -374,9 +392,13 @@ def search_batch(datlas: DeviceAtlas, vectors, adjacency, metadata, q_vecs,
     to a common row count and use this to keep pad rows (zero vector,
     metadata -1) out of every pass set — including the unconstrained
     predicate, which an empty clause table would otherwise let through.
+
+    ``tag_cols`` (static) is the schema's tag-set fields' first columns.
+    Besides the results and the walk counters it returns
+    ``clause_passes``, the vector passes of the predicate sweep.
     """
     Q = q_vecs.shape[0]
-    pass_bm = _eval_passes(metadata, fields, allowed, bounds, kcfg)
+    pass_bm = _eval_passes(metadata, fields, allowed, bounds, kcfg, tag_cols)
     if valid_bm is not None:
         pass_bm = pass_bm & valid_bm[None, :]
     # the dense unpack feeds only selection math and is round-invariant:
@@ -420,7 +442,8 @@ def search_batch(datlas: DeviceAtlas, vectors, adjacency, metadata, q_vecs,
     out = jax.lax.while_loop(cond, body, init)
     return dict(res_v=out["res_v"], res_i=out["res_i"], hops=out["hops"],
                 walks=out["walks"], rounds=out["r"], iters=out["iters"],
-                slots=out["slots"])
+                slots=out["slots"],
+                clause_passes=clause_passes(fields, allowed, bounds))
 
 
 def clause_dim(n_clauses: int) -> int:
@@ -443,13 +466,15 @@ def disjunct_dim(n_disjuncts: int) -> int:
     return 1 << (n_disjuncts - 1).bit_length()
 
 
-def _compile_query_dnf(pred, vocab_sizes, v_cap: int):
+def _compile_query_dnf(pred, vocab_sizes, v_cap: int, tag_fields=()):
     """Per-query predicate normalization for the batch pack: conjunctive
     FilterPredicates whose every value fits the bitmap pass through
     verbatim (legacy tables stay byte-identical); everything else —
     expressions, precompiled DNFs, and FilterPredicates carrying codes
     beyond ``v_cap`` — compiles v_cap-aware so oversized values lower to
-    interval clauses instead of unpackable bitmap bits."""
+    interval clauses instead of unpackable bitmap bits. The predicate must
+    read the schema's ``tag_fields`` as they are (``_check_tag_use``)."""
+    _check_tag_use(pred, tag_fields)
     if isinstance(pred, FilterPredicate):
         if all(v < v_cap for _, vals in pred.clauses for v in vals):
             return pred
@@ -457,8 +482,40 @@ def _compile_query_dnf(pred, vocab_sizes, v_cap: int):
     return as_dnf(pred, vocab_sizes, v_cap=v_cap)
 
 
+def _check_tag_use(pred, tag_fields) -> None:
+    """A ``HasAny`` leaf (or ``AnyOf`` clause) must name a tag-set field
+    by its first column, and a value or interval constraint no column of
+    a tag-set field: the kernel picks a clause's test by its field.
+    ValueError otherwise."""
+    tagged = tag_columns(tag_fields)
+
+    def check(field: int, is_tag: bool) -> None:
+        if is_tag and tagged.get(field, (None,))[0] != field:
+            raise ValueError(f"HasAny on field {field}, which is not a "
+                             f"tag-set field of the index")
+        if not is_tag and field in tagged:
+            raise ValueError(f"field {field} is a tag-set field: "
+                             f"constrain it with HasAny, not by value")
+
+    def walk(e) -> None:
+        if isinstance(e, (And, Or)):
+            for c in e.children:
+                walk(c)
+        elif isinstance(e, Not):
+            walk(e.child)
+        else:
+            check(e.field, isinstance(e, HasAny))
+
+    if isinstance(pred, FilterExpr):
+        walk(pred)
+        return
+    for clauses in getattr(pred, "disjuncts", None) or (pred.clauses,):
+        for f, spec in clauses:
+            check(f, isinstance(spec, AnyOf))
+
+
 def pack_query_batch(queries: list[Query], *, v_cap: int,
-                     vocab_sizes=None):
+                     vocab_sizes=None, tag_fields=()):
     """Host-side query pack shared by the single-device and sharded
     engines: (Q, d) vector stack + clause tables with the clause dimension
     bucketed by ``clause_dim``.
@@ -474,14 +531,17 @@ def pack_query_batch(queries: list[Query], *, v_cap: int,
     (q_vecs, fields, allowed, bounds): ``bounds`` is the (Q, D, C, 2)
     interval table when any clause is an interval (its disjuncts packed
     rarest-first for the kernel's short-circuit), else None — the
-    invariant is ``bounds is not None ⟹ fields.ndim == 3``."""
+    invariant is ``bounds is not None ⟹ fields.ndim == 3``. A batch with
+    a tag clause (``HasAny`` on one of the ``tag_fields``) packs the
+    (Q, D, C) form too."""
     q_vecs = jnp.asarray(np.stack([q.vector for q in queries]))
-    dnfs = [_compile_query_dnf(q.predicate, vocab_sizes, v_cap)
+    dnfs = [_compile_query_dnf(q.predicate, vocab_sizes, v_cap, tag_fields)
             for q in queries]
     d_max = max((1 if isinstance(p, FilterPredicate) else p.n_disjuncts
                  for p in dnfs), default=0)
     has_iv = any(isinstance(p, DNF) and p.has_intervals for p in dnfs)
-    if d_max <= 1 and not has_iv:
+    has_tags = any(isinstance(p, DNF) and p.has_tags for p in dnfs)
+    if d_max <= 1 and not has_iv and not has_tags:
         preds = [p if isinstance(p, FilterPredicate) else p.to_predicate()
                  for p in dnfs]
         n_cl = max((p.n_clauses for p in preds), default=0)
@@ -534,13 +594,14 @@ def fetch_results(token: dict, finish=None):
     """Sync an in-flight batch, shared by both engines: the batch's one
     host sync (host span ``fns.fetch``), then the result unpack (host span
     ``fns.unpack``, which carries the batch's ``rounds``, ``iters``,
-    ``slots`` and its queries' Σ ``hops``).
+    ``slots``, ``clause_passes`` and its queries' Σ ``hops``).
 
     ``token`` holds the program's output (``out``), the query count to
     keep (``q_n``), the batch number (``batch``), and optionally the
     global-id map (``gids``) and the publish generation (``generation``).
     The per-lane ``rounds``/``iters``/``slots`` of a sharded program
-    reduce to their max. ``finish(ids, stats)``, when given, runs inside the unpack span
+    reduce to their max, its per-lane ``clause_passes`` to their sum.
+    ``finish(ids, stats)``, when given, runs inside the unpack span
     and its value is returned."""
     batch = token["batch"]
     with TraceAnnotation("fns.fetch", batch=batch):
@@ -549,9 +610,11 @@ def fetch_results(token: dict, finish=None):
     rounds = int(np.max(host["rounds"]))
     iters = int(np.max(host["iters"]))
     slots = int(np.max(host["slots"]))
+    passes = int(np.sum(host["clause_passes"]))
     hops = int(host["hops"][:q_n].sum())
     with TraceAnnotation("fns.unpack", batch=batch, rounds=rounds,
-                         iters=iters, slots=slots, hops=hops):
+                         iters=iters, slots=slots, hops=hops,
+                         clause_passes=passes):
         res_v, res_i = host["res_v"], host["res_i"]
         ids = [res_i[i][res_v[i] < INF / 2] for i in range(q_n)]
         g = token.get("gids")
@@ -560,7 +623,8 @@ def fetch_results(token: dict, finish=None):
         # [:q_n] drops the inert lane-pad rows a 2D dispatch may append
         stats = {"walks": host["walks"][:q_n].astype(np.int32),
                  "hops": host["hops"][:q_n].astype(np.int64),
-                 "rounds": rounds, "iters": iters, "slots": slots}
+                 "rounds": rounds, "iters": iters, "slots": slots,
+                 "clause_passes": passes}
         if "generation" in token:
             stats["generation"] = token["generation"]
         return (ids, stats) if finish is None else finish(ids, stats)
@@ -622,6 +686,10 @@ class BatchedEngine:
         v_cap = cfg.atlas.v_cap
         capacity = cfg.serve.capacity
         n = index.vectors.shape[0]
+        # the schema's tag-set fields; the search program is compiled for
+        # them (a clause's test is picked by its field)
+        self.tag_fields = index.tag_fields
+        check_tag_fields(self.tag_fields, index.metadata.shape[1], v_cap)
         if capacity is None:
             self.datlas = index.atlas.to_device(v_cap=v_cap)
             self.vectors = jnp.asarray(index.vectors)
@@ -649,12 +717,12 @@ class BatchedEngine:
             if v_cap is None:
                 # same auto-sizing rule as AnchorAtlas.to_device
                 from repro.core.device_atlas import auto_v_cap
-                vmax = int(index.metadata.max()) if index.metadata.size \
-                    else -1
-                v_cap = auto_v_cap(vmax)
+                v_cap = auto_v_cap(value_max(index.metadata,
+                                             self.tag_fields))
             self._state = InsertState(shards=[slab], v_cap=v_cap,
                                       graph_k=graph_k, alpha=cfg.graph.alpha,
-                                      seed=0, next_gid=n)
+                                      seed=0, next_gid=n,
+                                      tag_fields=self.tag_fields)
             self._refresh_from_slab(v_cap)
         # per-field domains for Not/Range lowering in FilterExpr queries;
         # derived from observed codes when the dataset's declaration isn't
@@ -701,10 +769,12 @@ class BatchedEngine:
         slab = state.shards[0]
         eng = cls.__new__(cls)
         eng.cfg = cfg
+        eng.tag_fields = state.tag_fields
         eng.index = FiberIndex(
             slab.vectors[: slab.n_valid].copy(),
             slab.metadata[: slab.n_valid].copy(),
-            emit_graph(slab), emit_anchor_atlas(slab))
+            emit_graph(slab), emit_anchor_atlas(slab, state.tag_fields),
+            tag_fields=state.tag_fields)
         eng.p = cfg.walk
         eng._state = state
         eng._refresh_from_slab(state.v_cap)
@@ -721,7 +791,7 @@ class BatchedEngine:
         from repro.core.batched.insert import emit_device_atlas
 
         slab = self._state.shards[0]
-        self.datlas = emit_device_atlas(slab, v_cap)
+        self.datlas = emit_device_atlas(slab, v_cap, self._state.tag_fields)
         self.vectors = jnp.asarray(slab.vectors)
         self.adjacency = jnp.asarray(slab.adjacency)
         self.metadata = jnp.asarray(slab.metadata)
@@ -733,6 +803,7 @@ class BatchedEngine:
     def _init_programs(self) -> None:
         params = self.p
         kcfg = self.cfg.kernel
+        tag_cols = first_columns(self.tag_fields)
         # the CPU backend ignores donation; search_batch's inputs have no
         # same-shaped output to alias, so only the round state is donated
         self._round = jax.jit(
@@ -741,9 +812,11 @@ class BatchedEngine:
         # named after search_batch, so the trace's module says which
         # program ran and its recorded scopes (scopes.py) are its own
         self._search = jax.jit(functools.update_wrapper(
-            functools.partial(search_batch, p=params, kcfg=kcfg),
+            functools.partial(search_batch, p=params, kcfg=kcfg,
+                              tag_cols=tag_cols),
             search_batch))
-        self._passes = jax.jit(functools.partial(_eval_passes, kcfg=kcfg))
+        self._passes = jax.jit(functools.partial(_eval_passes, kcfg=kcfg,
+                                                 tag_cols=tag_cols))
         self.dispatches = 0
         self.publish_generation = getattr(self, "publish_generation", 0)
         self.fence_retries = 0
@@ -823,7 +896,8 @@ class BatchedEngine:
 
     def _pack_queries(self, queries: list[Query]):
         return pack_query_batch(queries, v_cap=self.datlas.v_cap,
-                                vocab_sizes=self.vocab_sizes)
+                                vocab_sizes=self.vocab_sizes,
+                                tag_fields=self.tag_fields)
 
     def _to_gids(self, ids: list[np.ndarray]) -> list[np.ndarray]:
         """Map slab row indices to global ids. Identity until the first

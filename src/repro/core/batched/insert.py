@@ -46,6 +46,7 @@ from repro.core.batched.bitmap import n_words
 from repro.core.device_atlas import DeviceAtlas
 from repro.core.graph import (Graph, assign_shards_balanced, patch_adjacency)
 from repro.core.kmeans import kmeans
+from repro.core.tags import TagFields, label_postings, tag_columns
 from repro.core.types import normalize
 
 
@@ -146,6 +147,8 @@ class InsertState:
     # the inline one). Compaction drains a shard's ranges before it
     # remaps rows, so entries never dangle.
     pending: list = dataclasses.field(default_factory=list)
+    # the schema's tag-set fields (core.tags): (first column, V) pairs
+    tag_fields: TagFields = ()
 
     @property
     def n_valid(self) -> int:
@@ -195,14 +198,17 @@ class InsertState:
 
     def expand_vocab(self, vocab_sizes) -> tuple[int, ...] | None:
         """Widen per-field domains with any codes the inserts brought in
-        (Not/Range lowering must keep covering the observed corpus)."""
+        (Not/Range lowering must keep covering the observed corpus). A
+        tag-set field's columns hold label words, not codes: they keep
+        their declared domains."""
         if vocab_sizes is None:
             return None
         seen = np.maximum.reduce(
             [sh.metadata[: sh.n_valid][sh.live[: sh.n_valid]].max(
                 axis=0, initial=-1) for sh in self.shards])
-        return tuple(max(old, int(mx) + 1)
-                     for old, mx in zip(vocab_sizes, seen))
+        tagged = tag_columns(self.tag_fields)
+        return tuple(old if f in tagged else max(old, int(mx) + 1)
+                     for f, (old, mx) in enumerate(zip(vocab_sizes, seen)))
 
     def centroid_drift(self) -> float:
         """Max cosine drift of any shard's centroids since its last
@@ -427,7 +433,8 @@ def insert_rows(state: InsertState, vectors: np.ndarray,
 
 # -- emitters: host state -> the structures the engines consume -------------
 
-def emit_device_atlas(sh: ShardState, v_cap: int) -> DeviceAtlas:
+def emit_device_atlas(sh: ShardState, v_cap: int,
+                      tag_fields: TagFields = ()) -> DeviceAtlas:
     """Pack a shard's host atlas into a DeviceAtlas with the exact
     ``pad_rows`` layout: LIVE rows CSR-grouped by cluster (ascending id
     within a cluster), every dead row — the unwritten tail AND any
@@ -435,7 +442,9 @@ def emit_device_atlas(sh: ShardState, v_cap: int) -> DeviceAtlas:
     so every stacked leaf keeps its build-time shape. Keeping tombstones
     out of the member lists / presence bitmaps / envelopes means a deleted
     row can never be seeded or make a cluster falsely match; when liveness
-    is a prefix this emits bit-identically to the pre-lifecycle packer."""
+    is a prefix this emits bit-identically to the pre-lifecycle packer.
+    A tag-set field's presence row is the OR of its live rows' label
+    masks (its envelope spans the labels present), on its first column."""
     k = sh.atlas.n_clusters
     cap = sh.cap
     live_idx = np.nonzero(sh.live)[0].astype(np.int32)
@@ -453,11 +462,19 @@ def emit_device_atlas(sh: ShardState, v_cap: int) -> DeviceAtlas:
     pres = np.zeros((f_count, k, n_words(v_cap)), np.uint32)
     cmin = np.full((f_count, k), np.int32(2**31 - 1), np.int32)
     cmax = np.full((f_count, k), -1, np.int32)
+    tagged = tag_columns(tag_fields)
     for f in range(f_count):
-        codes = sh.metadata[live_idx, f]
+        if f in tagged:
+            if tagged[f][0] != f:
+                continue  # a later word of a tag-set field
+            rows, codes = label_postings(sh.metadata[live_idx], f,
+                                         tagged[f][1])
+            a_f = a_v[rows]
+        else:
+            codes, a_f = sh.metadata[live_idx, f], a_v
         ok = codes >= 0
-        np.minimum.at(cmin[f], a_v[ok], codes[ok])
-        np.maximum.at(cmax[f], a_v[ok], codes[ok])
+        np.minimum.at(cmin[f], a_f[ok], codes[ok])
+        np.maximum.at(cmax[f], a_f[ok], codes[ok])
         # Codes at/above v_cap get no presence bit, same as the auto-v_cap
         # path of DeviceAtlas.from_atlas: value-set clauses can never name
         # them (pack_dnf lowers such In values to intervals), and interval
@@ -465,7 +482,7 @@ def emit_device_atlas(sh: ShardState, v_cap: int) -> DeviceAtlas:
         inb = ok & (codes < v_cap)
         v = codes[inb].astype(np.uint32)
         bits = np.left_shift(np.ones_like(v), v & np.uint32(31))
-        np.bitwise_or.at(pres[f], (a_v[inb], v >> np.uint32(5)), bits)
+        np.bitwise_or.at(pres[f], (a_f[inb], v >> np.uint32(5)), bits)
     return DeviceAtlas(
         jnp.asarray(sh.atlas.centroids, jnp.float32),
         jnp.asarray(assign_full), jnp.asarray(csr_pts),
@@ -481,14 +498,15 @@ def emit_graph(sh: ShardState) -> Graph:
     return Graph(nbrs.copy(), (nbrs >= 0).sum(axis=1).astype(np.int32))
 
 
-def emit_anchor_atlas(sh: ShardState) -> AnchorAtlas:
+def emit_anchor_atlas(sh: ShardState,
+                      tag_fields: TagFields = ()) -> AnchorAtlas:
     """The host ``AnchorAtlas`` dict-of-dicts view of the incremental
     state (shared ``from_assignment`` pass, maintained assignment instead
     of a fresh kmeans) so the sequential search path can run on a
     dynamically grown index."""
     return AnchorAtlas.from_assignment(
         sh.atlas.centroids.copy(), sh.atlas.assign[: sh.n_valid],
-        sh.metadata[: sh.n_valid])
+        sh.metadata[: sh.n_valid], tag_fields)
 
 
 def _smoke() -> None:

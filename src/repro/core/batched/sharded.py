@@ -47,6 +47,8 @@ from repro.core.device_atlas import (DeviceAtlas, auto_v_cap,
                                      stack_atlases)
 from repro.core.graph import build_shard_graphs
 from repro.core.predicate import FilterExpr, derived_vocab_sizes
+from repro.core.tags import (TagFields, check_tag_fields, field_domains,
+                             first_columns, normalize_tag_fields, value_max)
 from repro.core.types import Dataset, Query
 from repro.launch.mesh import index_axis_size, query_axis_name
 from repro.launch.shardings import index_shardings
@@ -76,6 +78,8 @@ class ShardedIndex:
     # the build reserved ``capacity`` slack; None = build-once index,
     # insert_batch raises
     insert_state: InsertState | None = None
+    # the schema's tag-set fields (core.tags)
+    tag_fields: TagFields = ()
 
     @property
     def n_shards(self) -> int:
@@ -94,7 +98,8 @@ def build_sharded_index(vectors: np.ndarray, metadata: np.ndarray,
                         n_clusters: int | None = None,
                         v_cap: int | None = None,
                         seed: int | None = None,
-                        capacity: int | None = None) -> ShardedIndex:
+                        capacity: int | None = None,
+                        tag_fields: TagFields = ()) -> ShardedIndex:
     """Partition a corpus into ``n_shards`` row blocks and build each
     shard's subgraph + atlas. All shards share one n_clusters and one v_cap
     (the atlas leaves must stack to fixed shapes for ``shard_map``), and
@@ -108,7 +113,8 @@ def build_sharded_index(vectors: np.ndarray, metadata: np.ndarray,
     m = ceil(n / S) and inserts fail on capacity.
 
     All knobs come from ``config`` (one ``FnsConfig``); the loose kwargs
-    are deprecation shims that fold into it, warning once."""
+    are deprecation shims that fold into it, warning once. ``tag_fields``
+    declares the metadata's tag-set fields (``core.tags``)."""
     cfg = coerce_config(config,
                         {"graph.graph_k": graph_k, "graph.r_max": r_max,
                          "graph.alpha": alpha, "atlas.n_clusters": n_clusters,
@@ -123,6 +129,7 @@ def build_sharded_index(vectors: np.ndarray, metadata: np.ndarray,
     metadata = np.asarray(metadata, np.int32)
     n, d = vectors.shape
     f_count = metadata.shape[1]
+    tag_fields = normalize_tag_fields(tag_fields)
     if capacity is not None and capacity < n:
         raise ValueError(f"capacity {capacity} < corpus size {n}")
     graphs, bounds = build_shard_graphs(vectors, n_shards, k=graph_k,
@@ -134,8 +141,8 @@ def build_sharded_index(vectors: np.ndarray, metadata: np.ndarray,
         n_clusters = int(np.ceil(np.sqrt(m)))
     n_clusters = min(n_clusters, min_real)
     if v_cap is None:
-        vmax = int(metadata.max()) if metadata.size else -1
-        v_cap = auto_v_cap(vmax)
+        v_cap = auto_v_cap(value_max(metadata, tag_fields))
+    check_tag_fields(tag_fields, f_count, v_cap)
 
     # one adjacency width across shards, with room for the forward edges
     # appended rows request later (1.5x graph_k, see insert.insert_rows)
@@ -144,7 +151,7 @@ def build_sharded_index(vectors: np.ndarray, metadata: np.ndarray,
     slabs = []
     for s, (lo, hi) in enumerate(bounds):
         ds_s = Dataset(vectors[lo:hi], metadata[lo:hi], field_names,
-                       [v_cap] * f_count)
+                       [v_cap] * f_count, tag_fields)
         atlas = AnchorAtlas.build(ds_s, n_clusters=n_clusters, seed=seed)
         adj_s = np.full((hi - lo, r), -1, np.int32)
         adj_s[:, : graphs[s].r_pad] = graphs[s].neighbors
@@ -155,7 +162,8 @@ def build_sharded_index(vectors: np.ndarray, metadata: np.ndarray,
     # build-once index must REFUSE inserts rather than silently absorb a
     # few rows into its ceil(n/S) padding slack
     istate = (InsertState(shards=slabs, v_cap=v_cap, graph_k=graph_k,
-                          alpha=alpha, seed=seed, next_gid=n)
+                          alpha=alpha, seed=seed, next_gid=n,
+                          tag_fields=tag_fields)
               if capacity is not None else None)
     return ShardedIndex(
         vectors=jnp.asarray(np.stack([sl.vectors for sl in slabs])),
@@ -163,9 +171,11 @@ def build_sharded_index(vectors: np.ndarray, metadata: np.ndarray,
         metadata=jnp.asarray(np.stack([sl.metadata for sl in slabs])),
         global_ids=jnp.asarray(np.stack([sl.global_ids for sl in slabs])),
         valid_bm=pack_bits(jnp.asarray(np.stack([sl.valid for sl in slabs]))),
-        datlas=stack_atlases([emit_device_atlas(sl, v_cap) for sl in slabs]),
-        n=n, vocab_sizes=derived_vocab_sizes(metadata),
-        insert_state=istate)
+        datlas=stack_atlases([emit_device_atlas(sl, v_cap, tag_fields)
+                              for sl in slabs]),
+        n=n, vocab_sizes=field_domains(derived_vocab_sizes(metadata),
+                                       tag_fields),
+        insert_state=istate, tag_fields=tag_fields)
 
 
 def index_from_state(state: InsertState,
@@ -183,9 +193,11 @@ def index_from_state(state: InsertState,
         global_ids=jnp.asarray(np.stack([sl.global_ids for sl in slabs])),
         valid_bm=pack_bits(jnp.asarray(np.stack([sl.valid
                                                  for sl in slabs]))),
-        datlas=stack_atlases([emit_device_atlas(sl, state.v_cap)
+        datlas=stack_atlases([emit_device_atlas(sl, state.v_cap,
+                                                state.tag_fields)
                               for sl in slabs]),
-        n=state.next_gid, vocab_sizes=vocab_sizes, insert_state=state)
+        n=state.next_gid, vocab_sizes=vocab_sizes, insert_state=state,
+        tag_fields=state.tag_fields)
 
 
 def merge_topk(all_v: jax.Array, all_i: jax.Array, k: int):
@@ -276,6 +288,8 @@ class ShardedEngine:
         self._leaves, self._tdef = jax.tree_util.tree_flatten(datlas)
         self.v_cap = sindex.datlas.v_cap
         self.vocab_sizes = sindex.vocab_sizes
+        self.tag_fields = sindex.tag_fields
+        tag_cols = first_columns(self.tag_fields)
         self.n, self.n_shards = sindex.n, s
         self._search = (self._build_program(has_bounds=False)
                         if mesh is not None else None)
@@ -283,7 +297,7 @@ class ShardedEngine:
         self._ref = jax.jit(
             lambda datlas, vec, adj, meta, vbm, qv, f, a, b: search_batch(
                 datlas, vec, adj, meta, qv, f, a, cfg.walk,
-                valid_bm=vbm, bounds=b, kcfg=cfg.kernel))
+                valid_bm=vbm, bounds=b, kcfg=cfg.kernel, tag_cols=tag_cols))
         self.dispatches = 0
         self.publish_generation = 0
         self.fence_retries = 0
@@ -291,6 +305,7 @@ class ShardedEngine:
     def _build_program(self, has_bounds: bool):
         axis, p = self.axis, self.p
         kcfg = self.cfg.kernel
+        tag_cols = first_columns(self.tag_fields)
         nl, tdef = len(self._leaves), self._tdef
 
         def sharded_search(*args):
@@ -303,20 +318,23 @@ class ShardedEngine:
             out = search_batch(datlas, vectors[0], adjacency[0], metadata[0],
                                q_vecs, fields, allowed, p,
                                valid_bm=valid_bm[0], bounds=bounds,
-                               kcfg=kcfg)
+                               kcfg=kcfg, tag_cols=tag_cols)
             gids = jnp.where(out["res_i"] >= 0,
                              global_ids[0][jnp.maximum(out["res_i"], 0)], -1)
             all_v = jax.lax.all_gather(out["res_v"], axis)
             all_i = jax.lax.all_gather(gids, axis)
             res_v, res_i = merge_topk(all_v, all_i, p.k)
             # the slowest shard's rounds, iterations and slots, one per
-            # query lane so that they follow the queries' layout
+            # query lane so that they follow the queries' layout; every
+            # shard sweeps its lane's clause tables alike
             return dict(res_v=res_v, res_i=res_i,
                         hops=jax.lax.psum(out["hops"], axis),
                         walks=jax.lax.psum(out["walks"], axis),
                         rounds=jax.lax.pmax(out["rounds"], axis)[None],
                         iters=jax.lax.pmax(out["iters"], axis)[None],
-                        slots=jax.lax.pmax(out["slots"], axis)[None])
+                        slots=jax.lax.pmax(out["slots"], axis)[None],
+                        clause_passes=jax.lax.pmax(out["clause_passes"],
+                                                   axis)[None])
 
         # index leaves are partitioned row-wise over the data axis; the
         # query tensors (and the bounds table, when the batch carries
@@ -330,7 +348,7 @@ class ShardedEngine:
         in_specs = tuple([P(axis)] * (nl + 5) + [q_spec] * n_q)
         out_specs = dict(res_v=q_spec, res_i=q_spec, hops=q_spec,
                          walks=q_spec, rounds=q_spec, iters=q_spec,
-                         slots=q_spec)
+                         slots=q_spec, clause_passes=q_spec)
         return jax.jit(jax.shard_map(sharded_search, mesh=self.mesh,
                                      in_specs=in_specs, out_specs=out_specs,
                                      check_vma=False))
@@ -429,7 +447,8 @@ class ShardedEngine:
                                         for sl in st.shards]),
                 "valid": np.stack([sl.valid for sl in st.shards])}
             self._shard_atlases = [
-                None if s in touched else emit_device_atlas(sl, self.v_cap)
+                None if s in touched
+                else emit_device_atlas(sl, self.v_cap, st.tag_fields)
                 for s, sl in enumerate(st.shards)]
         for s in touched:
             sl = st.shards[s]
@@ -438,7 +457,8 @@ class ShardedEngine:
             self._host["metadata"][s] = sl.metadata
             self._host["global_ids"][s] = sl.global_ids
             self._host["valid"][s] = sl.valid
-            self._shard_atlases[s] = emit_device_atlas(sl, self.v_cap)
+            self._shard_atlases[s] = emit_device_atlas(sl, self.v_cap,
+                                                       st.tag_fields)
         self.vectors = put(jnp.asarray(self._host["vectors"]))
         self.adjacency = put(jnp.asarray(self._host["adjacency"]))
         self.metadata = put(jnp.asarray(self._host["metadata"]))
@@ -457,7 +477,8 @@ class ShardedEngine:
 
     def _pack_queries(self, queries: list[Query]):
         return pack_query_batch(queries, v_cap=self.v_cap,
-                                vocab_sizes=self.vocab_sizes)
+                                vocab_sizes=self.vocab_sizes,
+                                tag_fields=self.tag_fields)
 
     def _pad_to_lanes(self, queries: list[Query]) -> list[Query]:
         """Pad the batch to a multiple of the query-axis size (shard_map
@@ -533,7 +554,7 @@ class ShardedEngine:
         # cannot be partitioned automatically
         local = functools.partial(jax.device_put, device=jax.devices()[0])
         per_v, per_i, hops, walks = [], [], 0, 0
-        rounds = iters = slots = 0
+        rounds = iters = slots = passes = 0
         for s in range(self.n_shards):
             datlas = jax.tree_util.tree_unflatten(
                 self._tdef, [local(l[s]) for l in self._leaves])
@@ -550,10 +571,12 @@ class ShardedEngine:
             rounds = jnp.maximum(rounds, out["rounds"])
             iters = jnp.maximum(iters, out["iters"])
             slots = jnp.maximum(slots, out["slots"])
+            passes = jnp.maximum(passes, out["clause_passes"])
         res_v, res_i = merge_topk(jnp.stack(per_v), jnp.stack(per_i),
                                   self.p.k)
         return dict(res_v=res_v, res_i=res_i, hops=hops, walks=walks,
-                    rounds=rounds, iters=iters, slots=slots)
+                    rounds=rounds, iters=iters, slots=slots,
+                    clause_passes=passes)
 
     def search_reference(self, queries: list[Query]):
         """Single-device fused baseline: the identical per-shard

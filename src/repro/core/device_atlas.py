@@ -219,8 +219,9 @@ class DeviceAtlas:
         k = atlas.n_clusters
         explicit = v_cap is not None
         if v_cap is None:
-            vmax = max((v for by_f in atlas.cluster_index for v in by_f),
-                       default=-1)
+            # a tag-set field's bitmap holds all V labels, present or not
+            vmax = max([v for by_f in atlas.cluster_index for v in by_f]
+                       + [v - 1 for _, v in atlas.tag_fields], default=-1)
             v_cap = auto_v_cap(vmax)
         order = np.argsort(assign, kind="stable").astype(np.int32)
         offsets = np.zeros(k + 1, np.int64)

@@ -2,7 +2,8 @@
 
 The paper fixes predicates to conjunctions of value-sets (§3.1); this
 module is the serving-grade generalization (DESIGN.md §8): an expression
-tree of ``In`` / ``Range`` leaves composed with ``And`` / ``Or`` / ``Not``,
+tree of ``In`` / ``Range`` / ``HasAny`` leaves composed with ``And`` /
+``Or`` / ``Not``,
 plus a compiler that normalizes any expression into a *bounded* disjunctive
 normal form — at most ``max_disjuncts`` disjuncts, each a conjunctive
 clause list of the exact shape ``FilterPredicate.clauses`` already has, so
@@ -25,6 +26,11 @@ kernels — property-tested bit-identical in ``tests/test_predicate.py``):
   value-set, so clause-table bytes are O(1) in the domain size.
 * ``Range(f, lo, hi)`` is the inclusive interval clipped to the field's
   domain; open ends (``None``) extend to the domain edge.
+* ``HasAny(f, labels)`` is the tag-set leaf (``core.tags``): the row's
+  label set at field f meets ``labels``. It compiles to an ``AnyOf``
+  clause whose value bitmap is the label set; two on one field in one
+  conjunction stay two clauses. ``Not`` over it raises (a complement of
+  a label set is not a label set).
 
 ``vocab_sizes`` (the per-field domain) is only needed when an expression
 contains ``Not`` or an open-ended ``Range``; when omitted, a field's
@@ -62,6 +68,22 @@ class Interval(NamedTuple):
     @property
     def width(self) -> int:
         return max(self.hi - self.lo + 1, 0)
+
+
+class AnyOf(tuple):
+    """Tag clause value: the row passes iff its label set at the clause's
+    field holds any of these labels. A tuple of labels, so a packer that
+    writes value-set bits writes its bitmap unchanged; consumers that
+    evaluate clauses must check ``isinstance(spec, AnyOf)``."""
+
+    __slots__ = ()
+
+
+class _TagConj(frozenset):
+    """A conjunction of label sets on one tag-set field while lowering:
+    each member becomes one ``AnyOf`` clause."""
+
+    __slots__ = ()
 
 # bound on the disjunctive blow-up: And-over-Or distribution is cut off
 # (ValueError) once a (sub)expression needs more conjunctive clause tables
@@ -140,6 +162,21 @@ class Range(FilterExpr):
 
 
 @dataclasses.dataclass(frozen=True, init=False)
+class HasAny(FilterExpr):
+    """field is a tag-set field (``core.tags``) and the row's label set
+    holds any of ``labels`` (negatives dropped)."""
+
+    field: int
+    labels: tuple[int, ...]
+
+    def __init__(self, field: int, labels: Iterable[int]):
+        object.__setattr__(self, "field", int(field))
+        object.__setattr__(self, "labels",
+                           tuple(sorted({int(v) for v in labels
+                                         if int(v) >= 0})))
+
+
+@dataclasses.dataclass(frozen=True, init=False)
 class And(FilterExpr):
     children: tuple[FilterExpr, ...]
 
@@ -166,6 +203,32 @@ def _domain(field: int, vocab_sizes: Sequence[int] | None) -> int:
     return DEFAULT_DOMAIN
 
 
+def _not_over_tags(field: int) -> ValueError:
+    return ValueError(
+        f"Not over HasAny on tag-set field {field} is not supported: the "
+        f"complement of a label set is no label set")
+
+
+def _has_any(meta: np.ndarray, field: int, labels) -> np.ndarray:
+    """Rows whose tag words at ``field`` hold any of ``labels`` (labels
+    whose word lies past the metadata can never be present)."""
+    out = np.zeros(meta.shape[0], dtype=bool)
+    for lab in labels:
+        col = field + (int(lab) >> 5)
+        if col < meta.shape[1]:
+            w = meta[:, col].astype(np.int64) & 0xFFFFFFFF
+            out |= ((w >> (int(lab) & 31)) & 1).astype(bool)
+    return out
+
+
+def _tag_labels(e: "HasAny", vocab_sizes) -> tuple[int, ...]:
+    """The leaf's labels inside its field's declared vocabulary (all of
+    them when no vocabulary is given)."""
+    if vocab_sizes is None or e.field >= len(vocab_sizes):
+        return e.labels
+    return tuple(v for v in e.labels if v < int(vocab_sizes[e.field]))
+
+
 def _range_bounds(e: Range, dom: int) -> tuple[int, int]:
     lo = 0 if e.lo is None else max(int(e.lo), 0)
     hi = dom - 1 if e.hi is None else min(int(e.hi), dom - 1)
@@ -188,6 +251,10 @@ def _eval(e: FilterExpr, meta: np.ndarray,
             m = _eval(c, meta, vocab_sizes, neg)
             out = (out & m) if conj else (out | m)
         return out
+    if isinstance(e, HasAny):
+        if neg:
+            raise _not_over_tags(e.field)
+        return _has_any(meta, e.field, _tag_labels(e, vocab_sizes))
     col = meta[:, e.field]
     if isinstance(e, In):
         m = np.isin(col, np.asarray(e.values, dtype=np.int64))
@@ -225,17 +292,26 @@ class DNF:
         return any(isinstance(spec, Interval)
                    for d in self.disjuncts for _, spec in d)
 
+    @property
+    def has_tags(self) -> bool:
+        return any(isinstance(spec, AnyOf)
+                   for d in self.disjuncts for _, spec in d)
+
     def mask(self, metadata: np.ndarray,
              vocab_sizes: Sequence[int] | None = None) -> np.ndarray:
         """Union over disjuncts of conjunctive clause masks (``vocab_sizes``
         accepted for interface parity; negation is already lowered).
-        Interval clauses are two comparisons; value-set clauses an isin."""
+        Interval clauses are two comparisons; value-set clauses an isin;
+        tag clauses a bit test per label over the field's words."""
         del vocab_sizes
         meta = np.asarray(metadata)
         out = np.zeros(meta.shape[0], dtype=bool)
         for clauses in self.disjuncts:
             m = np.ones(meta.shape[0], dtype=bool)
             for f, spec in clauses:
+                if isinstance(spec, AnyOf):
+                    m &= _has_any(meta, f, spec)
+                    continue
                 col = meta[:, f]
                 # col >= 0 guard: unpopulated codes fail every clause even
                 # if a hand-built DNF carries negative values (the device
@@ -257,12 +333,12 @@ class DNF:
         (0 disjuncts become the canonical match-nothing clause), so purely
         conjunctive batches keep the legacy clause-table shape and its
         compiled programs. Interval clauses have no FilterPredicate form —
-        callers must check ``has_intervals`` first."""
+        callers must check ``has_intervals`` and ``has_tags`` first."""
         from repro.core.types import FilterPredicate
-        if self.has_intervals:
+        if self.has_intervals or self.has_tags:
             raise ValueError(
-                "DNF with interval clauses cannot lower to a value-set "
-                "FilterPredicate; keep the DNF form")
+                "DNF with interval or tag clauses cannot lower to a "
+                "value-set FilterPredicate; keep the DNF form")
         if self.n_disjuncts == 0:
             return FilterPredicate(((0, ()),))
         if self.n_disjuncts == 1:
@@ -311,6 +387,11 @@ def _leaf_specs(e: FilterExpr, neg: bool, vocab_sizes: Sequence[int] | None,
       domain fits the bitmap (byte-identical legacy tables for small
       categorical vocabs), gap intervals beyond that.
     """
+    if isinstance(e, HasAny):
+        if neg:
+            raise _not_over_tags(e.field)
+        labels = _tag_labels(e, vocab_sizes)
+        return [{e.field: _TagConj([frozenset(labels)])}] if labels else []
     dom = _domain(e.field, vocab_sizes)
     small = v_cap if v_cap is not None else DEFAULT_DOMAIN
     if isinstance(e, Range):
@@ -342,7 +423,17 @@ def _leaf_specs(e: FilterExpr, neg: bool, vocab_sizes: Sequence[int] | None,
 
 def _isect(a, b):
     """Intersection of two clause specs (frozenset or Interval). Returns
-    a spec, or None/empty-set when unsatisfiable."""
+    a spec, or None/empty-set when unsatisfiable. Label sets on one
+    tag-set field conjoin as separate clauses, less any set that holds
+    another (HasAny(A) implies HasAny(B) for A within B)."""
+    a_tag, b_tag = isinstance(a, _TagConj), isinstance(b, _TagConj)
+    if a_tag and b_tag:
+        sets = a | b
+        return _TagConj(s for s in sets
+                        if not any(t < s for t in sets))
+    if a_tag or b_tag:
+        raise ValueError("a field is constrained both as a tag set "
+                         "(HasAny) and by value (In/Range)")
     a_iv, b_iv = isinstance(a, Interval), isinstance(b, Interval)
     if a_iv and b_iv:
         lo, hi = max(a.lo, b.lo), min(a.hi, b.hi)
@@ -442,10 +533,20 @@ def compile_to_dnf(expr, vocab_sizes: Sequence[int] | None = None, *,
         return DNF((tuple((f, tuple(v for v in vals if v >= 0))
                           for f, vals in clauses),))
     disjuncts = _lower(expr, False, vocab_sizes, max_disjuncts, v_cap)
-    return DNF(tuple(
-        tuple(sorted(((f, _norm_spec(vs)) for f, vs in d.items()),
-                     key=lambda c: c[0]))
-        for d in disjuncts))
+    return DNF(tuple(_clauses(d) for d in disjuncts))
+
+
+def _clauses(conj: dict) -> Clauses:
+    """One lowered conjunct as a clause tuple ordered by field; a tag
+    field's label sets become one ``AnyOf`` clause each."""
+    out = []
+    for f, spec in sorted(conj.items(), key=lambda c: c[0]):
+        if isinstance(spec, _TagConj):
+            out.extend((f, AnyOf(sorted(s)))
+                       for s in sorted(spec, key=sorted))
+        else:
+            out.append((f, _norm_spec(spec)))
+    return tuple(out)
 
 
 def as_dnf(pred, vocab_sizes: Sequence[int] | None = None, *,

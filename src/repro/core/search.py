@@ -13,6 +13,7 @@ import numpy as np
 from repro.core.atlas import AnchorAtlas
 from repro.core.graph import Graph
 from repro.core.predicate import FilterExpr, as_dnf, derived_vocab_sizes
+from repro.core.tags import TagFields, field_domains
 from repro.core.types import FilterPredicate, Query, SearchStats
 from repro.core.walk_beam import beam_walk
 from repro.core.walk_common import WalkContext
@@ -44,6 +45,8 @@ class FiberIndex:
     metadata: np.ndarray
     graph: Graph
     atlas: AnchorAtlas
+    # the schema's tag-set fields (core.tags), as the Dataset declared them
+    tag_fields: TagFields = ()
 
     def vocab_sizes(self) -> tuple[int, ...]:
         """Per-field domains for FilterExpr Not/Range lowering, derived
@@ -53,7 +56,8 @@ class FiberIndex:
         newly introduced codes."""
         vs = getattr(self, "_vocab_sizes", None)
         if vs is None:
-            vs = derived_vocab_sizes(self.metadata)
+            vs = field_domains(derived_vocab_sizes(self.metadata),
+                               self.tag_fields)
             self._vocab_sizes = vs
         return vs
 

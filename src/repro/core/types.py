@@ -17,25 +17,37 @@ from typing import Mapping, Sequence
 import numpy as np
 
 from repro.core.predicate import And, FilterExpr, In
+from repro.core.tags import (TagFields, check_tag_fields, field_domains,
+                             normalize_tag_fields)
 
 
 @dataclasses.dataclass
 class Dataset:
     """Unit-norm vectors (n, d) float32 + metadata codes (n, F) int32.
 
-    ``field_names``/``vocab_sizes`` describe the metadata schema; code -1
-    denotes "field not populated" (sparse metadata, §4.3).
+    ``field_names``/``vocab_sizes`` describe the metadata schema, one entry
+    per column; code -1 denotes "field not populated" (sparse metadata,
+    §4.3). ``tag_fields`` declares tag-set fields (``core.tags``): each
+    ``(column, V)`` pair's row value is a set of labels from [0, V), held
+    as a V-bit mask in ``ceil(V/32)`` columns from ``column`` on; its
+    ``vocab_sizes`` entry reads V.
     """
 
     vectors: np.ndarray
     metadata: np.ndarray
     field_names: list[str]
     vocab_sizes: list[int]
+    tag_fields: TagFields = ()
 
     def __post_init__(self) -> None:
         assert self.vectors.ndim == 2 and self.metadata.ndim == 2
         assert self.vectors.shape[0] == self.metadata.shape[0]
         assert self.metadata.shape[1] == len(self.field_names)
+        self.tag_fields = normalize_tag_fields(self.tag_fields)
+        check_tag_fields(self.tag_fields, self.metadata.shape[1])
+        if self.tag_fields:
+            self.vocab_sizes = list(field_domains(self.vocab_sizes,
+                                                  self.tag_fields))
 
     @property
     def n(self) -> int:

@@ -20,6 +20,12 @@ Conjunctive (Q, C) tables are the D = 1 case of the disjunctive (Q, D, C)
 form (DESIGN.md §8): the kernel ORs the per-disjunct conjunctions, skipping
 the dead-disjunct tail by the per-query live count. ``bounds`` adds the
 interval test (``lo <= hi`` marks an interval clause) to the same program.
+
+``tag_cols`` (static: the schema's tag-set fields, ``core.tags``) adds the
+tag test: a clause on a tag-set field passes a row whose label words share
+a bit with the clause's bitmap words, ``(row word w & clause word w) != 0``
+for some w, reading word w from column ``field + w``. With no tag-set
+field the program is the value-set/interval one alone.
 """
 from __future__ import annotations
 
@@ -77,15 +83,18 @@ def _tile_words(tn: int, n_words: int, lane_aligned: bool) -> int:
 
 def _kernel(fields_ref, words_ref, bounds_ref, ndisj_ref, meta_ref, out_ref,
             ok_ref, conj_ref, hit_ref, *, qt: int, n_disjuncts: int,
-            n_clauses: int, n_vwords: int, has_bounds: bool):
+            n_clauses: int, n_vwords: int, has_bounds: bool,
+            tag_cols: tuple[int, ...] = ()):
     """One (corpus tile, query tile) step. Scalar tables are flattened per
     query: fields[q*D*C + d*C + c], words[(q*D*C + d*C + c)*Wv + w],
     bounds[(q*D*C + d*C + c)*2 + {0, 1}], ndisj[q]; ``q`` is the query's
     row within this step's SMEM block. Dead disjuncts, inactive clauses and
     all-zero bitmap words are skipped by scalar branches, so a clause costs
     one vector pass per value word it actually uses. The pass state lives
-    in int32 VMEM scratch (Mosaic selects no bool vectors)."""
-    tw = meta_ref.shape[2]
+    in int32 VMEM scratch (Mosaic selects no bool vectors). A clause whose
+    field is one of ``tag_cols`` takes the tag test instead, one vector
+    pass per non-zero clause word as well."""
+    n_cols, tw = meta_ref.shape[0], meta_ref.shape[2]
     dc = n_disjuncts * n_clauses
     shift = jax.lax.broadcasted_iota(jnp.int32, (_WORD, tw), 0)
 
@@ -116,6 +125,32 @@ def _kernel(fields_ref, words_ref, bounds_ref, ndisj_ref, meta_ref, out_ref,
                 hit_ref[...] = iv.astype(jnp.int32)
         conj_ref[...] &= hit_ref[...]
 
+    def tag_clause(slot):
+        field = fields_ref[slot]
+        hit_ref[...] = jnp.zeros((_WORD, tw), jnp.int32)
+
+        def word_step(w, carry):
+            word = words_ref[slot * n_vwords + w]
+
+            @pl.when(word != 0)
+            def _():
+                row = meta_ref[jnp.minimum(field + w, n_cols - 1)]
+                hit_ref[...] |= ((row & word) != 0).astype(jnp.int32)
+            return carry
+
+        jax.lax.fori_loop(0, n_vwords, word_step, 0)
+        conj_ref[...] &= hit_ref[...]
+
+    def any_clause(slot):
+        field = fields_ref[slot]
+        if not tag_cols:
+            pl.when(field >= 0)(lambda: clause(slot))
+            return
+        is_tag = functools.reduce(jnp.logical_or,
+                                  [field == c for c in tag_cols])
+        pl.when((field >= 0) & is_tag)(lambda: tag_clause(slot))
+        pl.when((field >= 0) & ~is_tag)(lambda: clause(slot))
+
     def query(j, carry):
         ok_ref[...] = jnp.zeros_like(ok_ref)
         for dd in range(n_disjuncts):
@@ -124,8 +159,7 @@ def _kernel(fields_ref, words_ref, bounds_ref, ndisj_ref, meta_ref, out_ref,
             def _():
                 conj_ref[...] = jnp.ones_like(conj_ref)
                 for c in range(n_clauses):
-                    slot = j * dc + dd * n_clauses + c
-                    pl.when(fields_ref[slot] >= 0)(lambda: clause(slot))
+                    any_clause(j * dc + dd * n_clauses + c)
                 ok_ref[...] |= conj_ref[...]
         out_ref[pl.ds(j, 1), :] = jnp.sum(ok_ref[...] << shift, axis=0,
                                           keepdims=True)     # (1, tw)
@@ -134,9 +168,10 @@ def _kernel(fields_ref, words_ref, bounds_ref, ndisj_ref, meta_ref, out_ref,
     jax.lax.fori_loop(0, qt, query, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("tn", "interpret"))
+@functools.partial(jax.jit, static_argnames=("tn", "interpret", "tag_cols"))
 def filter_eval_batch(metadata, fields, allowed, n_disj=None, bounds=None, *,
-                      tn: int, interpret: bool = True):
+                      tn: int, interpret: bool = True,
+                      tag_cols: tuple[int, ...] = ()):
     """Batched corpus sweep: metadata (n, F) i32; fields (Q, C) i32 (-1
     inactive); allowed (Q, C, ceil(v_cap/32)) uint32 value bitmaps (the
     ``pack_predicates`` clause-table format) -> (Q, ceil(n/32)) uint32.
@@ -150,6 +185,10 @@ def filter_eval_batch(metadata, fields, allowed, n_disj=None, bounds=None, *,
     Interval form: ``bounds`` (Q, D, C, 2) i32 rides along the disjunctive
     tables; a clause row with ``lo <= hi`` is evaluated as the inclusive
     interval test instead of bitmap membership (its bitmap row is zero).
+
+    Tag-set fields: a clause whose field is in ``tag_cols`` (the first
+    metadata column of each tag-set field) passes a row whose label words
+    share a bit with the clause's bitmap words.
 
     The grid is (corpus tiles, query tiles) with queries on the fast axis,
     so a corpus tile's code block stays resident while the query tiles'
@@ -199,7 +238,8 @@ def filter_eval_batch(metadata, fields, allowed, n_disj=None, bounds=None, *,
 
     out = pl.pallas_call(
         functools.partial(_kernel, qt=qt, n_disjuncts=d_n, n_clauses=c_n,
-                          n_vwords=wv, has_bounds=has_bounds),
+                          n_vwords=wv, has_bounds=has_bounds,
+                          tag_cols=tuple(tag_cols)),
         grid=(w_pad // tw, n_qt),
         in_specs=[spec for _, spec in tables] + [
             pl.BlockSpec((n_fields, _WORD, tw), lambda i, q: (0, 0, i))],

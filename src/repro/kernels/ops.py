@@ -42,9 +42,11 @@ def filter_eval(metadata, fields, allowed, *, tn: int = _KCFG.filter_tile):
 
 
 def filter_eval_batch(metadata, fields, allowed, n_disj=None, bounds=None, *,
-                      tn: int = _KCFG.filter_tile):
+                      tn: int = _KCFG.filter_tile,
+                      tag_cols: tuple[int, ...] = ()):
     return _filter_eval_batch(metadata, fields, allowed, n_disj, bounds,
-                              tn=tn, interpret=not on_tpu())
+                              tn=tn, interpret=not on_tpu(),
+                              tag_cols=tag_cols)
 
 
 def predicate_tables(pred, n_fields: int,

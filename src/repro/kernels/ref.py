@@ -13,7 +13,9 @@ Semantics contract:
   pack_predicates clause tables (fields (Q, C) i32; allowed (Q, C, Wv)
   uint32 value bitmaps) -> (Q, ceil(n/32)) uint32. Disjunctive (Q, D, C)
   pack_dnf tables OR the per-disjunct conjunctive bitmaps (dead-disjunct
-  padding, marked with field sentinel -2, contributes nothing).
+  padding, marked with field sentinel -2, contributes nothing). A clause
+  on a tag-set field (its first column in ``tag_cols``) passes a row whose
+  label words share a bit with the clause's bitmap words (``tag_ok``).
 """
 from __future__ import annotations
 
@@ -71,12 +73,29 @@ def filter_eval(metadata: jax.Array, fields: jax.Array, allowed: jax.Array):
     return (bits * weights).sum(axis=1).astype(jnp.uint32)
 
 
+def tag_ok(metadata: jax.Array, field: jax.Array, words: jax.Array):
+    """The tag-set clause oracle: metadata (n, F) i32; field (Q,) i32, the
+    first column of a tag-set field; words (Q, Wv) uint32, the clause's
+    label bitmap. (Q, n) bool: the row's label word w (column field + w)
+    shares a bit with clause word w, for some w. Clause words past the
+    field's own words are zero (labels lie below its V), so the columns
+    they would read are never tested."""
+    n_cols = metadata.shape[1]
+    ok = jnp.zeros((field.shape[0], metadata.shape[0]), bool)
+    for w in range(words.shape[1]):
+        col = jnp.minimum(jnp.maximum(field, 0) + w, n_cols - 1)
+        row = metadata[:, col].T.astype(jnp.uint32)             # (Q, n)
+        ok = ok | ((row & words[:, w][:, None]) != 0)
+    return ok
+
+
 def _conj_ok(metadata: jax.Array, fields: jax.Array, allowed: jax.Array,
-             bounds: jax.Array | None = None):
+             bounds: jax.Array | None = None, tag_cols: tuple = ()):
     """(Q, n) bool conjunction for one clause-table slice: fields (Q, C)
     i32, allowed (Q, C, Wv) uint32 value bitmaps, optional bounds (Q, C, 2)
     i32 interval rows (a clause with lo <= hi is the two-comparison
-    interval test; its bitmap row is zero)."""
+    interval test; its bitmap row is zero). A clause whose field is one of
+    ``tag_cols`` is the tag test (``tag_ok``)."""
     n = metadata.shape[0]
     q_n, n_clauses = fields.shape
     v_cap = allowed.shape[-1] * 32
@@ -94,13 +113,19 @@ def _conj_ok(metadata: jax.Array, fields: jax.Array, allowed: jax.Array,
             hi = bounds[:, c, 1][:, None]
             iv_ok = (vals >= 0) & (vals >= lo) & (vals <= hi)
             clause_ok = jnp.where(lo <= hi, iv_ok, clause_ok)
+        if tag_cols:
+            is_tag = jnp.isin(f, jnp.asarray(tag_cols, jnp.int32))
+            clause_ok = jnp.where(is_tag[:, None],
+                                  tag_ok(metadata, f, allowed[:, c, :]),
+                                  clause_ok)
         ok = jnp.where((f >= 0)[:, None], ok & clause_ok, ok)
     return ok
 
 
 def filter_eval_batch(metadata: jax.Array, fields: jax.Array,
                       allowed: jax.Array, n_disj: jax.Array | None = None,
-                      bounds: jax.Array | None = None):
+                      bounds: jax.Array | None = None,
+                      tag_cols: tuple = ()):
     """metadata (n, F) i32; fields (Q, C) i32 (-1 = inactive clause);
     allowed (Q, C, ceil(v_cap/32)) uint32 value bitmaps (the
     ``pack_predicates`` clause-table format). Returns (Q, ceil(n/32))
@@ -110,7 +135,8 @@ def filter_eval_batch(metadata: jax.Array, fields: jax.Array,
     (-2 = dead-disjunct padding), allowed (Q, D, C, Wv), n_disj (Q,) i32
     live-disjunct counts (derived from the sentinel when omitted); the
     bitmap is the union over live disjuncts of conjunctive bitmaps.
-    Optional bounds (Q, D, C, 2) i32 marks interval clauses (lo <= hi)."""
+    Optional bounds (Q, D, C, 2) i32 marks interval clauses (lo <= hi).
+    ``tag_cols`` names the tag-set fields by their first column."""
     n = metadata.shape[0]
     q_n = fields.shape[0]
     if fields.ndim == 3:
@@ -121,10 +147,11 @@ def filter_eval_batch(metadata: jax.Array, fields: jax.Array,
         ok = jnp.zeros((q_n, n), bool)
         for d in range(D):
             ok_d = _conj_ok(metadata, fields[:, d, :], allowed[:, d, :, :],
-                            None if bounds is None else bounds[:, d, :, :])
+                            None if bounds is None else bounds[:, d, :, :],
+                            tag_cols)
             ok = ok | (ok_d & (d < n_disj)[:, None])
     else:
-        ok = _conj_ok(metadata, fields, allowed)
+        ok = _conj_ok(metadata, fields, allowed, tag_cols=tag_cols)
     pad = (-n) % 32
     okp = jnp.pad(ok, ((0, 0), (0, pad)))
     bits = okp.reshape(q_n, -1, 32).astype(jnp.uint32)

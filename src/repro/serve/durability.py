@@ -251,6 +251,8 @@ def state_to_tree(state: InsertState, extra: dict | None = None) -> dict:
             "grown": state.grown,
             "pending": [[int(s), int(lo), int(hi)]
                         for s, lo, hi in state.pending],
+            # the schema's tag-set fields: [first column, V] pairs
+            "tag_fields": [[int(c), int(v)] for c, v in state.tag_fields],
             "shards": [{"n_valid": int(sh.n_valid),
                         "reclusters": int(sh.atlas.reclusters)}
                        for sh in state.shards],
@@ -309,7 +311,9 @@ def state_from_tree(arrays: dict) -> tuple[InsertState, dict]:
         compactions=meta.get("compactions", 0),
         grown=meta.get("grown", 0),
         pending=[(int(s), int(lo), int(hi))
-                 for s, lo, hi in meta.get("pending", [])])
+                 for s, lo, hi in meta.get("pending", [])],
+        tag_fields=tuple((int(c), int(v))
+                         for c, v in meta.get("tag_fields", [])))
     return state, meta["extra"]
 
 

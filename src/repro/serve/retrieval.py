@@ -24,6 +24,7 @@ from repro.core.config import (AtlasConfig, FnsConfig, GraphConfig,
 from repro.core.graph import build_alpha_knn
 from repro.core.predicate import FilterExpr
 from repro.core.search import FiberIndex, SearchParams, search
+from repro.core.tags import stray_labels, tag_columns
 from repro.core.types import Dataset, FilterPredicate, Query, normalize
 from repro.launch.mesh import index_axis_size, query_axis_name
 from repro.models.transformer import ShardEnv, encode
@@ -135,7 +136,8 @@ class RetrievalService:
             graph = build_alpha_knn(ds.vectors, k=gb["graph_k"],
                                     r_max=gb["r_max"], alpha=gb["alpha"])
             atlas = AnchorAtlas.build(ds, n_clusters=gb["n_clusters"])
-            self.index = FiberIndex(ds.vectors, ds.metadata, graph, atlas)
+            self.index = FiberIndex(ds.vectors, ds.metadata, graph, atlas,
+                                    tag_fields=ds.tag_fields)
         return self.index
 
     def _gb(self) -> dict:
@@ -166,6 +168,11 @@ class RetrievalService:
         if self._ds is not None:
             return self._ds.vectors, self._ds.metadata
         return self.index.vectors, self.index.metadata
+
+    def _tag_fields(self):
+        """The schema's tag-set fields (``core.tags``)."""
+        src = self._ds if self._ds is not None else self.index
+        return src.tag_fields
 
     def query(self, vector: np.ndarray, predicate: FilterPredicate,
               seed: int = 0):
@@ -236,7 +243,8 @@ class RetrievalService:
             vectors, metadata = self._corpus()
             sidx = build_sharded_index(vectors, metadata,
                                        self._mesh_shards(),
-                                       config=self._cfg())
+                                       config=self._cfg(),
+                                       tag_fields=self._tag_fields())
             self._sharded = ShardedEngine(sidx, self.mesh,
                                           config=self._cfg())
         return self._sharded
@@ -300,7 +308,8 @@ class RetrievalService:
             checked = []
             for i, p in enumerate(predicates):
                 try:
-                    _compile_query_dnf(p, eng.vocab_sizes, v_cap)
+                    _compile_query_dnf(p, eng.vocab_sizes, v_cap,
+                                       eng.tag_fields)
                     checked.append(p)
                 except ValueError as e:
                     errors[i] = str(e)
@@ -409,11 +418,19 @@ class RetrievalService:
                 f"index declares {f_count}")
         # a field's codes may reach its declared vocabulary (a large-domain
         # field such as a timestamp serves codes >= v_cap through interval
-        # clauses and the atlas envelopes), else the value-bitmap width
+        # clauses and the atlas envelopes), else the value-bitmap width;
+        # a tag-set field's columns hold label words, whose bits must stay
+        # below its V
         limit = np.full(f_count, st.v_cap, np.int64)
         if eng.vocab_sizes is not None:
             limit = np.maximum(limit, np.asarray(eng.vocab_sizes, np.int64))
         over = metadata >= limit[None, :]
+        over[:, list(tag_columns(st.tag_fields))] = False
+        stray = stray_labels(metadata, st.tag_fields)
+        if stray:
+            raise ValueError(
+                f"ingest metadata sets a label beyond the vocabulary of "
+                f"the tag-set field at column {stray[0]}")
         if over.any():
             f = int(np.nonzero(over.any(axis=0))[0][0])
             raise ValueError(

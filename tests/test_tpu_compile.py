@@ -104,3 +104,40 @@ def test_fused_search_lowers_for_v5e(spec, monkeypatch):
                         valid_bm=spec(((n + 31) // 32,), jnp.uint32)
                         ).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("form", ["dnf", "dnf_bounds"])
+def test_tag_filter_eval_lowers_for_v5e(spec, form):
+    """The tag test (a tag-set field's label words in columns 1..5, read
+    at a dynamic column per clause word) lowers to a Mosaic custom call
+    in both table forms that carry it."""
+    fn = jax.jit(functools.partial(filter_eval_batch, tn=TN,
+                                   interpret=False, tag_cols=(1,)))
+    compiled = fn.lower(spec((N, F), jnp.int32),
+                        *_tables(spec, form, 1, C)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_fused_tag_search_lowers_for_v5e(spec, monkeypatch):
+    """The one-dispatch search program at the arXiv-titles store's shape:
+    49,152 rows of 384, a 155-label tag-set field in columns 0..4 and a
+    day column, label-set AND window tables (rank 3 with bounds)."""
+    import repro.core.batched.engine as engine
+    from repro.core.device_atlas import DeviceAtlas
+
+    monkeypatch.setattr(engine, "on_tpu", lambda: True)
+    cfg = FnsConfig()
+    n, d, f, k, r = 49_152, 384, 6, 222, 128   # sqrt(n) clusters
+    datlas = DeviceAtlas(
+        spec((k, d), jnp.float32), spec((n,), jnp.int32),
+        spec((n,), jnp.int32), spec((k + 1,), jnp.int32),
+        spec((n,), jnp.int32), spec((f, k, WV), jnp.uint32),
+        spec((f, k), jnp.int32), spec((f, k), jnp.int32), v_cap=WV * 32)
+    fields, allowed, _, bounds = _tables(spec, "dnf_bounds", 1, C)
+    fn = jax.jit(functools.partial(engine.search_batch, p=cfg.walk,
+                                   kcfg=cfg.kernel, tag_cols=(0,)))
+    compiled = fn.lower(datlas, spec((n, d), jnp.float32),
+                        spec((n, r), jnp.int32), spec((n, f), jnp.int32),
+                        spec((Q, d), jnp.float32), fields, allowed,
+                        bounds=bounds).compile()
+    assert "tpu_custom_call" in compiled.as_text()
