@@ -55,6 +55,9 @@ from repro.kernels.filter_eval import filter_eval_batch
 from repro.kernels.ops import MAX_CLAUSES, on_tpu
 
 INF = jnp.float32(3.4e38)
+# the host twin of ``INF / 2``: a result slot at or above it is unfilled.
+# A numpy scalar, so the unpack after the fetch runs no device op.
+_HOST_FOUND = np.float32(3.4e38) / 2
 HIGHEST = jax.lax.Precision.HIGHEST
 
 TERM_RUNNING, TERM_CONVERGED, TERM_EARLY, TERM_STALL, TERM_MAXHOP = 0, 1, 2, 3, 4
@@ -590,6 +593,16 @@ def _fence_pack(eng, queries: list[Query], batch: int):
             eng.fence_retries += 1
 
 
+def _found_ids(res_v: np.ndarray, res_i: np.ndarray, q_n: int,
+               gids: np.ndarray | None = None) -> list[np.ndarray]:
+    """Each of the first ``q_n`` queries' found ids, in program order, from
+    one float32 host compare over the whole ``(Q, k)`` result (rows past
+    ``q_n`` are lane pad). ``gids`` maps slab rows to global ids."""
+    found = res_v[:q_n] < _HOST_FOUND
+    ids = [row[m] for row, m in zip(res_i[:q_n], found)]
+    return ids if gids is None else [gids[i] for i in ids]
+
+
 def fetch_results(token: dict, finish=None):
     """Sync an in-flight batch, shared by both engines: the batch's one
     host sync (host span ``fns.fetch``), then the result unpack (host span
@@ -615,11 +628,8 @@ def fetch_results(token: dict, finish=None):
     with TraceAnnotation("fns.unpack", batch=batch, rounds=rounds,
                          iters=iters, slots=slots, hops=hops,
                          clause_passes=passes):
-        res_v, res_i = host["res_v"], host["res_i"]
-        ids = [res_i[i][res_v[i] < INF / 2] for i in range(q_n)]
-        g = token.get("gids")
-        if g is not None:
-            ids = [g[i] for i in ids]
+        ids = _found_ids(host["res_v"], host["res_i"], q_n,
+                         token.get("gids"))
         # [:q_n] drops the inert lane-pad rows a 2D dispatch may append
         stats = {"walks": host["walks"][:q_n].astype(np.int32),
                  "hops": host["hops"][:q_n].astype(np.int64),
@@ -899,14 +909,14 @@ class BatchedEngine:
                                 vocab_sizes=self.vocab_sizes,
                                 tag_fields=self.tag_fields)
 
-    def _to_gids(self, ids: list[np.ndarray]) -> list[np.ndarray]:
-        """Map slab row indices to global ids. Identity until the first
-        compaction moves rows (build + append assign gid == row), so this
-        only matters on an index with a document lifecycle."""
+    def _gid_map(self) -> np.ndarray | None:
+        """A snapshot of the slab-row -> global-id map, None without a
+        capacity slab. Identity until the first compaction moves rows
+        (build + append assign gid == row), so it only matters on an index
+        with a document lifecycle."""
         if self._state is None:
-            return ids
-        g = self._state.shards[0].global_ids
-        return [g[i] for i in ids]
+            return None
+        return self._state.shards[0].global_ids.copy()
 
     def dispatch(self, queries: list[Query], seed: int = 0, *,
                  batch: int = -1) -> dict:
@@ -927,10 +937,8 @@ class BatchedEngine:
                                q_vecs, fields, allowed,
                                valid_bm=self._valid_bm, bounds=bounds)
         self.dispatches += 1
-        gids = (self._state.shards[0].global_ids.copy()
-                if self._state is not None else None)
         return {"out": out, "q_n": len(queries), "generation": gen,
-                "gids": gids, "batch": batch}
+                "gids": self._gid_map(), "batch": batch}
 
     def collect(self, token: dict, finish=None):
         """Sync an in-flight ``dispatch`` token: the batch's single host
@@ -989,8 +997,6 @@ class BatchedEngine:
             stats["walks"] += seeded
             if not bool(np.asarray(need).any()):
                 break
-        res_v = np.asarray(res_v)
-        res_i = np.asarray(res_i)
-        ids = self._to_gids(
-            [res_i[i][res_v[i] < INF / 2] for i in range(Q)])
+        ids = _found_ids(np.asarray(res_v), np.asarray(res_i), Q,
+                         self._gid_map())
         return ids, stats
