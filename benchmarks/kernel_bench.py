@@ -8,11 +8,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.batched.engine import BatchedEngine, BatchedParams
+from repro.core.config import FnsConfig, WalkConfig
 from repro.core.device_atlas import pack_predicates
 from repro.core.search import SearchParams, run_queries
 from repro.kernels import ref
 from benchmarks.datasets import K, get_indexes
+from benchmarks.search_bench import engine_over
 
 
 def _time(fn, *args, reps=5):
@@ -89,7 +90,8 @@ def engine_bench():
     ids_ref, _ = run_queries(idx_alpha, sub,
                              SearchParams(k=K, walk="guided", beam_width=2))
     t_ref = time.time() - t0
-    eng = BatchedEngine(idx_alpha, BatchedParams(k=K, beam_width=4))
+    cfg = FnsConfig(walk=WalkConfig(k=K, beam_width=4))
+    eng = engine_over(idx_alpha, cfg)
     eng.search(sub)  # compile at the timed batch shape
     t0 = time.time()
     ids_b, _ = eng.search(sub)

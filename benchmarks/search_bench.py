@@ -49,7 +49,8 @@ import time
 import numpy as np
 
 from repro.core.atlas import AnchorAtlas
-from repro.core.batched.engine import BatchedEngine
+from repro.core.batched.engine import Engine
+from repro.core.batched.sharded import ShardedIndex
 from repro.core.config import FnsConfig
 from repro.core.graph import build_alpha_knn
 from repro.core.search import FiberIndex
@@ -92,6 +93,13 @@ def build_search_fixture(selectivities=SELECTIVITIES, *, n: int = 8000,
     atlas = AnchorAtlas.build(ds, n_clusters=config.atlas.n_clusters,
                               seed=config.atlas.kmeans_seed)
     return ds, FiberIndex(ds.vectors, ds.metadata, graph, atlas)
+
+
+def engine_over(index: FiberIndex, config: FnsConfig, *,
+                vocab_sizes=None) -> Engine:
+    """An engine over a built single-device index, as one shard."""
+    return Engine(ShardedIndex.from_index(index, config,
+                                          vocab_sizes=vocab_sizes), config)
 
 
 def make_query_pools(ds, selectivities, q_max: int, k: int) -> dict:
@@ -157,7 +165,7 @@ def search_bench(batch_sizes=BATCH_SIZES, selectivities=SELECTIVITIES, *,
     k = cfg.walk.k
     ds, index = build_search_fixture(selectivities, n=n, d=d, seed=seed,
                                      config=cfg)
-    eng = BatchedEngine(index, config=cfg)
+    eng = engine_over(index, cfg)
     n_words = (n + 31) // 32
     out: dict = {}
     if not key_prefix:
@@ -217,7 +225,7 @@ def or_search_bench(batch_sizes=(64,), or_sels=OR_SELECTIVITIES, *,
     graph = build_alpha_knn(ds.vectors, config=cfg.graph)
     atlas = AnchorAtlas.build(ds, seed=cfg.atlas.kmeans_seed)
     index = FiberIndex(ds.vectors, ds.metadata, graph, atlas)
-    eng = BatchedEngine(index, config=cfg, vocab_sizes=ds.vocab_sizes)
+    eng = engine_over(index, cfg, vocab_sizes=ds.vocab_sizes)
     n_words = (n + 31) // 32
     out: dict = {}
     q_max = max(batch_sizes)
@@ -231,7 +239,8 @@ def or_search_bench(batch_sizes=(64,), or_sels=OR_SELECTIVITIES, *,
             batch = pools[sel][:q_n]
             # disjunction kernel vs expression-tree oracle, bit-exact
             _, f_t, a_t, b_t = eng._pack_queries(batch)
-            got = np.asarray(_eval_passes(eng.metadata, f_t, a_t, b_t))
+            got = np.asarray(_eval_passes(eng.shards[0].metadata, f_t,
+                                          a_t, b_t))
             want = np.asarray(pack_bits(jnp.asarray(np.stack(
                 [q.predicate.mask(ds.metadata, ds.vocab_sizes)
                  for q in batch]))))
@@ -278,7 +287,7 @@ def range_search_bench(batch_sizes=(64,), range_sels=SELECTIVITIES, *,
     graph = build_alpha_knn(ds.vectors, config=cfg.graph)
     atlas = AnchorAtlas.build(ds, seed=cfg.atlas.kmeans_seed)
     index = FiberIndex(ds.vectors, ds.metadata, graph, atlas)
-    eng = BatchedEngine(index, config=cfg, vocab_sizes=ds.vocab_sizes)
+    eng = engine_over(index, cfg, vocab_sizes=ds.vocab_sizes)
     n_words = (n + 31) // 32
     out: dict = {}
     q_max = max(batch_sizes)
@@ -292,7 +301,8 @@ def range_search_bench(batch_sizes=(64,), range_sels=SELECTIVITIES, *,
             batch = pools[sel][:q_n]
             # interval kernel vs expression-tree oracle, bit-exact
             _, f_t, a_t, b_t = eng._pack_queries(batch)
-            got = np.asarray(_eval_passes(eng.metadata, f_t, a_t, b_t))
+            got = np.asarray(_eval_passes(eng.shards[0].metadata, f_t,
+                                          a_t, b_t))
             want = np.asarray(pack_bits(jnp.asarray(np.stack(
                 [q.predicate.mask(ds.metadata, ds.vocab_sizes)
                  for q in batch]))))
@@ -333,7 +343,7 @@ def sharded_search_bench(batch_sizes=(64,), selectivities=SELECTIVITIES, *,
     get a real multi-shard row). Keys look like ``sharded4/q64/sel0.1``."""
     import jax
 
-    from repro.core.batched.sharded import ShardedEngine, build_sharded_index
+    from repro.core.batched.sharded import build_sharded_index
     from repro.launch.mesh import make_local_mesh
 
     n_dev = len(jax.devices())
@@ -343,7 +353,7 @@ def sharded_search_bench(batch_sizes=(64,), selectivities=SELECTIVITIES, *,
                                   seed=seed)
     sidx = build_sharded_index(ds.vectors, ds.metadata, s, config=cfg)
     mesh = make_local_mesh(data=s, model=1)
-    eng = ShardedEngine(sidx, mesh, config=cfg)
+    eng = Engine(sidx, cfg, mesh)
     m_words = (sidx.rows_per_shard + 31) // 32
     out: dict = {}
     q_max = max(batch_sizes)
@@ -366,7 +376,7 @@ def insert_bench(batch_sizes=(64, 256, 1024), *, n: int = 8000, d: int = 64,
                  seed: int = 7, q_post: int = 64) -> dict:
     """Dynamic-insert rows (DESIGN.md §9): the ``search_bench`` corpus is
     built on a base prefix with capacity for the full n, then the held-out
-    rows are appended through ``BatchedEngine.insert_batch`` at each batch
+    rows are appended through ``Engine.insert_batch`` at each batch
     size — ``insert/b<B>`` rows report rows/sec of the whole append path
     (slab writes + reverse-edge graph repair + incremental atlas + device
     refresh). A final ``post_insert/q64/sel0.1`` row re-measures search QPS
@@ -397,7 +407,7 @@ def insert_bench(batch_sizes=(64, 256, 1024), *, n: int = 8000, d: int = 64,
                    ds.field_names, ds.vocab_sizes)
     atlas = AnchorAtlas.build(base, seed=cfg.atlas.kmeans_seed)
     index = FiberIndex(base.vectors, base.metadata, graph, atlas)
-    eng = BatchedEngine(index, config=cfg, vocab_sizes=ds.vocab_sizes)
+    eng = engine_over(index, cfg, vocab_sizes=ds.vocab_sizes)
     out: dict = {}
     loop = MaintenanceLoop(eng, cfg.maintenance)
     written = base_n
@@ -455,7 +465,7 @@ def lifecycle_bench(*, n: int = 8000, d: int = 64, k: int = 10,
     graph = build_alpha_knn(ds.vectors, config=cfg.graph)
     atlas = AnchorAtlas.build(ds, seed=cfg.atlas.kmeans_seed)
     index = FiberIndex(ds.vectors, ds.metadata, graph, atlas)
-    eng = BatchedEngine(index, config=cfg, vocab_sizes=ds.vocab_sizes)
+    eng = engine_over(index, cfg, vocab_sizes=ds.vocab_sizes)
     loop = MaintenanceLoop(eng, cfg.maintenance)
     rng = np.random.default_rng(seed)
     target = int(churn_frac * n)
@@ -578,7 +588,7 @@ def durability_bench(*, n: int = 8000, d: int = 64, k: int = 10,
             svc2.staleness(), written)
         qs = make_selectivity_queries(ds, 1, q_post)
         attach_ground_truth(ds, qs, k=k)
-        row = measure_batch(svc2._live_engine(), qs, reps)
+        row = measure_batch(svc2.engine(), qs, reps)
         out[f"post_recover/q{q_post}/sel0.1"] = row
     finally:
         shutil.rmtree(root, ignore_errors=True)
@@ -638,8 +648,7 @@ def slo_bench(*, q_total: int = 1024, n: int = 8000, d: int = 64,
         return out[key]
 
     if len(jax.devices()) >= n_shards * q_lanes:
-        from repro.core.batched.sharded import (ShardedEngine,
-                                                build_sharded_index)
+        from repro.core.batched.sharded import build_sharded_index
         from repro.launch.mesh import make_serving_mesh
 
         sidx = build_sharded_index(ds.vectors, ds.metadata, n_shards,
@@ -647,14 +656,14 @@ def slo_bench(*, q_total: int = 1024, n: int = 8000, d: int = 64,
         mesh = make_serving_mesh(data=n_shards, query=q_lanes)
         prefix = f"serve_slo/q{q_total}/mesh{n_shards}x{q_lanes}"
         cfg_rep = cfg.with_knobs({"mesh.query_parallel": False})
-        eng_rep = ShardedEngine(sidx, mesh, config=cfg_rep)
+        eng_rep = Engine(sidx, cfg_rep, mesh)
         svc_rep = RetrievalService(None, SearchParams(k=k), mesh=mesh,
-                                   config=cfg_rep, _ds=ds, _sharded=eng_rep)
+                                   config=cfg_rep, _ds=ds, _engine=eng_rep)
         base = run(svc_rep, f"{prefix}/replicated",
                    {"n_shards": n_shards, "q_lanes": 1})
-        eng_2d = ShardedEngine(sidx, mesh, config=cfg)
+        eng_2d = Engine(sidx, cfg, mesh)
         svc_2d = RetrievalService(None, SearchParams(k=k), mesh=mesh,
-                                  config=cfg, _ds=ds, _sharded=eng_2d)
+                                  config=cfg, _ds=ds, _engine=eng_2d)
         row = run(svc_2d, f"{prefix}/lanes",
                   {"n_shards": n_shards, "q_lanes": eng_2d.q_lanes})
         row["speedup_vs_replicated"] = row["qps"] / base["qps"]

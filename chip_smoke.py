@@ -5,9 +5,10 @@ Zipf fields (DESIGN.md §1), the paper's index settings (graph_k=64,
 r_max=128, k=25), data made from ``--seed``.
 
     python chip_smoke.py              # one TPU chip, every phase below
-    python chip_smoke.py --chips 4    # four chips: the sharded engine on a
-                                      # data=4 and a 2x2 query x data mesh
-                                      # vs its one-device reference, only
+    python chip_smoke.py --chips 4    # four chips: the engine's mesh
+                                      # program on a data=4 and a 2x2
+                                      # query x data mesh vs its one-device
+                                      # reference, only
     JAX_PLATFORMS=cpu python chip_smoke.py   # CPU rehearsal, n cut to 8,192
 
 One chip, in one process:
@@ -263,9 +264,10 @@ def search_program_text(eng, queries) -> str:
     """The optimized HLO of the engine's fused search program for this
     batch's table form (compiled already by the batch itself)."""
     q_vecs, fields, allowed, bounds = eng._pack_queries(queries)
-    return eng._search.lower(eng.datlas, eng.vectors, eng.adjacency,
-                             eng.metadata, q_vecs, fields, allowed,
-                             valid_bm=eng._valid_bm,
+    ix = eng.shards[0]
+    return eng._search.lower(ix.datlas, ix.vectors, ix.adjacency,
+                             ix.metadata, q_vecs, fields, allowed,
+                             valid_bm=ix.valid_bm,
                              bounds=bounds).compile().as_text()
 
 
@@ -300,7 +302,7 @@ def run_one_chip(args, checks: dict) -> None:
     say("build_s", f"{t_host + t_dev:.3f} (host graph+atlas {t_host:.3f}, "
         f"device placement {t_dev:.3f}; mean_degree "
         f"{g.degrees.mean():.1f} max_degree {int(g.degrees.max())} "
-        f"adjacency_width {eng.adjacency.shape[1]})")
+        f"adjacency_width {eng.shards[0].adjacency.shape[1]})")
 
     results = {}
     forms_seen = {}
@@ -309,11 +311,12 @@ def run_one_chip(args, checks: dict) -> None:
         (ids, stats), t_first = timed(svc.query_batch, qv, preds)
         qs = [Query(vector=v, predicate=pred) for v in qv]
         packed = eng._pack_queries(qs)
+        ix = eng.shards[0]
 
         def fused():
             return jax.block_until_ready(eng._search(
-                eng.datlas, eng.vectors, eng.adjacency, eng.metadata,
-                *packed[:3], valid_bm=eng._valid_bm, bounds=packed[3]))
+                ix.datlas, ix.vectors, ix.adjacency, ix.metadata,
+                *packed[:3], valid_bm=ix.valid_bm, bounds=packed[3]))
 
         out = fused()
         err = score_error(ds.vectors, packed[0], out["res_v"],
@@ -416,7 +419,7 @@ def run_four_chips(args, checks: dict) -> None:
         tag = f"mesh{data}x{query}"
         mesh = make_serving_mesh(data, query)
         svc = RetrievalService.build(ds, config=cfg, mesh=mesh)
-        eng, t_build = timed(svc.sharded_engine)
+        eng, t_build = timed(svc.engine)
         jax.block_until_ready(eng.vectors)
         spans = {d.id for d in mesh.devices.flat}
         placed = {d.id for d in eng.vectors.sharding.device_set}

@@ -12,7 +12,12 @@ predicate evaluation (batched ``filter_eval``), the restart round loop
 (an outer ``lax.while_loop`` over ``atlas_round`` — batched anchor
 selection from the packed ``DeviceAtlas`` + the lockstep walk), and the
 per-round walks/hops stats all run on device; the host syncs once per
-batch to fetch results. ``BatchedEngine.search_hostloop`` keeps the PR 1
+batch to fetch results.
+
+``Engine`` serves that program over a ``ShardedIndex`` of any shard
+count: on one device it runs ``search_batch`` itself, on a mesh the same
+program per shard under ``shard_map`` with an exact cross-shard merge
+(DESIGN.md §7, §13). ``Engine.search_hostloop`` keeps the original
 host-driven round loop (one jitted call per round, two scalar syncs) as
 the parity baseline.
 
@@ -28,31 +33,35 @@ and validated for recall parity in tests):
 """
 from __future__ import annotations
 
-import dataclasses
 import functools
+from typing import NamedTuple
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.profiler import TraceAnnotation
+from jax.sharding import PartitionSpec as P
 
 from repro import faults
 from repro.core.batched.bitmap import (n_words, pack_bits, popcount,
                                        set_bits, test_bits, unpack_bits)
-from repro.core.config import (FnsConfig, KernelConfig, WalkConfig,
-                               check_state_config, coerce_config)
-from repro.core.device_atlas import (DeviceAtlas, pack_dnf, pack_predicates,
-                                     table_n_disj)
+from repro.core.batched.insert import emit_device_atlas
+from repro.core.batched.lifecycle import pad_state
 from repro.core.batched.scopes import dispatch_program
+from repro.core.batched.sharded import ShardedIndex, merge_topk, stack_slabs
+from repro.core.config import (FnsConfig, KernelConfig, WalkConfig,
+                               check_state_config)
+from repro.core.device_atlas import (DeviceAtlas, pack_dnf, pack_predicates,
+                                     stack_atlases, table_n_disj)
 from repro.core.predicate import (DNF, And, AnyOf, FilterExpr, HasAny, Not,
                                   Or, as_dnf, disjunct_selectivity)
-from repro.core.search import FiberIndex, SearchParams
-from repro.core.tags import (check_tag_fields, first_columns, tag_columns,
-                             value_max)
+from repro.core.tags import first_columns, tag_columns
 from repro.core.types import FilterPredicate, Query
 from repro.kernels import ref
 from repro.kernels.filter_eval import filter_eval_batch
 from repro.kernels.ops import MAX_CLAUSES, on_tpu
+from repro.launch.mesh import index_axis_size, query_axis_name
+from repro.launch.shardings import index_shardings
 
 INF = jnp.float32(3.4e38)
 # the host twin of ``INF / 2``: a result slot at or above it is unfilled.
@@ -63,12 +72,6 @@ HIGHEST = jax.lax.Precision.HIGHEST
 TERM_RUNNING, TERM_CONVERGED, TERM_EARLY, TERM_STALL, TERM_MAXHOP = 0, 1, 2, 3, 4
 # the narrowest stage of the walk: the f32 sublane count
 LANE_FLOOR = 8
-
-# the walk-budget section of the unified config tree (core/config.py) IS
-# the engine's parameter object; the historical name stays importable and
-# constructible so every existing call site keeps working
-BatchedParams = WalkConfig
-
 
 def _merge_queue(q_v, q_i, new_v, new_i, cap: int):
     """Merge sorted queue (Q,cap) with candidates (Q,m); keep cap smallest."""
@@ -140,7 +143,7 @@ def _running_first(term, width: int):
 
 
 def walk_batch(vectors, adjacency, pass_bm, q_vecs, seeds,
-               p: BatchedParams, init_results=None):
+               p: WalkConfig, init_results=None):
     """One lockstep walk round.
 
     vectors (n, d) f32; adjacency (n, R) i32 (-1 pad); pass_bm
@@ -349,7 +352,7 @@ def walk_batch(vectors, adjacency, pass_bm, q_vecs, seeds,
 
 def atlas_round(datlas: DeviceAtlas, vectors, adjacency, pass_bm, passes,
                 q_vecs, fields, allowed, processed, need, res_v, res_i,
-                p: BatchedParams, bounds=None,
+                p: WalkConfig, bounds=None,
                 kcfg: KernelConfig | None = None):
     """One full restart round for all Q queries on device: batched anchor
     selection from the packed atlas, then the lockstep walk. ``pass_bm``
@@ -379,7 +382,7 @@ def atlas_round(datlas: DeviceAtlas, vectors, adjacency, pass_bm, passes,
 
 
 def search_batch(datlas: DeviceAtlas, vectors, adjacency, metadata, q_vecs,
-                 fields, allowed, p: BatchedParams,
+                 fields, allowed, p: WalkConfig,
                  valid_bm=None, bounds=None,
                  kcfg: KernelConfig | None = None, tag_cols=()):
     """A whole filtered search batch as ONE device program: batched
@@ -519,9 +522,8 @@ def _check_tag_use(pred, tag_fields) -> None:
 
 def pack_query_batch(queries: list[Query], *, v_cap: int,
                      vocab_sizes=None, tag_fields=()):
-    """Host-side query pack shared by the single-device and sharded
-    engines: (Q, d) vector stack + clause tables with the clause dimension
-    bucketed by ``clause_dim``.
+    """The engine's host-side query pack: (Q, d) vector stack + clause
+    tables with the clause dimension bucketed by ``clause_dim``.
 
     Predicates may be conjunctive ``FilterPredicate``s, ``FilterExpr``
     trees, or precompiled ``DNF``s; expressions compile against
@@ -569,7 +571,7 @@ def pack_query_batch(queries: list[Query], *, v_cap: int,
 
 
 def _fence_pack(eng, queries: list[Query], batch: int):
-    """Publish-generation fence (DESIGN.md §13), shared by both engines.
+    """Publish-generation fence (DESIGN.md §13) of ``Engine.dispatch``.
 
     Pack the batch, then check the engine's ``publish_generation`` — the
     counter every device publish (ingest refresh, tombstone, maintenance
@@ -604,10 +606,10 @@ def _found_ids(res_v: np.ndarray, res_i: np.ndarray, q_n: int,
 
 
 def fetch_results(token: dict, finish=None):
-    """Sync an in-flight batch, shared by both engines: the batch's one
-    host sync (host span ``fns.fetch``), then the result unpack (host span
-    ``fns.unpack``, which carries the batch's ``rounds``, ``iters``,
-    ``slots``, ``clause_passes`` and its queries' Σ ``hops``).
+    """Sync an in-flight batch: the batch's one host sync (host span
+    ``fns.fetch``), then the result unpack (host span ``fns.unpack``,
+    which carries the batch's ``rounds``, ``iters``, ``slots``,
+    ``clause_passes`` and its queries' Σ ``hops``).
 
     ``token`` holds the program's output (``out``), the query count to
     keep (``q_n``), the batch number (``batch``), and optionally the
@@ -640,345 +642,461 @@ def fetch_results(token: dict, finish=None):
         return (ids, stats) if finish is None else finish(ids, stats)
 
 
-class BatchedEngine:
-    """Single-dispatch batched search over a device-resident index.
 
-    ``search`` issues exactly one jitted call per batch (predicate eval +
-    restart loop + walks fused in ``search_batch``) and one host sync to
-    fetch results; ``dispatches`` counts compiled-callable invocations so
-    tests can assert that. ``search_hostloop`` keeps the PR 1 host-driven
-    round loop (one jitted ``atlas_round`` per round) as the parity and
-    migration baseline. On the TPU the per-round state buffers
-    (processed/need/res_v/res_i) are donated into the round call.
 
-    ``serve.capacity`` (DESIGN.md §9) turns the device index into an
-    append-able capacity slab: arrays are sized to ``capacity`` rows, a
-    row-validity bitmap masks the unwritten tail out of every pass set,
-    and ``insert_batch`` grows the corpus in place (graph repair +
-    incremental atlas update on a host mirror, then a same-shape device
-    refresh — the compiled search program is reused, and ``self.index``
-    keeps the build-time snapshot). ``graph.graph_k``/``graph.alpha`` are
-    the append path's forward-edge count and α-RNG slack.
+class DeviceShard(NamedTuple):
+    """The device arrays the search program reads: one shard's, or, with
+    a leading shard dim, every shard's (partitioned over a mesh)."""
 
-    Every knob arrives through one ``FnsConfig`` (``config=``, stored as
-    ``self.cfg``); the historical kwargs (``params=``/positional
-    BatchedParams, ``capacity=``, ``graph_k=``, ``alpha=``) are
-    deprecation shims that warn once and fold into it.
+    datlas: DeviceAtlas
+    vectors: jax.Array     # (m, d) f32
+    adjacency: jax.Array   # (m, R) i32 shard-local ids, -1 padded
+    metadata: jax.Array    # (m, F) i32
+    valid_bm: jax.Array    # (ceil(m/32),) u32 packed row validity
+    global_ids: jax.Array  # (m,) i32 local row -> global id, -1 = pad
+
+
+class Engine:
+    """One-dispatch filtered search over a ``ShardedIndex`` of S >= 1
+    shards. Every knob arrives through one ``FnsConfig`` (``self.cfg``).
+
+    The program follows from what the engine can observe:
+
+    * a ``mesh`` spanning more than one device on its ``data`` axis (which
+      must hold the S shards) or on a query axis runs the fused per-shard
+      ``search_batch`` under ``shard_map``: index rows partitioned over
+      ``data``, local result ids mapped to global ids, one
+      ``lax.all_gather`` and an exact top-k merge (``merge_topk``) on
+      device. On a 2D query×data mesh (DESIGN.md §13) the batch is also
+      partitioned over the query axis: each of the ``q_lanes`` lane groups
+      walks Q/q_lanes queries against every shard. Per-query state is
+      row-independent, so results are bit-identical to replicated queries
+      and to ``search_reference``;
+    * no such mesh and one shard runs ``search_batch`` itself (XLA module
+      ``jit_search_batch``) on the shard's arrays, and maps ids to global
+      ids on the host;
+    * no such mesh and S > 1 shards (a snapshot restored onto fewer
+      devices, DESIGN.md §10) runs the per-shard programs one after the
+      other on the default device with the same merge
+      (``_run_reference``, also the oracle of ``search_reference``).
+
+    Either way a batch is one dispatch and one host sync (S dispatches in
+    the last case); ``dispatches`` counts them. ``dispatch``/``collect``
+    split a batch into an async half (fenced pack + device call) and a
+    sync half, so a serving pipeline can overlap batch N+1's staging with
+    batch N's device time. The index arrays are placed once at
+    construction and again at each publish, never per batch; the
+    validity bitmap is the one liveness source the program reads.
+
+    An index built with ``serve.capacity`` carries a host ``InsertState``
+    (DESIGN.md §9, §12): ``insert_batch``/``delete_batch`` and the
+    maintenance loop mutate it, and ``refresh_device`` re-places the
+    shards they touched at the same shapes, so the compiled program is
+    reused.
     """
 
-    def __init__(self, index: FiberIndex, config=None,
-                 v_cap: int | None = None,
-                 vocab_sizes=None, capacity: int | None = None,
-                 graph_k: int | None = None, alpha: float | None = None,
-                 params: BatchedParams | None = None):
-        from repro.core.batched.insert import (InsertState,
-                                               emit_device_atlas,
-                                               make_shard_state)
-
-        if config is None:
-            config = params
-        # this entry point's historical append-path default (graph_k=16)
-        # predates the config tree's 32; applied silently unless a full
-        # FnsConfig states otherwise
-        cfg = coerce_config(config,
-                            {"serve.capacity": capacity,
-                             "graph.graph_k": graph_k,
-                             "graph.alpha": alpha},
-                            where="BatchedEngine",
-                            defaults={"graph.graph_k": 16})
-        # non-knob plumbing args (bitmap width, domains) stay
-        # first-class: fold without deprecation noise
-        if v_cap is not None:
-            cfg = cfg.with_knobs({"atlas.v_cap": v_cap})
-        self.cfg = cfg
-        self.index = index
-        self.p = cfg.walk
-        v_cap = cfg.atlas.v_cap
-        capacity = cfg.serve.capacity
-        n = index.vectors.shape[0]
-        # the schema's tag-set fields; the search program is compiled for
-        # them (a clause's test is picked by its field)
-        self.tag_fields = index.tag_fields
-        check_tag_fields(self.tag_fields, index.metadata.shape[1], v_cap)
-        if capacity is None:
-            self.datlas = index.atlas.to_device(v_cap=v_cap)
-            self.vectors = jnp.asarray(index.vectors)
-            self.adjacency = jnp.asarray(index.graph.neighbors)
-            self.metadata = jnp.asarray(index.metadata)
-            self._state = None
-            self._valid_bm = None
-        else:
-            if capacity < n:
-                raise ValueError(f"capacity {capacity} < corpus size {n}")
-            # widen the row width for the append path's 1.5x graph_k
-            # forward edges (mirrors build_sharded_index)
-            graph_k = cfg.graph.graph_k
-            adj = np.asarray(index.graph.neighbors, np.int32)
-            w = max(adj.shape[1], graph_k + graph_k // 2)
-            if w > adj.shape[1]:
-                adj = np.concatenate(
-                    [adj, np.full((n, w - adj.shape[1]), -1, np.int32)],
-                    axis=1)
-            slab = make_shard_state(
-                np.asarray(index.vectors, np.float32),
-                np.asarray(index.metadata, np.int32),
-                np.arange(n, dtype=np.int32), adj,
-                index.atlas, cap=capacity)
-            if v_cap is None:
-                # same auto-sizing rule as AnchorAtlas.to_device
-                from repro.core.device_atlas import auto_v_cap
-                v_cap = auto_v_cap(value_max(index.metadata,
-                                             self.tag_fields))
-            self._state = InsertState(shards=[slab], v_cap=v_cap,
-                                      graph_k=graph_k, alpha=cfg.graph.alpha,
-                                      seed=0, next_gid=n,
-                                      tag_fields=self.tag_fields)
-            self._refresh_from_slab(v_cap)
-        # per-field domains for Not/Range lowering in FilterExpr queries;
-        # derived from observed codes when the dataset's declaration isn't
-        # handed in (identical masks for any domain covering the corpus)
-        self.vocab_sizes = (tuple(int(v) for v in vocab_sizes)
-                            if vocab_sizes is not None
-                            else index.vocab_sizes())
+    def __init__(self, sindex: ShardedIndex, config: FnsConfig | None = None,
+                 mesh=None):
+        self.cfg = config if config is not None else FnsConfig()
+        self.p = self.cfg.walk
+        s = sindex.n_shards
+        q_axis = (query_axis_name(mesh, self.cfg.mesh.query_axes)
+                  if mesh is not None and self.cfg.mesh.query_parallel
+                  else None)
+        if mesh is not None and index_axis_size(mesh) == 1 and q_axis is None:
+            mesh = None  # one device's worth of shards and lanes
+        if mesh is not None and index_axis_size(mesh) != s:
+            raise ValueError(
+                f"index has {s} shards but mesh axis 'data' spans "
+                f"{index_axis_size(mesh)} devices")
+        self.mesh, self.q_axis = mesh, q_axis
+        self.q_lanes = int(mesh.shape[q_axis]) if q_axis is not None else 1
+        self.n_shards = s
+        self.v_cap = sindex.atlases[0].v_cap
+        self.vocab_sizes = sindex.vocab_sizes
+        self.tag_fields = sindex.tag_fields
+        self._state = sindex.insert_state
+        # host arrays, stacked over shards: what each publish places
+        self._host = {k: getattr(sindex, k) for k in
+                      ("vectors", "adjacency", "metadata", "global_ids",
+                       "valid")}
+        self._atlases = list(sindex.atlases)
+        if mesh is not None:
+            sh = index_shardings(mesh, query_axis=q_axis)
+            self._put = functools.partial(jax.device_put, device=sh["rows"])
+            # queries are staged on their sharding explicitly, so batch
+            # N+1's host->device transfer overlaps batch N's device time
+            self._q_put = functools.partial(jax.device_put,
+                                            device=sh["queries"])
+        self.stacked: DeviceShard | None = None  # on a mesh
+        self.shards: tuple[DeviceShard, ...] = ()  # without one
+        self._place(range(s))
+        self.publish_generation = 0
         self._init_programs()
 
     @classmethod
-    def from_state(cls, state, config=None, vocab_sizes=None,
-                   params: BatchedParams | None = None) -> "BatchedEngine":
-        """Reconstruct a live capacity-slab engine from a restored
-        ``InsertState`` (DESIGN.md §10) with ZERO graph/atlas rebuild: the
-        slab already carries the patched adjacency and the incremental
-        atlas, so everything derived (device atlas CSR, validity bitmap,
-        the sequential-path FiberIndex view) is re-*emitted*, never
-        re-built. Further ``insert_batch`` calls continue seamlessly.
+    def from_state(cls, state, config: FnsConfig | None = None, mesh=None, *,
+                   vocab_sizes=None) -> "Engine":
+        """A live engine from a restored ``InsertState`` (DESIGN.md §10)
+        on whatever mesh this process has, with ZERO graph/atlas rebuild
+        (``ShardedIndex.from_state``). A mesh whose ``data`` axis spans
+        more devices than the state has shards gets empty shards
+        (``lifecycle.pad_state``, exact); one spanning fewer runs the
+        shards one after the other on the default device, with the same
+        results.
 
-        An explicit full ``FnsConfig`` is validated against the state's
-        shape-baked knobs (``ConfigMismatch`` on disagreement — e.g. a
-        snapshot built at graph_k=16 cannot restore under graph_k=32)."""
-        from repro.core.batched.insert import emit_anchor_atlas, emit_graph
-
-        if len(state.shards) != 1:
-            raise ValueError(
-                f"BatchedEngine.from_state needs a 1-shard state, got "
-                f"{len(state.shards)} shards (use ShardedEngine)")
+        A given ``config`` is checked against the state's shape-baked
+        knobs (``ConfigMismatch`` on conflict: graph_k, v_cap, n_clusters
+        and capacity are burned into the slabs); without one the engine
+        takes the default tree with the state's baked knobs."""
         if config is None:
-            config = params
-        cfg = coerce_config(config, {}, where="BatchedEngine.from_state")
-        if isinstance(config, FnsConfig):
+            config = FnsConfig().with_knobs(
+                {"graph.graph_k": state.graph_k, "graph.alpha": state.alpha,
+                 "atlas.v_cap": state.v_cap})
+        else:
             check_state_config(
-                cfg, graph_k=state.graph_k, v_cap=state.v_cap,
+                config, graph_k=state.graph_k, v_cap=state.v_cap,
                 n_clusters=state.shards[0].atlas.n_clusters,
                 capacity=sum(sh.cap for sh in state.shards),
-                where="BatchedEngine.from_state")
-        else:
-            # fold the restored state's baked values so self.cfg reports
-            # the truth even for legacy callers
-            cfg = cfg.with_knobs({"graph.graph_k": state.graph_k,
-                                  "graph.alpha": state.alpha,
-                                  "atlas.v_cap": state.v_cap})
-        slab = state.shards[0]
-        eng = cls.__new__(cls)
-        eng.cfg = cfg
-        eng.tag_fields = state.tag_fields
-        eng.index = FiberIndex(
-            slab.vectors[: slab.n_valid].copy(),
-            slab.metadata[: slab.n_valid].copy(),
-            emit_graph(slab), emit_anchor_atlas(slab, state.tag_fields),
-            tag_fields=state.tag_fields)
-        eng.p = cfg.walk
-        eng._state = state
-        eng._refresh_from_slab(state.v_cap)
-        eng.vocab_sizes = (tuple(int(v) for v in vocab_sizes)
-                           if vocab_sizes is not None
-                           else eng.index.vocab_sizes())
-        eng.index.extend_vocab(eng.vocab_sizes)
-        eng._init_programs()
-        return eng
-
-    def _refresh_from_slab(self, v_cap: int) -> None:
-        """(Re)place the device arrays from the host slab mirror at fixed
-        shapes — shared by construction, ingest, and snapshot restore."""
-        from repro.core.batched.insert import emit_device_atlas
-
-        slab = self._state.shards[0]
-        self.datlas = emit_device_atlas(slab, v_cap, self._state.tag_fields)
-        self.vectors = jnp.asarray(slab.vectors)
-        self.adjacency = jnp.asarray(slab.adjacency)
-        self.metadata = jnp.asarray(slab.metadata)
-        self._valid_bm = pack_bits(jnp.asarray(slab.valid))
-        # getattr: the first refresh runs from __init__/from_state before
-        # the counters exist
-        self.publish_generation = getattr(self, "publish_generation", 0) + 1
+                where="Engine.from_state")
+        target = index_axis_size(mesh) if mesh is not None else 1
+        if target > len(state.shards):
+            pad_state(state, target)
+        elif target < len(state.shards):
+            mesh = None
+        return cls(ShardedIndex.from_state(state, vocab_sizes), config, mesh)
 
     def _init_programs(self) -> None:
-        params = self.p
-        kcfg = self.cfg.kernel
+        p, kcfg = self.p, self.cfg.kernel
         tag_cols = first_columns(self.tag_fields)
-        # the CPU backend ignores donation; search_batch's inputs have no
-        # same-shaped output to alias, so only the round state is donated
-        self._round = jax.jit(
-            functools.partial(atlas_round, p=params, kcfg=kcfg),
-            donate_argnums=(8, 9, 10, 11) if on_tpu() else ())
+        search = functools.partial(search_batch, p=p, kcfg=kcfg,
+                                   tag_cols=tag_cols)
         # named after search_batch, so the trace's module says which
         # program ran and its recorded scopes (scopes.py) are its own
-        self._search = jax.jit(functools.update_wrapper(
-            functools.partial(search_batch, p=params, kcfg=kcfg,
-                              tag_cols=tag_cols),
-            search_batch))
+        self._shard_search = jax.jit(functools.update_wrapper(
+            search, search_batch))
+        self._search = (self._mesh_program(search) if self.mesh is not None
+                        else self._shard_search)
+        # the host-loop baseline's programs; the CPU backend ignores
+        # donation, so only the round state is donated on the TPU
+        self._round = jax.jit(
+            functools.partial(atlas_round, p=p, kcfg=kcfg),
+            donate_argnums=(8, 9, 10, 11) if on_tpu() else ())
         self._passes = jax.jit(functools.partial(_eval_passes, kcfg=kcfg,
                                                  tag_cols=tag_cols))
         self.dispatches = 0
-        self.publish_generation = getattr(self, "publish_generation", 0)
         self.fence_retries = 0
+
+    def _mesh_program(self, search):
+        k = self.p.k
+
+        def sharded_search(ix: DeviceShard, q_vecs, fields, allowed,
+                           bounds):
+            ix = jax.tree.map(lambda leaf: leaf[0], ix)
+            out = search(ix.datlas, ix.vectors, ix.adjacency, ix.metadata,
+                         q_vecs, fields, allowed, valid_bm=ix.valid_bm,
+                         bounds=bounds)
+            gids = jnp.where(out["res_i"] >= 0,
+                             ix.global_ids[jnp.maximum(out["res_i"], 0)], -1)
+            res_v, res_i = merge_topk(
+                jax.lax.all_gather(out["res_v"], "data"),
+                jax.lax.all_gather(gids, "data"), k)
+            # the slowest shard's rounds, iterations and slots, one per
+            # query lane so that they follow the queries' layout; every
+            # shard sweeps its lane's clause tables alike
+            lane = {key: jax.lax.pmax(out[key], "data")[None]
+                    for key in ("rounds", "iters", "slots", "clause_passes")}
+            return dict(res_v=res_v, res_i=res_i,
+                        hops=jax.lax.psum(out["hops"], "data"),
+                        walks=jax.lax.psum(out["walks"], "data"), **lane)
+
+        # index leaves are partitioned row-wise over ``data``; the query
+        # tensors (and the bounds table, when the batch has interval
+        # clauses) are replicated, or split on their batch dim over the
+        # query axis, and the outputs follow the queries
+        q = P(self.q_axis) if self.q_axis is not None else P()
+        return jax.jit(jax.shard_map(sharded_search, mesh=self.mesh,
+                                     in_specs=(P("data"), q, q, q, q),
+                                     out_specs=q, check_vma=False))
+
+    # -- placement and the document lifecycle (DESIGN.md §9, §12) ---------
+
+    def _place(self, touched) -> None:
+        """Put the touched shards' host arrays on the device(s): on a mesh
+        the stacks are placed whole on the ``data`` sharding; without one
+        each touched shard is placed on its own, so the program reads it
+        with no slicing."""
+        h = self._host
+        if self.mesh is not None:
+            put = self._put
+            self.stacked = DeviceShard(
+                put(stack_atlases(self._atlases)), put(h["vectors"]),
+                put(h["adjacency"]), put(h["metadata"]),
+                put(pack_bits(jnp.asarray(h["valid"]))),
+                put(h["global_ids"]))
+            return
+        shards = list(self.shards) or [None] * self.n_shards
+        for s in touched:
+            shards[s] = DeviceShard(
+                self._atlases[s], jnp.asarray(h["vectors"][s]),
+                jnp.asarray(h["adjacency"][s]), jnp.asarray(h["metadata"][s]),
+                pack_bits(jnp.asarray(h["valid"][s])),
+                jnp.asarray(h["global_ids"][s]))
+        self.shards = tuple(shards)
+
+    def _live_state(self, what: str):
+        if self._state is None:
+            raise ValueError(
+                f"index has no insert state; {what} need an index built "
+                f"with spare capacity (config serve.capacity)")
+        return self._state
 
     def insert_batch(self, vectors, metadata, *,
                      gids: np.ndarray | None = None) -> np.ndarray:
-        """Append (vector, metadata) rows to the live index: slab writes +
-        validity-bit flips, reverse-edge graph repair, and the incremental
-        atlas update run on the host mirror, then the device arrays are
-        refreshed (no extra search dispatches; shapes only change when the
-        slab outgrew its capacity, in which case ``ensure_capacity``
-        compacts/grows first and the jitted program retraces once). With
-        ``maintenance.defer_repair`` the repair half is queued for the
-        maintenance loop instead. ``gids`` re-introduces deleted documents
-        under their old ids (still-live ids are rejected). Returns the new
-        rows' ids."""
+        """Append (vector, metadata) rows to the live index (DESIGN.md §9):
+        balance-aware shard placement, slab writes + validity-bit flips,
+        reverse-edge graph repair and incremental atlas updates run on the
+        host mirror (with ``maintenance.defer_repair`` the repair half is
+        queued for the maintenance loop); then the touched shards are
+        re-placed at the same shapes, so the compiled program is reused.
+        When the slabs outgrow their capacity, ``ensure_capacity``
+        compacts or grows them first and the program retraces once.
+        ``gids`` re-introduces deleted documents under their old ids
+        (still-live ids are rejected). Returns the new rows' global ids.
+        Ingest costs transfers only: ``dispatches`` is untouched."""
         from repro.core.batched.insert import insert_rows
         from repro.core.batched.lifecycle import ensure_capacity
 
-        if self._state is None:
-            raise ValueError(
-                "engine was built without spare capacity; construct "
-                "BatchedEngine(..., capacity=...) to enable insert_batch")
-        mcfg = self.cfg.maintenance
-        room = ensure_capacity(self._state, np.asarray(vectors).shape[0],
-                               mcfg)
+        st, mcfg = self._live_state("inserts"), self.cfg.maintenance
+        room = ensure_capacity(st, np.asarray(vectors).shape[0], mcfg)
         if room["grown"]:
             # keep the shape-baked knob truthful for snapshot/restore
             self.cfg = self.cfg.with_knobs(
-                {"serve.capacity": room["new_cap"]})
-        gids, _ = insert_rows(self._state, vectors, metadata, gids=gids,
-                              defer_repair=mcfg.defer_repair)
-        self._refresh_from_slab(self.datlas.v_cap)
-        self.vocab_sizes = self._state.expand_vocab(self.vocab_sizes)
-        # keep the sequential path's memoized domains in sync: Not /
-        # open-ended-Range lowering reads index.vocab_sizes(), which would
-        # otherwise silently miss codes first introduced by this ingest
-        self.index.extend_vocab(self.vocab_sizes)
+                {"serve.capacity": room["new_cap"] * len(st.shards)})
+        gids, touched = insert_rows(st, vectors, metadata, gids=gids,
+                                    defer_repair=mcfg.defer_repair)
+        moved = room["compacted"] or room["grown"]
+        self.refresh_device(None if moved else touched)
         return gids
 
     def delete_batch(self, gids) -> int:
         """Tombstone documents by global id (DESIGN.md §12): clear their
-        validity bits on the host mirror and re-place the packed bitmap —
-        the ONLY liveness source the fused search reads — so the cost is
-        one bit-pack + transfer. No recompile, no graph or atlas work (the
-        dead rows keep routing walks until compaction recycles them).
+        bits on the host mirror and re-place the packed validity bitmap —
+        the one liveness source the fused search reads — so a delete costs
+        one bit-pack + transfer. No recompile, no graph/atlas work
+        (tombstones keep routing walks until compaction recycles them).
         Returns the number of rows tombstoned."""
         from repro.core.batched.lifecycle import delete_rows
 
-        if self._state is None:
-            raise ValueError(
-                "engine was built without spare capacity; deletes need a "
-                "capacity-slab engine (BatchedEngine(..., capacity=...))")
-        n, _ = delete_rows(self._state, gids)
-        self._valid_bm = pack_bits(jnp.asarray(self._state.shards[0].valid))
+        st = self._live_state("deletes")
+        n, touched = delete_rows(st, gids)
+        valid = self._host["valid"]
+        for s in touched:
+            valid[s] = st.shards[s].valid
+        if self.mesh is not None:
+            self.stacked = self.stacked._replace(
+                valid_bm=self._put(pack_bits(jnp.asarray(valid))))
+        else:
+            self.shards = tuple(
+                sh._replace(valid_bm=pack_bits(jnp.asarray(valid[s])))
+                if s in touched else sh for s, sh in enumerate(self.shards))
         self.publish_generation += 1
         return n
 
-    def refresh_device(self, touched=None) -> None:
-        """Re-place the device arrays from the host slab after host-side
-        maintenance (compaction, growth, deferred repair). The uniform
-        engine hook ``MaintenanceLoop`` publishes through."""
-        del touched  # one shard: a refresh is always full
-        if self._state is not None:
-            self._refresh_from_slab(self.datlas.v_cap)
+    def refresh_device(self, touched: list[int] | None = None) -> None:
+        """Re-place the touched shards (None = all) from the host mirror
+        after host-side work (ingest, compaction, growth, deferred repair):
+        copy their slabs into the host stacks, re-emit their atlases and
+        place them. The engine hook ``MaintenanceLoop`` publishes through.
+        Slab growth changes the shapes, so every shard is re-stacked and
+        the program retraces once."""
+        st = self._state
+        if st is None:
+            return
+        if self._host["vectors"].shape[1] != st.shards[0].cap:
+            self._host = stack_slabs(st.shards)
+            touched = None
+        if touched is None:
+            touched = range(self.n_shards)
+        for s in touched:
+            sl = st.shards[s]
+            for k, host in self._host.items():
+                host[s] = getattr(sl, k)
+            self._atlases[s] = emit_device_atlas(sl, self.v_cap,
+                                                 st.tag_fields)
+        self._place(touched)
+        self.vocab_sizes = st.expand_vocab(self.vocab_sizes)
+        self.publish_generation += 1
 
     @property
     def state(self):
-        """The host ``InsertState`` mirror (None on a fixed-size engine) —
+        """The host ``InsertState`` mirror (None on a build-once index) —
         what the lifecycle/maintenance subsystem mutates."""
         return self._state
 
     @property
     def insert_stats(self) -> dict | None:
-        """Ingest/staleness accounting, or None on a fixed-size engine."""
+        """Ingest/staleness accounting, or None on a build-once index."""
         return self._state.stats() if self._state is not None else None
 
+    @property
+    def vectors(self) -> jax.Array:
+        """The device vectors: stacked (S, m, d) over the mesh, else the
+        first shard's (m, d)."""
+        if self.stacked is not None:
+            return self.stacked.vectors
+        return self.shards[0].vectors
+
+    # -- search ---------------------------------------------------------
+
     def _pack_queries(self, queries: list[Query]):
-        return pack_query_batch(queries, v_cap=self.datlas.v_cap,
+        return pack_query_batch(queries, v_cap=self.v_cap,
                                 vocab_sizes=self.vocab_sizes,
                                 tag_fields=self.tag_fields)
 
+    def _pad_to_lanes(self, queries: list[Query]) -> list[Query]:
+        """Pad the batch to a multiple of the query-axis size (shard_map
+        needs the partitioned dim divisible by the axis). Pads are inert —
+        ``FilterExpr.never()`` admits no point, so they never seed — and
+        carry a unit basis vector: a zero vector would go NaN under cosine
+        normalization and could poison the lane's top-k merge."""
+        rem = len(queries) % self.q_lanes
+        if rem == 0:
+            return queries
+        basis = np.zeros(np.asarray(queries[0].vector).shape, np.float32)
+        basis[0] = 1.0
+        dummy = Query(vector=basis, predicate=FilterExpr.never())
+        return list(queries) + [dummy] * (self.q_lanes - rem)
+
     def _gid_map(self) -> np.ndarray | None:
-        """A snapshot of the slab-row -> global-id map, None without a
-        capacity slab. Identity until the first compaction moves rows
-        (build + append assign gid == row), so it only matters on an index
-        with a document lifecycle."""
+        """A snapshot of one shard's row -> global-id map, None without an
+        insert state (then rows are global ids). Identity until the first
+        compaction moves rows."""
         if self._state is None:
             return None
         return self._state.shards[0].global_ids.copy()
 
     def dispatch(self, queries: list[Query], seed: int = 0, *,
                  batch: int = -1) -> dict:
-        """Fenced pack + ONE jitted call; returns an in-flight token
-        without syncing the host. jax's async dispatch means the device
-        crunches batch N while the host packs batch N+1 — the overlap the
-        serve pipeline (serve/pipeline.py) is built on. The token snapshots
-        the global-id map and the publish generation, so a compaction that
-        remaps rows between dispatch and collect can't mistranslate the
-        in-flight batch's results. ``batch`` is the caller's batch number,
-        carried by the host spans (``fns.pack``, ``fns.dispatch``, and at
-        collect ``fns.fetch``, ``fns.unpack``); -1 outside a service."""
+        """Fenced pack + the batch's device call; returns an in-flight
+        token without syncing the host. jax's async dispatch means the
+        device crunches batch N while the host packs batch N+1 — the
+        overlap the serve pipeline (serve/pipeline.py) is built on. The
+        token snapshots the publish generation and, on one shard without
+        a mesh, the global-id map, so a compaction that remaps rows
+        between dispatch and collect can't mistranslate the in-flight
+        batch's results. ``batch`` is the caller's batch number, carried
+        by the host spans (``fns.pack``, ``fns.dispatch``, and at collect
+        ``fns.fetch``, ``fns.unpack``); -1 outside a service."""
         del seed
-        (q_vecs, fields, allowed, bounds), gen = _fence_pack(self, queries,
-                                                             batch)
-        out = dispatch_program(self._search, batch, self.datlas,
-                               self.vectors, self.adjacency, self.metadata,
-                               q_vecs, fields, allowed,
-                               valid_bm=self._valid_bm, bounds=bounds)
-        self.dispatches += 1
-        return {"out": out, "q_n": len(queries), "generation": gen,
-                "gids": self._gid_map(), "batch": batch}
+        packed, gen = _fence_pack(self, self._pad_to_lanes(queries), batch)
+        token = {"q_n": len(queries), "generation": gen, "batch": batch}
+        if self.mesh is not None:
+            token["out"] = dispatch_program(
+                self._search, batch, self.stacked,
+                *[self._q_put(a) for a in packed])
+            self.dispatches += 1
+        elif self.n_shards == 1:
+            q_vecs, fields, allowed, bounds = packed
+            ix = self.shards[0]
+            token["out"] = dispatch_program(
+                self._search, batch, ix.datlas, ix.vectors, ix.adjacency,
+                ix.metadata, q_vecs, fields, allowed, valid_bm=ix.valid_bm,
+                bounds=bounds)
+            token["gids"] = self._gid_map()
+            self.dispatches += 1
+        else:
+            with TraceAnnotation("fns.dispatch", batch=batch):
+                token["out"] = self._run_reference(*packed)
+            self.dispatches += self.n_shards
+        return token
 
     def collect(self, token: dict, finish=None):
         """Sync an in-flight ``dispatch`` token: the batch's single host
         sync + result/stat post-processing (``fetch_results``).
-        ``stats["generation"]`` is the scalar publish generation the batch
-        was dispatched against; ``stats["rounds"]``/``stats["iters"]`` the
-        restart rounds and lockstep iterations the program ran,
-        ``stats["slots"]`` the lanes those iterations computed."""
+        ``stats["generation"]`` is the publish generation the batch was
+        dispatched against; ``stats["rounds"]``/``["iters"]``/``["slots"]``
+        the restart rounds, lockstep iterations and lanes computed of the
+        slowest shard."""
         return fetch_results(token, finish)
 
     def search(self, queries: list[Query], seed: int = 0, *,
                batch: int = -1, finish=None):
         """Filtered top-k for a batch: one device dispatch, one host sync.
         ``seed`` is kept for API compat; the device path is deterministic
-        (seeds are nearest matching members, never random samples)."""
+        (seeds are nearest matching members, never random samples). Walks
+        and hops sum over shards (every shard walks every query)."""
         del seed
         return self.collect(self.dispatch(queries, batch=batch), finish)
 
+    def _run_reference(self, q_vecs, fields, allowed, bounds):
+        """The per-shard ``search_batch`` programs one shard at a time on
+        the default device, merged by ``merge_topk`` in shard order: the
+        program of an engine without a mesh over S > 1 shards, and the
+        ``search_reference`` oracle. No host sync. A mesh engine's shards
+        are copied to the default device first: a program over
+        mesh-sharded inputs would be partitioned, and a Pallas kernel
+        cannot be partitioned automatically."""
+        shards = self.shards
+        if self.mesh is not None:
+            local = functools.partial(jax.device_put,
+                                      device=jax.devices()[0])
+            shards = [jax.tree.map(lambda leaf: local(leaf[s]), self.stacked)
+                      for s in range(self.n_shards)]
+        per_v, per_i, hops, walks = [], [], 0, 0
+        rounds = iters = slots = passes = 0
+        for ix in shards:
+            out = self._shard_search(ix.datlas, ix.vectors, ix.adjacency,
+                                     ix.metadata, q_vecs, fields, allowed,
+                                     valid_bm=ix.valid_bm, bounds=bounds)
+            per_v.append(out["res_v"])
+            per_i.append(jnp.where(
+                out["res_i"] >= 0,
+                ix.global_ids[jnp.maximum(out["res_i"], 0)], -1))
+            hops = hops + out["hops"]
+            walks = walks + out["walks"]
+            rounds = jnp.maximum(rounds, out["rounds"])
+            iters = jnp.maximum(iters, out["iters"])
+            slots = jnp.maximum(slots, out["slots"])
+            passes = jnp.maximum(passes, out["clause_passes"])
+        res_v, res_i = merge_topk(jnp.stack(per_v), jnp.stack(per_i),
+                                  self.p.k)
+        return dict(res_v=res_v, res_i=res_i, hops=hops, walks=walks,
+                    rounds=rounds, iters=iters, slots=slots,
+                    clause_passes=passes)
+
+    def search_reference(self, queries: list[Query]):
+        """Single-device fused baseline: the per-shard ``search_batch``
+        programs run shard-at-a-time on the default device, merged by the
+        same ``merge_topk`` in the same shard order. The mesh path must
+        match it bit-for-bit (tested at selectivities {0.5, 0.1, 0.02} on
+        1D and 2D meshes)."""
+        out = self._run_reference(*self._pack_queries(queries))
+        return fetch_results({"out": out, "q_n": len(queries), "batch": -1})
+
     def search_hostloop(self, queries: list[Query], seed: int = 0):
-        """PR 1 semantics: host round loop, one jitted select+walk call and
-        two scalar syncs per round. Kept as the exact-parity baseline for
-        ``search`` (tests) and for incremental debugging; it counts
+        """The original semantics on one shard without a mesh: a host round
+        loop, one jitted select+walk call and two scalar syncs per round.
+        Kept as the exact-parity baseline for ``search`` (tests); it counts
         ``rounds``, ``iters`` and ``slots`` from its own loop."""
         del seed
-        p = self.p
+        if self.mesh is not None or self.n_shards != 1:
+            raise ValueError("search_hostloop runs one shard without a mesh")
+        ix, p = self.shards[0], self.p
         Q = len(queries)
         q_vecs, fields, allowed, bounds = self._pack_queries(queries)
-        pass_bm = self._passes(self.metadata, fields, allowed, bounds)
-        if self._valid_bm is not None:  # capacity slab: mask unwritten rows
-            pass_bm = pass_bm & self._valid_bm[None, :]
+        pass_bm = self._passes(ix.metadata, fields, allowed, bounds)
+        pass_bm = pass_bm & ix.valid_bm[None, :]
         self.dispatches += 1
-        passes = unpack_bits(pass_bm, self.vectors.shape[0])
-        processed = jnp.zeros((Q, self.datlas.n_clusters), bool)
+        passes = unpack_bits(pass_bm, ix.vectors.shape[0])
+        processed = jnp.zeros((Q, ix.datlas.n_clusters), bool)
         need = popcount(pass_bm) > 0  # mirror search_batch's need init
         res_v = jnp.full((Q, p.k), INF)
         res_i = jnp.full((Q, p.k), -1, jnp.int32)
         stats = {"walks": np.zeros(Q, np.int32), "hops": np.zeros(Q, np.int64),
                  "rounds": 0, "iters": 0, "slots": 0}
         for _ in range(p.jump_budget + 1):
-            out = self._round(self.datlas, self.vectors, self.adjacency,
+            out = self._round(ix.datlas, ix.vectors, ix.adjacency,
                               pass_bm, passes, q_vecs, fields, allowed,
                               processed, need, res_v, res_i, bounds=bounds)
             self.dispatches += 1

@@ -24,13 +24,13 @@ is what makes a row visible. An insert batch:
    last (re)cluster or its centroid has drifted past ``recluster_drift``
    in cosine distance.
 
-All state here is HOST state (numpy): the engines own the device copies
-and refresh them from the touched shards after each batch. The one
+All state here is HOST state (numpy): the engine owns the device copies
+and refreshes them from the touched shards after each batch. The one
 dispatch / one host sync contract of ``search_batch`` is untouched —
 ingest costs transfers, never extra search dispatches.
 
 ``python -m repro.core.batched.insert`` runs the CI smoke: build a small
-sharded index with spare capacity, insert under ``shard_map``, and assert
+sharded index with spare capacity, insert through the engine, and assert
 the new rows are findable in one dispatch.
 """
 from __future__ import annotations
@@ -431,7 +431,7 @@ def insert_rows(state: InsertState, vectors: np.ndarray,
     return gids, touched
 
 
-# -- emitters: host state -> the structures the engines consume -------------
+# -- emitters: host state -> the structures the engine consumes ------------
 
 def emit_device_atlas(sh: ShardState, v_cap: int,
                       tag_fields: TagFields = ()) -> DeviceAtlas:
@@ -512,13 +512,13 @@ def emit_anchor_atlas(sh: ShardState,
 def _smoke() -> None:
     """CI insert-path smoke (both tier-1 jobs run this in-process): build a
     sharded index with spare capacity on as many shards as the session's
-    devices allow, insert a batch through the shard_map engine, and assert
+    devices allow, insert a batch through the engine, and assert
     the new rows are findable in one dispatch."""
     import jax
 
-    from repro.core.batched.engine import BatchedParams
-    from repro.core.batched.sharded import (ShardedEngine,
-                                            build_sharded_index)
+    from repro.core.batched.engine import Engine
+    from repro.core.batched.sharded import build_sharded_index
+    from repro.core.config import FnsConfig, WalkConfig
     from repro.core.types import FilterPredicate, Query
     from repro.launch.mesh import make_local_mesh
 
@@ -530,8 +530,8 @@ def _smoke() -> None:
     meta = rng.integers(0, 5, (n, 2)).astype(np.int32)
     sidx = build_sharded_index(vecs, meta, s, graph_k=8, r_max=16,
                                capacity=n + 64)
-    eng = ShardedEngine(sidx, make_local_mesh(data=s, model=1),
-                        BatchedParams(k=5, beam_width=2))
+    eng = Engine(sidx, FnsConfig(walk=WalkConfig(k=5, beam_width=2)),
+                 make_local_mesh(data=s, model=1))
     new_v = normalize(rng.standard_normal((16, d)))
     new_m = np.full((16, 2), 3, np.int32)
     gids = eng.insert_batch(new_v, new_m)

@@ -20,9 +20,10 @@ reads. This module closes the loop so a serving index can live forever:
 * **growth** (``grow_state`` / ``ensure_capacity``) re-shards past
   capacity instead of raising: every shard's slab is enlarged in place
   (shard COUNT is pinned by the mesh axis, so growth is per-shard cap),
-  and the engines' jitted programs retrace on the new shapes
+  and the engine's jitted programs retrace on the new shapes
   automatically. ``ensure_capacity`` prefers reclaiming tombstones over
-  growing;
+  growing; ``pad_state`` adds empty shards when a snapshot restores onto
+  a mesh with more devices;
 * the **deferred-repair backlog** (``drain_pending``): with
   ``maintenance.defer_repair`` the ingest hot path stops after slab
   writes + bit flips + nearest-cluster assignment, and the graph
@@ -32,8 +33,8 @@ reads. This module closes the loop so a serving index can live forever:
   of ``patch_adjacency`` are strictly earlier rows). Compaction drains a
   shard's backlog before remapping rows, so queued ranges never dangle.
 
-Everything here mutates HOST state (``InsertState``); the engines
-re-place device arrays afterwards (``delete_batch`` costs one bitmap
+Everything here mutates HOST state (``InsertState``); the engine
+re-places device arrays afterwards (``delete_batch`` costs one bitmap
 re-pack, compaction/growth a touched-shard refresh). Crash consistency
 rides the PR 7 journal: deletes and compactions append records before
 the host mutation, and replay after ``applied_seq`` re-runs them
@@ -51,7 +52,8 @@ import math
 import numpy as np
 
 from repro import faults
-from repro.core.batched.insert import (InsertState, _refresh_centroids,
+from repro.core.batched.insert import (HostAtlas, InsertState,
+                                       ShardState, _refresh_centroids,
                                        repair_range)
 from repro.core.config import MaintenanceConfig
 from repro.core.graph import relink_rows
@@ -236,6 +238,28 @@ def grow_state(state: InsertState, new_cap: int) -> None:
     state.grown += 1
 
 
+def pad_state(state: InsertState, n_shards: int) -> InsertState:
+    """Grow a restored state to ``n_shards`` by appending EMPTY slabs
+    (n_valid 0, all rows invalid, centroids cloned from shard 0 so the
+    stacked atlas keeps its K). Exact by construction: an empty shard's
+    validity bitmap fails every predicate, and balance-aware placement
+    fills the empty slabs first on subsequent inserts."""
+    s0 = state.shards[0]
+    k = s0.atlas.n_clusters
+    while len(state.shards) < n_shards:
+        atlas = HostAtlas(
+            centroids=s0.atlas.centroids.copy(),
+            assign=np.zeros(s0.cap, np.int32),
+            base_counts=np.zeros(k, np.int64),
+            base_centroids=s0.atlas.centroids.copy())
+        state.shards.append(ShardState(
+            np.zeros_like(s0.vectors),
+            np.full_like(s0.adjacency, -1),
+            np.full_like(s0.metadata, -1),
+            np.full(s0.cap, -1, np.int32), 0, atlas))
+    return state
+
+
 def ensure_capacity(state: InsertState, n_new: int,
                     mcfg: MaintenanceConfig | None = None) -> dict:
     """Make room for ``n_new`` appended rows before the slab writes run:
@@ -278,8 +302,8 @@ def _smoke() -> None:
     one-dispatch contract."""
     import jax
 
-    from repro.core.batched.sharded import (ShardedEngine,
-                                            build_sharded_index)
+    from repro.core.batched.engine import Engine
+    from repro.core.batched.sharded import build_sharded_index
     from repro.core.config import FnsConfig, GraphConfig, WalkConfig
     from repro.core.types import FilterPredicate, Query, normalize
     from repro.launch.mesh import make_local_mesh
@@ -293,7 +317,7 @@ def _smoke() -> None:
     cfg = FnsConfig(graph=GraphConfig(graph_k=8, r_max=16),
                     walk=WalkConfig(k=5, beam_width=2))
     sidx = build_sharded_index(vecs, meta, s, capacity=n + 64, config=cfg)
-    eng = ShardedEngine(sidx, make_local_mesh(data=s, model=1), cfg)
+    eng = Engine(sidx, cfg, make_local_mesh(data=s, model=1))
     new_v = normalize(rng.standard_normal((32, d)))
     new_m = np.full((32, 2), 3, np.int32)
     gids = eng.insert_batch(new_v, new_m)

@@ -6,15 +6,16 @@ knobs scattered as hard-coded literals and per-function kwargs: graph
 build constants in ``core/graph.py`` and ``serve/retrieval.py``
 (``GRAPH_BUILD_DEFAULTS``), atlas caps in ``core/device_atlas.py``
 (``MEMBER_CAP`` / ``AUTO_V_CAP_MAX``), walk budgets in
-``core/batched/engine.py`` (``BatchedParams``), kernel tile sizes in
+``core/batched/engine.py``, kernel tile sizes in
 ``kernels/ops.py`` (``MAX_CLAUSES`` / ``V_CAP`` / ``tn`` / ``qt`` /
 ``nt``), DNF caps in ``core/predicate.py``, and serving bucketing in
 ``serve/retrieval.py``. ``FnsConfig`` is now the single origin: every one
 of those modules derives its module-level constant from a default section
 instance here (a CI guard — ``tools/knob_guard.py`` — fails the build if
 a knob literal reappears elsewhere), and every build/serve/restore entry
-point accepts one ``FnsConfig`` (with deprecation shims folding the old
-kwargs in).
+point accepts one ``FnsConfig`` (``RetrievalService.build`` and
+``build_sharded_index`` keep deprecation shims folding their old kwargs
+in).
 
 The tree is deliberately flat-addressable: ``flatten()`` gives the
 ``{"walk.beam_width": 8, ...}`` dict the autotuner mutates via
@@ -79,9 +80,7 @@ class AtlasConfig:
 
 @dataclasses.dataclass(frozen=True)
 class WalkConfig:
-    """Lockstep-walk budgets — the runtime-tunable heart of the space
-    (identical fields to the historical ``BatchedParams``, which is now
-    an alias of this class)."""
+    """Lockstep-walk budgets — the runtime-tunable heart of the space."""
 
     k: int = 25
     beam_width: int = 4
@@ -282,36 +281,26 @@ def warn_once(key: str, message: str) -> None:
     warnings.warn(message, DeprecationWarning, stacklevel=3)
 
 
-def coerce_config(config, legacy: dict, *, where: str,
-                  defaults: dict | None = None) -> FnsConfig:
+def coerce_config(config, legacy: dict, *, where: str) -> FnsConfig:
     """Fold an entry point's arguments into one ``FnsConfig``.
 
-    ``config`` may be a full ``FnsConfig``, a bare ``WalkConfig`` (the
-    historical ``BatchedParams`` positional argument — deprecated, folded
-    into ``FnsConfig(walk=...)``), or None. ``legacy`` maps dotted knob
-    paths to the entry point's old kwargs (None = not passed); passing any
-    of them warns once and overrides the config. ``defaults`` are dotted
-    knobs applied silently when NO full FnsConfig was given — the entry
-    point's historical defaults where they differ from the config tree's
-    (e.g. BatchedEngine's append-path graph_k=16)."""
+    ``config`` may be a full ``FnsConfig``, a bare ``WalkConfig``
+    (deprecated, folded into ``FnsConfig(walk=...)``), or None. ``legacy``
+    maps dotted knob paths to the entry point's old kwargs (None = not
+    passed); passing any of them warns once and overrides the config."""
     if isinstance(config, FnsConfig):
         cfg = config
-        explicit = True
     elif isinstance(config, WalkConfig):
         warn_once(f"{where}:walk",
-                  f"{where}: passing bare WalkConfig/BatchedParams is "
-                  f"deprecated; pass FnsConfig(walk=...) instead")
+                  f"{where}: passing a bare WalkConfig is deprecated; "
+                  f"pass FnsConfig(walk=...) instead")
         cfg = FnsConfig(walk=config)
-        explicit = False
     elif config is None:
         cfg = FnsConfig()
-        explicit = False
     else:
         raise TypeError(
             f"{where}: config must be FnsConfig, WalkConfig, or None; "
             f"got {type(config).__name__}")
-    if not explicit and defaults:
-        cfg = cfg.with_knobs(defaults)
     used = {k: v for k, v in legacy.items() if v is not None}
     if used:
         warn_once(f"{where}:{','.join(sorted(used))}",

@@ -61,8 +61,8 @@ def auto_v_cap(vmax: int) -> int:
     ``vmax``: at least V_CAP (common small vocabularies share one width),
     else the next 32-bit word boundary, ceilinged at AUTO_V_CAP_MAX so a
     vocab-10^6 timestamp field doesn't allocate megabit presence rows —
-    the ONE sizing rule shared by atlas packing and both engines'
-    capacity-slab builds."""
+    the ONE sizing rule shared by atlas packing and the index builders'
+    capacity slabs."""
     return min(max(V_CAP, 32 * _n_words(vmax + 1)), AUTO_V_CAP_MAX)
 
 

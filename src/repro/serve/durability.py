@@ -10,15 +10,15 @@ durable with two complementary pieces:
   ``InsertState`` (slab vectors/metadata, patched adjacency, global-id
   maps, per-shard incremental atlases, insert/seq counters, scalar build
   knobs as one JSON leaf) through the existing ``checkpoint.ckpt``
-  atomic-rename + per-leaf-CRC format; ``engine_from_state`` rebuilds a
+  atomic-rename + per-leaf-CRC format; ``Engine.from_state`` rebuilds a
   working engine from it with ZERO graph/atlas rebuild — every derived
   device table (atlas CSR/presence/envelopes, validity bitmaps) is
   re-*emitted* from the slabs, never re-built. The snapshot is
   mesh-portable: an S-shard state restores onto an S-device mesh
   directly, onto a bigger mesh by padding empty slabs (exact — empty
   shards pass nothing and fill first on later inserts), and onto fewer
-  devices through ``ShardedEngine``'s reference mode (bit-identical
-  shard-at-a-time execution, tested in PR 3).
+  devices by running the shards one after the other on the default
+  device (bit-identical to the mesh program).
 
 * **Journal** — an append-only write-ahead log of ingest batches.
   ``serve.ingest`` appends the (vectors, metadata, seq) record — length-
@@ -51,12 +51,8 @@ import numpy as np
 
 from repro import faults
 from repro.checkpoint import ckpt
-from repro.core.batched.engine import BatchedEngine, BatchedParams
 from repro.core.batched.insert import (HostAtlas, InsertParams, InsertState,
                                        ShardState)
-from repro.core.batched.sharded import ShardedEngine, index_from_state
-from repro.core.config import FnsConfig, check_state_config
-from repro.launch.mesh import index_axis_size
 
 FORMAT = 2  # v2: per-shard liveness masks + lifecycle counters/backlog
 # Record kinds are distinguished by magic so the legacy insert framing is
@@ -315,74 +311,6 @@ def state_from_tree(arrays: dict) -> tuple[InsertState, dict]:
         tag_fields=tuple((int(c), int(v))
                          for c, v in meta.get("tag_fields", [])))
     return state, meta["extra"]
-
-
-# -- cross-mesh engine reconstruction ---------------------------------------
-
-def pad_state(state: InsertState, n_shards: int) -> InsertState:
-    """Grow a restored state to ``n_shards`` by appending EMPTY slabs
-    (n_valid 0, all rows invalid, centroids cloned from shard 0 so the
-    stacked atlas keeps its K). Exact by construction: an empty shard's
-    validity bitmap fails every predicate, and balance-aware placement
-    fills the empty slabs first on subsequent inserts."""
-    s0 = state.shards[0]
-    k = s0.atlas.n_clusters
-    while len(state.shards) < n_shards:
-        atlas = HostAtlas(
-            centroids=s0.atlas.centroids.copy(),
-            assign=np.zeros(s0.cap, np.int32),
-            base_counts=np.zeros(k, np.int64),
-            base_centroids=s0.atlas.centroids.copy())
-        state.shards.append(ShardState(
-            np.zeros_like(s0.vectors),
-            np.full_like(s0.adjacency, -1),
-            np.full_like(s0.metadata, -1),
-            np.full(s0.cap, -1, np.int32), 0, atlas))
-    return state
-
-
-def engine_from_state(state: InsertState, *, mesh=None, config=None,
-                      params: BatchedParams | None = None,
-                      vocab_sizes=None):
-    """Reconstruct a live engine from a restored state on whatever mesh
-    this process has — zero graph/atlas rebuild on every path:
-
-    * mesh spans exactly the snapshot's S shards -> ``ShardedEngine`` on
-      the mesh (the reshard-on-load case: host slabs -> device_put with
-      the target shardings);
-    * mesh spans MORE devices -> pad with empty slabs, then the mesh
-      program (exact, see ``pad_state``);
-    * mesh is None / spans FEWER devices: a 1-shard state becomes a
-      ``BatchedEngine``; a multi-shard state runs in ``ShardedEngine``'s
-      reference mode (bit-identical shard-at-a-time execution on the
-      default device — restoring a 4-shard snapshot on 1 device keeps the
-      4-shard search semantics, and with them the recall profile).
-
-    ``config`` (an ``FnsConfig``) is the one knob source; when given, its
-    shape-baked knobs are validated against the state (``ConfigMismatch``
-    on conflict — graph_k/v_cap/capacity are baked into the slabs and
-    cannot be changed by a restore). The legacy ``params`` kwarg remains
-    as a deprecation shim (folded by the engine constructors, which warn
-    once)."""
-    if isinstance(config, FnsConfig):
-        check_state_config(
-            config, graph_k=state.graph_k, v_cap=state.v_cap,
-            n_clusters=state.shards[0].atlas.n_clusters,
-            capacity=sum(sh.cap for sh in state.shards),
-            where="engine_from_state")
-    eff = config if config is not None else params
-    s = len(state.shards)
-    target = index_axis_size(mesh) if mesh is not None else 1
-    if mesh is not None and target >= s:
-        if target > s:
-            pad_state(state, target)
-        return ShardedEngine(index_from_state(state, vocab_sizes=vocab_sizes),
-                             mesh, config=eff)
-    if s == 1:
-        return BatchedEngine.from_state(state, config=eff,
-                                        vocab_sizes=vocab_sizes)
-    return ShardedEngine(index_from_state(state, vocab_sizes=vocab_sizes),
-                         None, config=eff)
 
 
 # -- the store: snapshots dir + journal under one root ----------------------

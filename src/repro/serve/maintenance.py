@@ -22,7 +22,7 @@ One ``step()`` does ONE category of work — the cheapest stale one — so a
 serving loop can interleave ``step()`` between query batches with a
 bounded per-call cost; ``run_until_idle()`` drains everything (capped by
 ``max_steps_per_drain``). Every step that mutated host state publishes
-through the engines' uniform ``refresh_device(touched)`` hook, keeping
+through the engine's ``refresh_device(touched)`` hook, keeping
 the device slabs current without ever touching the search path's
 one-dispatch contract.
 
@@ -47,8 +47,8 @@ from repro.core.config import MaintenanceConfig
 class MaintenanceLoop:
     """Budgeted background maintenance over one engine's host state.
 
-    ``engine`` is any capacity-slab engine (``BatchedEngine`` /
-    ``ShardedEngine``) exposing ``.state`` and ``.refresh_device``;
+    ``engine`` is an ``Engine`` over a capacity-slab index: the loop
+    mutates its ``.state`` and publishes through ``.refresh_device``;
     ``on_compact`` (optional) is called with the shard list about to be
     compacted — the serving layer uses it to journal the operation
     before it runs."""

@@ -117,9 +117,7 @@ class ServePipeline:
     def __init__(self, service, *, clock=time.monotonic):
         self.service = service
         scfg = service._cfg().serve
-        eng = service._live_engine()
-        self.queue = AdmissionQueue(scfg,
-                                    q_lanes=getattr(eng, "q_lanes", 1),
+        self.queue = AdmissionQueue(scfg, q_lanes=service.engine().q_lanes,
                                     clock=clock)
         self.depth = max(1, scfg.queue_depth)
         self.clock = clock

@@ -16,9 +16,9 @@ from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ArchConfig
 from repro.core.atlas import AnchorAtlas
-from repro.core.batched.engine import (BatchedEngine, BatchedParams,
-                                       _compile_query_dnf)
-from repro.core.batched.sharded import ShardedEngine, build_sharded_index
+from repro.core.batched.engine import Engine, _compile_query_dnf
+from repro.core.batched.insert import emit_anchor_atlas, emit_graph
+from repro.core.batched.sharded import ShardedIndex, build_sharded_index
 from repro.core.config import (AtlasConfig, FnsConfig, GraphConfig,
                                ServeConfig, coerce_config)
 from repro.core.graph import build_alpha_knn
@@ -26,7 +26,7 @@ from repro.core.predicate import FilterExpr
 from repro.core.search import FiberIndex, SearchParams, search
 from repro.core.tags import stray_labels, tag_columns
 from repro.core.types import Dataset, FilterPredicate, Query, normalize
-from repro.launch.mesh import index_axis_size, query_axis_name
+from repro.launch.mesh import index_axis_size
 from repro.models.transformer import ShardEnv, encode
 
 # singleton (and any sub-minimum) arrivals pad up to this bucket so a
@@ -51,31 +51,33 @@ _SHARED_WALK_FIELDS = ("k", "jump_budget", "n_seeds", "c_max",
                        "frontier_width", "stall_budget", "max_hops")
 
 
-def _engine_state(eng):
-    """The host InsertState behind either engine flavour (None when the
-    engine was built without append capacity)."""
-    return eng._istate if isinstance(eng, ShardedEngine) else eng._state
+def _state_index(state) -> FiberIndex:
+    """The sequential path's view of a restored one-shard state: its
+    written rows, current graph and incremental atlas, re-*emitted*."""
+    (slab,) = state.shards
+    return FiberIndex(slab.vectors[: slab.n_valid].copy(),
+                      slab.metadata[: slab.n_valid].copy(),
+                      emit_graph(slab),
+                      emit_anchor_atlas(slab, state.tag_fields),
+                      tag_fields=state.tag_fields)
 
 
 @dataclasses.dataclass
 class RetrievalService:
     index: FiberIndex | None
     params: SearchParams
-    # active mesh: when its "data" axis spans >1 device, query_batch routes
-    # to the sharded engine (corpus row-partitioned, DESIGN.md §7)
+    # active mesh: its "data" axis size is the engine's shard count (the
+    # corpus row-partitioned, DESIGN.md §7)
     mesh: object | None = None
     graph_build: dict = dataclasses.field(default_factory=dict)
-    # row capacity the batched/sharded engines reserve for ``ingest``
-    # (DESIGN.md §9); None = build-once service, ingest raises
+    # row capacity the engine reserves for ``ingest`` (DESIGN.md §9);
+    # None = build-once service, ingest raises
     capacity: int | None = None
-    # the one typed knob tree every engine this service builds consumes
-    # (DESIGN.md §11); None = derive lazily from the legacy fields above
+    # the one typed knob tree the engine consumes (DESIGN.md §11); None =
+    # derive lazily from the legacy fields above
     config: FnsConfig | None = None
     _ds: Dataset | None = dataclasses.field(default=None, repr=False)
-    _engine: BatchedEngine | None = dataclasses.field(default=None,
-                                                      repr=False)
-    _sharded: ShardedEngine | None = dataclasses.field(default=None,
-                                                       repr=False)
+    _engine: Engine | None = dataclasses.field(default=None, repr=False)
     # crash-consistency (DESIGN.md §10): attached by enable_durability /
     # recover; when set, every ingest/delete/compact is journaled before
     # it is applied
@@ -99,8 +101,7 @@ class RetrievalService:
         (sequential-path SearchParams) stays first-class: its walk-shared
         fields fold into ``config.walk`` so bench and serving measure the
         same engine — unless a full ``FnsConfig`` is given, which wins for
-        the batched engines while ``params`` keeps steering the sequential
-        path."""
+        the engine while ``params`` keeps steering the sequential path."""
         cfg = coerce_config(config,
                             {"graph.graph_k": graph_k,
                              "graph.r_max": r_max,
@@ -129,8 +130,8 @@ class RetrievalService:
 
     def _global_index(self) -> FiberIndex:
         """The single-device index (global α-kNN graph + atlas), built on
-        first use — eagerly for unmeshed services, lazily for sharded ones
-        (only ``query``/``engine`` need it there)."""
+        first use — eagerly for one-shard services, lazily for sharded
+        ones (only ``query`` needs it there)."""
         if self.index is None:
             gb, ds = self._gb(), self._ds
             graph = build_alpha_knn(ds.vectors, k=gb["graph_k"],
@@ -180,82 +181,47 @@ class RetrievalService:
                                   predicate, self.params, seed=seed)
         return ids, sims, stats
 
-    def engine(self) -> BatchedEngine:
-        """Lazily-built batched engine over the same index (device-resident
-        atlas; one jitted select+walk round per restart).
+    def engine(self) -> Engine:
+        """The service's engine, built on first use over as many shards
+        as the mesh's ``data`` axis spans: one shard wraps the global
+        index as it is, more re-partition the corpus row-wise with
+        per-shard subgraphs/atlases (each build ~S² cheaper than the
+        global one, DESIGN.md §7).
 
-        ``beam_width`` is deliberately NOT forwarded: SearchParams' default
-        (40) is tuned for the sequential beam walk, while the lockstep
-        engine pops one node per query per iteration and uses its own
-        small-beam default (4) — forwarding would multiply every query's
-        wall-clock by the widest beam in the batch. Pass an explicit
-        BatchedEngine for custom lockstep beams."""
+        ``beam_width`` is deliberately NOT forwarded from ``params``:
+        SearchParams' default (40) is tuned for the sequential beam walk,
+        while the lockstep engine pops one node per query per iteration
+        and uses its own small-beam default (4) — forwarding would
+        multiply every query's wall-clock by the widest beam in the batch.
+        Set ``walk.beam_width`` in the config for custom lockstep beams."""
         if self._engine is None:
-            self._engine = BatchedEngine(self._global_index(),
-                                         config=self._cfg(),
-                                         vocab_sizes=self._vocab_sizes())
+            cfg, s = self._cfg(), self._mesh_shards()
+            if s == 1:
+                sidx = ShardedIndex.from_index(
+                    self._global_index(), cfg,
+                    vocab_sizes=self._vocab_sizes())
+            else:
+                vectors, metadata = self._corpus()
+                sidx = build_sharded_index(vectors, metadata, s, config=cfg,
+                                           tag_fields=self._tag_fields())
+            self._engine = Engine(sidx, cfg, self.mesh)
         return self._engine
 
     def _vocab_sizes(self):
         """Per-field domains for FilterExpr Not/Range lowering: the
         dataset's declared vocabularies when the service was built from a
-        Dataset, else derived from the index metadata by the engine."""
+        Dataset, else derived from the index metadata."""
         return self._ds.vocab_sizes if self._ds is not None else None
-
-    def _batched_params(self) -> BatchedParams:
-        # the single walk-param origin (stale-duplication fix): serving's
-        # lockstep walk knobs ARE the config tree's walk section — the same
-        # object the benchmarks construct engines from
-        return self._cfg().walk
 
     def _mesh_shards(self) -> int:
         return index_axis_size(self.mesh) if self.mesh is not None else 1
-
-    def _mesh_parallel(self) -> bool:
-        """True when the mesh warrants the sharded engine: >1 corpus shard
-        on the data axis, or >1 query lane on a query axis (a data=1 2D
-        mesh still wants the shard_map program for query parallelism)."""
-        if self.mesh is None:
-            return False
-        if self._mesh_shards() > 1:
-            return True
-        cfg = self._cfg()
-        return (cfg.mesh.query_parallel and
-                query_axis_name(self.mesh, cfg.mesh.query_axes) is not None)
-
-    def _live_engine(self):
-        """The engine the batched paths route to: by mesh shape, except
-        that an engine attached by snapshot restore wins — a multi-shard
-        state recovered onto a meshless process serves through the sharded
-        engine's reference mode, not a freshly built global engine."""
-        if self._mesh_parallel():
-            return self.sharded_engine()
-        if self._sharded is not None:
-            return self._sharded
-        return self.engine()
-
-    def sharded_engine(self) -> ShardedEngine:
-        """Lazily-built sharded engine (DESIGN.md §7): the corpus is
-        re-partitioned row-wise over the mesh ``data`` axis with per-shard
-        subgraphs/atlases; the per-shard graph builds are each ~S² cheaper
-        than the global one."""
-        if self._sharded is None:
-            vectors, metadata = self._corpus()
-            sidx = build_sharded_index(vectors, metadata,
-                                       self._mesh_shards(),
-                                       config=self._cfg(),
-                                       tag_fields=self._tag_fields())
-            self._sharded = ShardedEngine(sidx, self.mesh,
-                                          config=self._cfg())
-        return self._sharded
 
     def query_batch(self, vectors: np.ndarray,
                     predicates: "list[FilterPredicate | FilterExpr]", *,
                     bucket: bool = True):
         """Batched filtered retrieval: the whole batch is ONE device
-        dispatch (fused predicate eval + restart loop + lockstep walks),
-        routed to the sharded engine when the service's mesh partitions the
-        corpus over >1 device. Predicates may be conjunctive
+        dispatch (fused predicate eval + restart loop + lockstep walks)
+        of the service's engine. Predicates may be conjunctive
         ``FilterPredicate``s or arbitrary ``FilterExpr`` trees (compiled to
         bounded DNF on pack; DESIGN.md §8).
 
@@ -302,13 +268,12 @@ class RetrievalService:
         self._batches += 1
         with TraceAnnotation("fns.form", batch=batch,
                              queries=q_real) as span:
-            eng = self._live_engine()
-            v_cap = eng.v_cap if hasattr(eng, "v_cap") else eng.datlas.v_cap
+            eng = self.engine()
             errors: list[str | None] = [None] * q_real
             checked = []
             for i, p in enumerate(predicates):
                 try:
-                    _compile_query_dnf(p, eng.vocab_sizes, v_cap,
+                    _compile_query_dnf(p, eng.vocab_sizes, eng.v_cap,
                                        eng.tag_fields)
                     checked.append(p)
                 except ValueError as e:
@@ -317,12 +282,11 @@ class RetrievalService:
             queries = [Query(vector=v, predicate=p)
                        for v, p in zip(normalize(vectors), checked)]
             if bucket:
-                lanes = getattr(eng, "q_lanes", 1)
                 target = max(MIN_BUCKET, 1 << (q_real - 1).bit_length())
                 # round the bucket UP to a multiple of the query-axis size
                 # so a 2D-mesh dispatch needs no extra lane padding and
                 # every lane walks the same block height (DESIGN.md §13)
-                target = -(-target // lanes) * lanes
+                target = -(-target // eng.q_lanes) * eng.q_lanes
                 if target > q_real:
                     # unit basis vector, NOT zeros: a zero vector has zero
                     # norm, so cosine normalization would turn it into NaNs
@@ -348,7 +312,7 @@ class RetrievalService:
                      if isinstance(v, np.ndarray) and v.ndim >= 1
                      and len(v) == q_padded else v)
                  for k, v in stats.items()}
-        st = _engine_state(eng)
+        st = eng.state
         if st is not None:
             # deferred work a result set might observe: un-repaired rows
             # plus tombstones still holding slab slots (DESIGN.md §12)
@@ -396,7 +360,7 @@ class RetrievalService:
         the recovery journal with an unappliable record)."""
         vectors = np.asarray(vectors, np.float32)
         metadata = np.atleast_2d(np.asarray(metadata, np.int32))
-        st = _engine_state(eng)
+        st = eng.state
         if vectors.ndim != 2:
             raise ValueError(
                 f"ingest vectors must be 2-D (rows, dim); got shape "
@@ -464,10 +428,11 @@ class RetrievalService:
 
     def ingest(self, vectors: np.ndarray, metadata: np.ndarray, *,
                gids: np.ndarray | None = None) -> np.ndarray:
-        """Append documents to the live serving index (DESIGN.md §9):
-        routed to the same engine ``query_batch`` uses (sharded when the
-        mesh partitions the corpus), so newly ingested rows are visible to
-        the very next batch without a rebuild. Requires the service to
+        """Append documents to the live serving index (DESIGN.md §9)
+        through the engine ``query_batch`` uses, so newly ingested rows
+        are visible to the very next batch without a rebuild. The
+        sequential index, where one was built, widens its memoized field
+        domains to the engine's. Requires the service to
         have been built with spare ``capacity``. Returns the new rows'
         global ids.
 
@@ -481,18 +446,22 @@ class RetrievalService:
                 "service was built without ingest capacity; pass "
                 "capacity=... to RetrievalService.build to reserve append "
                 "room")
-        eng = self._live_engine()
+        eng = self.engine()
         vectors, metadata = self._validate_ingest(vectors, metadata, eng)
         if gids is not None:
-            gids = self._validate_gids(gids, vectors.shape[0],
-                                       _engine_state(eng))
+            gids = self._validate_gids(gids, vectors.shape[0], eng.state)
         seq = self._next_seq
         if self._store is not None:
             self._store.journal.append(seq, vectors, metadata, gids=gids)
         out = eng.insert_batch(vectors, metadata, gids=gids)
         if self._store is not None:
-            _engine_state(eng).applied_seq = seq
+            eng.state.applied_seq = seq
             self._next_seq = seq + 1
+        if self.index is not None:
+            # Not / open-ended-Range lowering on the sequential path reads
+            # index.vocab_sizes(), which would otherwise miss codes first
+            # introduced by this ingest
+            self.index.extend_vocab(eng.vocab_sizes)
         self._sync_capacity(eng)
         return out
 
@@ -518,8 +487,8 @@ class RetrievalService:
                 "service was built without ingest capacity; deletes need "
                 "a capacity-slab service (RetrievalService.build(..., "
                 "capacity=...))")
-        eng = self._live_engine()
-        st = _engine_state(eng)
+        eng = self.engine()
+        st = eng.state
         gids = np.unique(np.asarray(gids, np.int64).ravel())
         shard_of, _rows = st.locate_gids(gids)
         missing = gids[shard_of < 0]
@@ -545,8 +514,8 @@ class RetrievalService:
         Returns the compaction accounting."""
         from repro.core.batched.lifecycle import compact_state
 
-        eng = self._live_engine()
-        st = _engine_state(eng)
+        eng = self.engine()
+        st = eng.state
         if st is None:
             raise ValueError(
                 "service has no mutable engine state; build with "
@@ -575,7 +544,7 @@ class RetrievalService:
     def _maintenance_loop(self):
         from repro.serve.maintenance import MaintenanceLoop
 
-        eng = self._live_engine()
+        eng = self.engine()
         if self._mloop is None or self._mloop.engine is not eng:
             def on_compact(shards, _eng=eng):
                 # WAL the compaction BEFORE any row moves (same ordering
@@ -583,7 +552,7 @@ class RetrievalService:
                 if self._store is not None:
                     seq = self._next_seq
                     self._store.journal.append_compact(seq)
-                    _engine_state(_eng).applied_seq = seq
+                    _eng.state.applied_seq = seq
                     self._next_seq = seq + 1
 
             self._mloop = MaintenanceLoop(eng, self._cfg().maintenance,
@@ -606,7 +575,7 @@ class RetrievalService:
                 "durability needs an ingest-capable service; pass "
                 "capacity=... to RetrievalService.build")
         self._store = DurableStore(path, keep=keep)
-        st = _engine_state(self._live_engine())
+        st = self.engine().state
         recs, _ = self._store.journal.read()
         self._next_seq = max([st.applied_seq] + [r[0] for r in recs]) + 1
         if snapshot_now:
@@ -620,7 +589,7 @@ class RetrievalService:
         if self._store is None:
             raise ValueError("no durability store attached; call "
                              "enable_durability(path) first")
-        eng = self._live_engine()
+        eng = self.engine()
         cfg = self._cfg()
         extra = {"search_params": dataclasses.asdict(self.params),
                  "graph_build": self._gb(),
@@ -632,7 +601,7 @@ class RetrievalService:
                             "knobs": cfg.flatten()},
                  "vocab_sizes": (list(eng.vocab_sizes)
                                  if eng.vocab_sizes is not None else None)}
-        return self._store.snapshot(_engine_state(eng), extra)
+        return self._store.snapshot(eng.state, extra)
 
     @classmethod
     def recover(cls, path: str, *, mesh=None,
@@ -642,7 +611,8 @@ class RetrievalService:
         """Bring a service back from its durability root: load the latest
         *readable* snapshot, reconstruct the engine for THIS process's
         mesh (zero graph/atlas rebuild; cross-mesh via reshard / empty-slab
-        padding / reference mode), replay the journal suffix
+        padding / shard-at-a-time, ``Engine.from_state``), replay the
+        journal suffix
         (``seq > applied_seq``, idempotent) through the normal insert
         path, truncate any torn tail, and serve. Corrupted journal or
         snapshot bytes raise a clean error — they are never served.
@@ -653,7 +623,7 @@ class RetrievalService:
         disagrees — those require a rebuild, not a restore). Snapshots
         from before the config layer (no recorded config) restore through
         the legacy fields unchanged."""
-        from repro.serve.durability import DurableStore, engine_from_state
+        from repro.serve.durability import DurableStore
 
         store = DurableStore(path)
         state, extra, _step = store.load_latest()
@@ -667,15 +637,19 @@ class RetrievalService:
                   capacity=extra.get("capacity"), config=cfg)
         vocab = (tuple(extra["vocab_sizes"])
                  if extra.get("vocab_sizes") else None)
-        eng = engine_from_state(state, mesh=mesh, config=cfg,
-                                params=(svc._batched_params()
-                                        if cfg is None else None),
-                                vocab_sizes=vocab)
-        if isinstance(eng, BatchedEngine):
-            svc._engine = eng
-            svc.index = eng.index  # the sequential path works post-restore
-        else:
-            svc._sharded = eng
+        if cfg is None:
+            # the legacy fields, with the knobs the state baked in
+            cfg = svc._cfg().with_knobs(
+                {"graph.graph_k": state.graph_k, "graph.alpha": state.alpha,
+                 "atlas.v_cap": state.v_cap,
+                 "atlas.n_clusters": state.shards[0].atlas.n_clusters,
+                 "serve.capacity": sum(sh.cap for sh in state.shards)})
+        eng = svc._engine = Engine.from_state(state, cfg, mesh,
+                                              vocab_sizes=vocab)
+        if len(state.shards) == 1:
+            # the sequential path works post-restore
+            svc.index = _state_index(state)
+            svc.index.extend_vocab(eng.vocab_sizes)
         svc._store = store
         recs, _ = store.journal.read()
         last = max([state.applied_seq] + [r[0] for r in recs])
@@ -715,9 +689,9 @@ class RetrievalService:
         """Ingest/staleness accounting: how much of the serving corpus is
         dynamic, how much append room is left, how often shards
         re-clustered — plus how many ingested rows the lazily-built
-        sequential index (``query``) has NOT seen, since only the batched
-        engines absorb inserts."""
-        eng = self._sharded if self._sharded is not None else self._engine
+        sequential index (``query``) has NOT seen, since only the engine
+        absorbs inserts."""
+        eng = self._engine
         stats = eng.insert_stats if eng is not None else None
         if stats is None:
             n = self._corpus()[0].shape[0]

@@ -9,10 +9,25 @@ import numpy as np
 import pytest
 
 from repro.core import AnchorAtlas, FiberIndex, build_alpha_knn
+from repro.core.batched.engine import Engine
+from repro.core.batched.sharded import ShardedIndex
+from repro.core.config import FnsConfig, WalkConfig
 from repro.data.ground_truth import attach_ground_truth
 from repro.data.synth import SynthSpec, make_dataset, make_queries
 
 SELECTIVITIES = (0.5, 0.1, 0.02)
+
+
+def walk_config(**knobs) -> FnsConfig:
+    """The default config with these walk knobs."""
+    return FnsConfig(walk=WalkConfig(**knobs))
+
+
+def engine_over(index: FiberIndex, config: FnsConfig | None = None, *,
+                vocab_sizes=None) -> Engine:
+    """An engine over a built single-device index, as one shard."""
+    return Engine(ShardedIndex.from_index(index, config,
+                                          vocab_sizes=vocab_sizes), config)
 
 try:  # property tests draw the same examples on every run and worker
     from hypothesis import settings as _hypothesis_settings

@@ -2,7 +2,7 @@
 
 * FnsConfig flat addressing (flatten / with_knobs / from_flat) and the
   stable fingerprint round-trip;
-* deprecation shims: legacy knob kwargs and bare BatchedParams keep
+* deprecation shims: legacy knob kwargs and a bare WalkConfig keep
   working, warn exactly once, and land in the config tree;
 * Pallas tile knobs are validated against shape constraints at trace
   time with errors naming the KernelConfig field;
@@ -111,30 +111,31 @@ def test_coerce_config_shims(monkeypatch):
         cfg = coerce_config(WalkConfig(k=7), {}, where="shim-test2")
         assert cfg.walk.k == 7
         assert len(w) == 1 and issubclass(w[0].category, DeprecationWarning)
-    # a full FnsConfig passes through silently and wins over defaults
+    # a full FnsConfig passes through silently
     with warnings.catch_warnings(record=True) as w:
         warnings.simplefilter("always")
         full = FnsConfig().with_knobs({"graph.graph_k": 20})
-        assert coerce_config(full, {}, where="shim-test3",
-                             defaults={"graph.graph_k": 16}) is full
+        assert coerce_config(full, {}, where="shim-test3") is full
         assert len(w) == 0
     with pytest.raises(TypeError):
         coerce_config("nope", {}, where="shim-test4")
 
 
-def test_legacy_engine_kwargs_fold_into_config(ds):
-    from repro.core.atlas import AnchorAtlas
-    from repro.core.batched.engine import BatchedEngine, BatchedParams
-    from repro.core.graph import build_alpha_knn
-    from repro.core.search import FiberIndex
-
-    graph = build_alpha_knn(ds.vectors, k=8, r_max=24)
-    atlas = AnchorAtlas.build(ds)
-    index = FiberIndex(ds.vectors, ds.metadata, graph, atlas)
-    eng = BatchedEngine(index, BatchedParams(k=5, beam_width=2),
-                        graph_k=8, capacity=320)
-    assert eng.cfg.walk.k == 5 and eng.cfg.walk.beam_width == 2
-    assert eng.cfg.graph.graph_k == 8
+def test_legacy_engine_kwargs_fold_into_config(ds, monkeypatch):
+    """The loose build kwargs ``RetrievalService.build`` keeps warn and
+    fold into the one config its engine is built from."""
+    monkeypatch.setattr(config_mod, "_WARNED", set())
+    with warnings.catch_warnings(record=True) as w:
+        warnings.simplefilter("always")
+        svc = RetrievalService.build(ds, graph_k=8, r_max=24, capacity=320,
+                                     params=SearchParams(k=5))
+        assert len(w) == 1 and issubclass(w[0].category, DeprecationWarning)
+    eng = svc.engine()
+    assert eng.cfg is svc.config
+    assert eng.cfg.walk.k == 5
+    # the sequential beam is not the lockstep one
+    assert eng.cfg.walk.beam_width == WalkConfig().beam_width
+    assert (eng.cfg.graph.graph_k, eng.cfg.graph.r_max) == (8, 24)
     assert eng.cfg.serve.capacity == 320
     assert eng.p is eng.cfg.walk  # one origin, no duplicated params
 
@@ -217,7 +218,6 @@ def test_pre_config_snapshot_still_restores(ds, tmp_path):
     import dataclasses
 
     from repro.serve.durability import DurableStore
-    from repro.serve.retrieval import _engine_state
 
     cfg = _service_config()
     svc = _build(ds, cfg)
@@ -228,7 +228,7 @@ def test_pre_config_snapshot_still_restores(ds, tmp_path):
                              "n_clusters": None},
              "capacity": svc.capacity,
              "vocab_sizes": list(ds.vocab_sizes)}  # deliberately no "config"
-    store.snapshot(_engine_state(svc._live_engine()), extra)
+    store.snapshot(svc.engine().state, extra)
 
     svc2 = RetrievalService.recover(str(tmp_path))
     assert svc2.staleness()["corpus_rows"] == 220
@@ -241,19 +241,19 @@ def test_pre_config_snapshot_still_restores(ds, tmp_path):
 
 
 def test_engine_from_state_validates_explicit_config(ds, tmp_path):
-    from repro.serve.durability import DurableStore, engine_from_state
-    from repro.serve.retrieval import _engine_state
+    from repro.core.batched.engine import Engine
+    from repro.serve.durability import DurableStore
 
     svc = _build(ds, _service_config())
     svc.enable_durability(str(tmp_path))
     state, extra, _ = DurableStore(str(tmp_path)).load_latest()
     with pytest.raises(ConfigMismatch, match="serve.capacity"):
-        engine_from_state(state,
-                          config=FnsConfig().with_knobs(
-                              {"serve.capacity": 999}))
-    # legacy params path: no config given, no mismatch possible
-    eng = engine_from_state(state, params=WalkConfig(k=5))
+        Engine.from_state(state, FnsConfig().with_knobs(
+            {"serve.capacity": 999}))
+    # no config given: the state's baked knobs, no mismatch possible
+    eng = Engine.from_state(state)
     assert eng.cfg.graph.graph_k == state.graph_k
+    assert eng.cfg.atlas.v_cap == state.v_cap
 
 
 # -- CI lint guard -----------------------------------------------------------

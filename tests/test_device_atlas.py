@@ -6,13 +6,12 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core.atlas import AnchorAtlas
-from repro.core.batched.engine import BatchedEngine, BatchedParams
 from repro.core.device_atlas import pack_predicates
 from repro.core.search import SearchParams, run_queries
 from repro.core.types import FilterPredicate, Query, normalize
 from repro.data.ground_truth import recall_at_k
 
-from conftest import SELECTIVITIES
+from conftest import SELECTIVITIES, engine_over, walk_config
 
 
 def _host_round(atlas, q, processed, vectors):
@@ -95,7 +94,7 @@ def test_recall_parity_across_selectivities(sel_sweep):
     _, index, queries = sel_sweep
     ids_seq, _ = run_queries(index, queries,
                              SearchParams(k=10, walk="guided", beam_width=2))
-    eng = BatchedEngine(index, BatchedParams(k=10, beam_width=4))
+    eng = engine_over(index, walk_config(k=10, beam_width=4))
     ids_b, _ = eng.search(queries)
     for v, target in enumerate(SELECTIVITIES):
         idx = [i for i, q in enumerate(queries)

@@ -1,7 +1,7 @@
 """Disjunctive filtered search end-to-end (ISSUE 4 acceptance): Or-of-two-
 fields expressions must flow through DNF clause tables and the in-kernel
 disjunct union with pass bitmaps bit-identical to the numpy expression-tree
-oracle, on the fused single-dispatch engine AND the 4-shard ShardedEngine,
+oracle, on the fused single-dispatch engine over one shard AND over 4,
 preserving one dispatch + one host sync per batch; serving must reject
 mismatched batches and keep its bucket pads inert under disjunctions."""
 import subprocess
@@ -16,12 +16,15 @@ import jax.numpy as jnp
 
 from repro.core import AnchorAtlas, FiberIndex, build_alpha_knn
 from repro.core.batched.bitmap import pack_bits
-from repro.core.batched.engine import BatchedEngine, BatchedParams
+from repro.core.batched.engine import Engine
+from repro.core.config import FnsConfig, WalkConfig
 from repro.core.predicate import FilterExpr, In, Not, Or
 from repro.core.types import FilterPredicate, Query
 from repro.data.ground_truth import attach_ground_truth, recall_at_k
 from repro.data.synth import (add_or_pair_fields, make_or_queries,
                               make_selectivity_dataset, or_pair_predicate)
+
+from conftest import engine_over, walk_config
 
 MULTI = len(jax.devices()) >= 4
 
@@ -49,8 +52,8 @@ def or_sweep():
 @pytest.fixture(scope="module")
 def or_engine(or_sweep):
     ds, index, _ = or_sweep
-    return BatchedEngine(index, BatchedParams(k=10, beam_width=4),
-                         vocab_sizes=ds.vocab_sizes)
+    return engine_over(index, walk_config(k=10, beam_width=4),
+                       vocab_sizes=ds.vocab_sizes)
 
 
 def test_engineered_or_selectivities(or_sweep):
@@ -69,8 +72,8 @@ def test_pass_bitmaps_match_tree_oracle_bitexact(or_sweep, or_engine):
     ds, _, queries = or_sweep
     _, fields, allowed, bounds = or_engine._pack_queries(queries)
     assert fields.ndim == 3 and fields.shape[1] == 2  # D buckets to 2
-    got = np.asarray(or_engine._passes(or_engine.metadata, fields, allowed,
-                                       bounds))
+    got = np.asarray(or_engine._passes(or_engine.shards[0].metadata,
+                                       fields, allowed, bounds))
     want = np.asarray(pack_bits(jnp.asarray(np.stack(
         [q.predicate.mask(ds.metadata, ds.vocab_sizes) for q in queries]))))
     np.testing.assert_array_equal(got, want)
@@ -167,8 +170,9 @@ SHARDED_SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import sys; sys.path.insert(0, "src")
     import numpy as np, jax
-    from repro.core.batched.engine import BatchedParams
-    from repro.core.batched.sharded import ShardedEngine, build_sharded_index
+    from repro.core.batched.engine import Engine
+    from repro.core.batched.sharded import build_sharded_index
+    from repro.core.config import FnsConfig, WalkConfig
     from repro.data.synth import (add_or_pair_fields, make_or_queries,
                                   make_selectivity_dataset)
     from repro.launch.mesh import make_local_mesh
@@ -182,7 +186,7 @@ SHARDED_SCRIPT = textwrap.dedent("""
     sidx = build_sharded_index(ds.vectors, ds.metadata, 4, graph_k=8,
                                r_max=24)
     mesh = make_local_mesh(data=4, model=1)
-    eng = ShardedEngine(sidx, mesh, BatchedParams(k=10, beam_width=4))
+    eng = Engine(sidx, FnsConfig(walk=WalkConfig(k=10, beam_width=4)), mesh)
     ids_m, st_m = eng.search(queries)
     assert eng.dispatches == 1, eng.dispatches
     ids_r, st_r = eng.search_reference(queries)
@@ -213,14 +217,14 @@ def test_sharded_disjunctive_bit_identity_subprocess():
 def sharded_or_setup(or_sweep):
     if not MULTI:
         pytest.skip("needs >= 4 devices (multi-device CI job)")
-    from repro.core.batched.sharded import ShardedEngine, build_sharded_index
+    from repro.core.batched.sharded import build_sharded_index
     from repro.launch.mesh import make_local_mesh
 
     ds, index, queries = or_sweep
     sidx = build_sharded_index(ds.vectors, ds.metadata, 4, graph_k=16,
                                r_max=48)
     mesh = make_local_mesh(data=4, model=1)
-    eng = ShardedEngine(sidx, mesh, BatchedParams(k=10, beam_width=4))
+    eng = Engine(sidx, FnsConfig(walk=WalkConfig(k=10, beam_width=4)), mesh)
     return ds, index, queries, eng
 
 
@@ -420,8 +424,8 @@ def test_disjunct_quota_end_to_end_recall():
     vecs, meta, atlas, rare_ids = _starved_or_setup()
     graph = build_alpha_knn(vecs, k=8, r_max=24)
     index = FiberIndex(vecs, meta, graph, atlas)
-    eng = BatchedEngine(index, BatchedParams(k=10, beam_width=4),
-                        vocab_sizes=(2, 2))
+    eng = engine_over(index, walk_config(k=10, beam_width=4),
+                      vocab_sizes=(2, 2))
     pred = Or(In(0, [1]), In(1, [1]))
     q = np.eye(vecs.shape[1], dtype=np.float32)[0]
     # precondition: all rare members belong in the exact filtered top-10

@@ -120,7 +120,7 @@ def test_snapshot_restore_roundtrip_zero_rebuild(ds, labeled, tmp_path,
                         trap("AnchorAtlas.build"))
 
     svc2 = RetrievalService.recover(str(tmp_path))
-    eng2 = svc2._live_engine()
+    eng2 = svc2.engine()
     d0 = eng2.dispatches
     ids1 = _query(svc2, labeled)
     assert eng2.dispatches - d0 == 1  # one-dispatch contract post-restore
@@ -171,16 +171,19 @@ def test_journal_replay_after_restore(ds, labeled, tmp_path):
 
 
 def test_recover_multi_shard_without_mesh(ds, labeled, tmp_path):
-    """A multi-shard snapshot on a 1-device process serves through the
-    ShardedEngine reference mode: same per-shard programs, same merge,
-    zero rebuild — search results keep the sharded semantics exactly."""
-    from repro.core.batched.engine import BatchedParams
-    from repro.core.batched.sharded import ShardedEngine, build_sharded_index
-    from repro.serve.durability import DurableStore, engine_from_state
+    """A multi-shard snapshot on a 1-device process serves its shards one
+    after the other: same per-shard programs, same merge, zero rebuild —
+    search results keep the sharded semantics exactly."""
+    from repro.core.batched.engine import Engine
+    from repro.core.batched.sharded import build_sharded_index
+    from repro.core.config import FnsConfig, WalkConfig
+    from repro.serve.durability import DurableStore
 
     sidx = build_sharded_index(ds.vectors[:BASE_N], ds.metadata[:BASE_N], 2,
                                capacity=ds.n, **GRAPH)
-    eng = ShardedEngine(sidx, None, BatchedParams(k=10))
+    cfg = FnsConfig(walk=WalkConfig(k=10)).with_knobs(
+        {"graph.graph_k": GRAPH["graph_k"]})
+    eng = Engine(sidx, cfg)
     eng.insert_batch(ds.vectors[BASE_N:BASE_N + CHUNK],
                      ds.metadata[BASE_N:BASE_N + CHUNK])
     qs = [q for _, q in labeled]
@@ -189,9 +192,8 @@ def test_recover_multi_shard_without_mesh(ds, labeled, tmp_path):
     store = DurableStore(str(tmp_path))
     store.snapshot(sidx.insert_state)
     state, extra, _ = store.load_latest()
-    eng2 = engine_from_state(state, mesh=None, params=BatchedParams(k=10),
-                             vocab_sizes=tuple(ds.vocab_sizes))
-    assert isinstance(eng2, ShardedEngine) and eng2.mesh is None
+    eng2 = Engine.from_state(state, cfg, vocab_sizes=tuple(ds.vocab_sizes))
+    assert eng2.mesh is None and eng2.n_shards == 2
     ids1, _ = eng2.search(qs)
     for a, b in zip(ids0, ids1):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
@@ -219,8 +221,8 @@ def test_recover_cross_mesh(ds, labeled, tmp_path):
     svc_m = RetrievalService.recover(str(tmp_path / "m4"), mesh=mesh)
     for a, b in zip(ids0, _query(svc_m, labeled)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    # meshless recovery of the same 4-shard snapshot: reference mode,
-    # still bit-identical (PR 3's mesh==reference parity, applied here)
+    # meshless recovery of the same 4-shard snapshot: its shards one after
+    # the other, still bit-identical (the mesh==reference parity, applied here)
     svc_r = RetrievalService.recover(str(tmp_path / "m4"))
     for a, b in zip(ids0, _query(svc_r, labeled)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

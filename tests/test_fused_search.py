@@ -7,9 +7,9 @@ import numpy as np
 import jax.numpy as jnp
 
 from repro.core.batched.bitmap import n_words, pack_bits
-from repro.core.batched.engine import (BatchedEngine, BatchedParams, INF,
-                                       walk_batch)
-from conftest import SELECTIVITIES
+from repro.core.batched.engine import INF, walk_batch
+from repro.core.config import WalkConfig
+from conftest import SELECTIVITIES, engine_over, walk_config
 
 
 def test_fused_matches_hostloop_exactly(sel_sweep):
@@ -17,7 +17,7 @@ def test_fused_matches_hostloop_exactly(sel_sweep):
     same ids in the same order, same per-query walks and hops, the same
     rounds and lockstep iterations, at every selectivity in the sweep."""
     _, index, queries = sel_sweep
-    eng = BatchedEngine(index, BatchedParams(k=10, beam_width=4))
+    eng = engine_over(index, walk_config(k=10, beam_width=4))
     ids_f, st_f = eng.search(queries)
     ids_h, st_h = eng.search_hostloop(queries)
     assert len(ids_f) == len(queries)
@@ -43,7 +43,7 @@ def test_search_is_single_dispatch(sel_sweep):
     """One batch = one compiled-callable invocation: the fused program is
     called exactly once and the per-round path not at all."""
     _, index, queries = sel_sweep
-    eng = BatchedEngine(index, BatchedParams(k=10, beam_width=4))
+    eng = engine_over(index, walk_config(k=10, beam_width=4))
     calls = {"search": 0, "round": 0, "passes": 0}
     orig_search, orig_round, orig_passes = (eng._search, eng._round,
                                             eng._passes)
@@ -84,7 +84,7 @@ def test_walk_state_is_bitmap_packed(small_index, small_queries):
     out = walk_batch(jnp.asarray(small_index.vectors),
                      jnp.asarray(small_index.graph.neighbors),
                      pass_bm, q_vecs, jnp.asarray(seeds),
-                     BatchedParams(k=5, beam_width=4))
+                     WalkConfig(k=5, beam_width=4))
     assert out["visited_bm"].shape == pass_bm.shape
     assert out["visited_bm"].dtype == jnp.uint32
     res_v = np.asarray(out["res_v"])
@@ -97,7 +97,7 @@ def test_walk_state_is_bitmap_packed(small_index, small_queries):
 
 def test_fused_results_pass_filters(sel_sweep):
     _, index, queries = sel_sweep
-    eng = BatchedEngine(index, BatchedParams(k=10, beam_width=4))
+    eng = engine_over(index, walk_config(k=10, beam_width=4))
     ids, _ = eng.search(queries)
     for q, row in zip(queries, ids):
         row = np.asarray(row)

@@ -26,14 +26,16 @@ except ImportError:  # container without hypothesis: deterministic shim
     from _hypothesis_fallback import given, settings, strategies as st
 
 from repro.core import AnchorAtlas, FiberIndex, build_alpha_knn
-from repro.core.batched.engine import BatchedEngine, BatchedParams
+from repro.core.config import FnsConfig, WalkConfig
 from repro.core.predicate import Range
 from repro.core.types import Dataset, FilterPredicate, Query
+
+from conftest import engine_over
 
 MULTI = len(jax.devices()) >= 4
 SELS = (0.5, 0.1, 0.02)
 GRAPH = dict(graph_k=16, r_max=48)
-PARAMS = BatchedParams(k=10, beam_width=4)
+CONFIG = FnsConfig(walk=WalkConfig(k=10, beam_width=4))
 
 
 # -- harness -----------------------------------------------------------------
@@ -97,8 +99,9 @@ def _build_single_engine(vectors, metadata, vocab, capacity=None):
                             r_max=GRAPH["r_max"])
     atlas = AnchorAtlas.build(ds, seed=0)
     index = FiberIndex(ds.vectors, ds.metadata, graph, atlas)
-    return BatchedEngine(index, PARAMS, vocab_sizes=vocab,
-                         capacity=capacity, graph_k=GRAPH["graph_k"])
+    cfg = CONFIG.with_knobs({"serve.capacity": capacity,
+                             "graph.graph_k": GRAPH["graph_k"]})
+    return engine_over(index, cfg, vocab_sizes=vocab)
 
 
 def _run_interleaving(make_engine, rebuild_engine, ds, chunks,
@@ -177,7 +180,8 @@ def test_sharded_rebuild_parity(full_ds):
     ``build_sharded_index`` of the grown corpus."""
     if not MULTI:
         pytest.skip("needs >= 4 devices (multi-device CI job)")
-    from repro.core.batched.sharded import ShardedEngine, build_sharded_index
+    from repro.core.batched.engine import Engine
+    from repro.core.batched.sharded import build_sharded_index
     from repro.launch.mesh import make_local_mesh
 
     mesh = make_local_mesh(data=4, model=1)
@@ -185,7 +189,7 @@ def test_sharded_rebuild_parity(full_ds):
     def make(vectors, metadata, vocab, capacity=None):
         sidx = build_sharded_index(vectors, metadata, 4, capacity=capacity,
                                    **GRAPH)
-        return ShardedEngine(sidx, mesh, PARAMS)
+        return Engine(sidx, CONFIG, mesh)
 
     _run_interleaving(make, lambda v, m, vo: make(v, m, vo), full_ds,
                       [125, 125])
@@ -196,8 +200,9 @@ SHARDED_SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import sys; sys.path.insert(0, "src"); sys.path.insert(0, "tests")
     import numpy as np
-    from test_insert import GRAPH, PARAMS, _full_dataset, _run_interleaving
-    from repro.core.batched.sharded import ShardedEngine, build_sharded_index
+    from test_insert import GRAPH, CONFIG, _full_dataset, _run_interleaving
+    from repro.core.batched.engine import Engine
+    from repro.core.batched.sharded import build_sharded_index
     from repro.launch.mesh import make_local_mesh
 
     ds = _full_dataset()
@@ -206,7 +211,7 @@ SHARDED_SCRIPT = textwrap.dedent("""
     def make(vectors, metadata, vocab, capacity=None):
         sidx = build_sharded_index(vectors, metadata, 4, capacity=capacity,
                                    **GRAPH)
-        return ShardedEngine(sidx, mesh, PARAMS)
+        return Engine(sidx, CONFIG, mesh)
 
     eng = _run_interleaving(make, lambda v, m, vo: make(v, m, vo), ds,
                             [250])
@@ -244,7 +249,8 @@ def test_property_random_interleavings(chunk_sizes):
     findable by its own vector under a predicate it satisfies, (c) the
     row-validity bitmaps admit exactly the written slab rows."""
     from repro.core.batched.bitmap import unpack_bits
-    from repro.core.batched.sharded import ShardedEngine, build_sharded_index
+    from repro.core.batched.engine import Engine
+    from repro.core.batched.sharded import build_sharded_index
     from repro.launch.mesh import make_local_mesh
 
     ds = _tiny_ds()
@@ -253,13 +259,13 @@ def test_property_random_interleavings(chunk_sizes):
     base_n = ds.n - total
     n_shards = min(4, 1 << (len(jax.devices()).bit_length() - 1))
     mesh = make_local_mesh(data=n_shards, model=1)
-    p = BatchedParams(k=5, beam_width=2)
+    cfg = FnsConfig(walk=WalkConfig(k=5, beam_width=2))
 
     def make(n_rows, capacity=None):
         sidx = build_sharded_index(ds.vectors[:n_rows], ds.metadata[:n_rows],
                                    n_shards, graph_k=8, r_max=16,
                                    capacity=capacity)
-        return ShardedEngine(sidx, mesh, p)
+        return Engine(sidx, cfg, mesh)
 
     eng = make(base_n, capacity=ds.n)
     written = base_n
@@ -268,9 +274,12 @@ def test_property_random_interleavings(chunk_sizes):
                                 ds.metadata[written:written + b])
         written += b
         # (c) bitmap == written rows, exactly, on every shard
-        got = np.asarray(unpack_bits(eng.valid_bm,
-                                     eng._istate.shards[0].cap))
-        want = np.stack([sl.valid for sl in eng._istate.shards])
+        bms = ([eng.stacked.valid_bm] if eng.stacked is not None
+               else [sh.valid_bm[None] for sh in eng.shards])
+        got = np.concatenate([np.asarray(unpack_bits(bm,
+                                                     eng.state.shards[0].cap))
+                              for bm in bms])
+        want = np.stack([sl.valid for sl in eng.state.shards])
         np.testing.assert_array_equal(got, want)
         assert int(want.sum()) == written
         # (b) each fresh insert findable by its own vector + a predicate
@@ -316,7 +325,7 @@ def test_unconstrained_search_never_returns_unwritten(full_ds):
     ids, _ = eng.search(queries)
     for row in ids:
         row = np.asarray(row)
-        assert row.size == PARAMS.k
+        assert row.size == CONFIG.walk.k
         assert (row < base_n).all(), "unwritten capacity row surfaced"
     eng.insert_batch(ds.vectors[base_n:base_n + 50],
                      ds.metadata[base_n:base_n + 50])
@@ -385,6 +394,7 @@ def test_recluster_trigger_and_drift():
     eng = _build_single_engine(ds.vectors[:200], ds.metadata[:200],
                                tuple(ds.vocab_sizes), capacity=300)
     assert eng.insert_stats["reclusters"] == 0
+    k0 = eng.shards[0].datlas.n_clusters
     rng = np.random.default_rng(5)
     from repro.core.types import normalize
     spot = ds.vectors[3]
@@ -394,7 +404,7 @@ def test_recluster_trigger_and_drift():
     st = eng.insert_stats
     assert st["reclusters"] >= 1
     assert st["inserted_rows"] == 100
-    assert eng.datlas.n_clusters == eng.index.atlas.n_clusters  # K fixed
+    assert eng.shards[0].datlas.n_clusters == k0  # K fixed
     q = Query(vector=hot_v[0],
               predicate=FilterPredicate.make({0: [int(hot_m[0, 0])]}))
     ids, _ = eng.search([q])
@@ -432,14 +442,15 @@ def test_insert_capacity_and_vocab_guards():
         fixed.insert_batch(ds.vectors[250:], ds.metadata[250:])
     # a build-once sharded index must refuse too, not silently absorb
     # rows into its ceil(n/S) padding slack
-    from repro.core.batched.sharded import ShardedEngine, build_sharded_index
+    from repro.core.batched.engine import Engine
+    from repro.core.batched.sharded import build_sharded_index
     from repro.launch.mesh import make_local_mesh
 
     sidx = build_sharded_index(ds.vectors[:250], ds.metadata[:250], 1,
                                graph_k=8, r_max=16)
     assert sidx.insert_state is None
-    seng = ShardedEngine(sidx, make_local_mesh(data=1, model=1),
-                         BatchedParams(k=5, beam_width=2))
+    seng = Engine(sidx, FnsConfig(walk=WalkConfig(k=5, beam_width=2)),
+                  make_local_mesh(data=1, model=1))
     with pytest.raises(ValueError, match="capacity"):
         seng.insert_batch(ds.vectors[250:], ds.metadata[250:])
 
@@ -482,14 +493,16 @@ def test_serve_ingest_and_staleness():
 # -- satellite (ISSUE 6): ingest must widen the memoized domains -------------
 
 def test_not_sees_brand_new_code_after_insert():
-    """``FiberIndex.vocab_sizes()`` is memoized at build time; an insert
+    """``FiberIndex.vocab_sizes()`` is memoized at build time; an ingest
     that introduces a brand-new code must extend both the engine's and the
-    index's per-field domains, or ``Not`` / open-ended ``Range`` queries
-    keep lowering against the stale domain and silently exclude every
-    newly inserted row."""
+    service index's per-field domains, or ``Not`` / open-ended ``Range``
+    queries keep lowering against the stale domain and silently exclude
+    every newly inserted row."""
     from repro.core.graph import build_alpha_knn
     from repro.core.predicate import In, Not, Range
+    from repro.core.search import SearchParams
     from repro.core.types import normalize
+    from repro.serve.retrieval import RetrievalService
 
     ds = _tiny_ds(n=260)
     base_n = 200
@@ -500,8 +513,11 @@ def test_not_sees_brand_new_code_after_insert():
     atlas = AnchorAtlas.build(d0, seed=0)
     index = FiberIndex(d0.vectors, d0.metadata, graph, atlas)
     # engine derives (and the index memoizes) domains from the base rows
-    eng = BatchedEngine(index, PARAMS, capacity=260,
-                        graph_k=GRAPH["graph_k"])
+    cfg = CONFIG.with_knobs({"serve.capacity": 260,
+                             "graph.graph_k": GRAPH["graph_k"]})
+    svc = RetrievalService(index, SearchParams(k=10), capacity=260,
+                           config=cfg)
+    eng = svc.engine()
     new_code = int(ds.metadata[:base_n, 0].max()) + 1
     assert eng.vocab_sizes[0] == new_code  # stale domain excludes it
     rng = np.random.default_rng(9)
@@ -510,7 +526,7 @@ def test_not_sees_brand_new_code_after_insert():
                       ).astype(np.float32)
     new_m = np.zeros((n_new, ds.metadata.shape[1]), np.int32)
     new_m[:, 0] = new_code
-    gids = eng.insert_batch(new_v, new_m)
+    gids = svc.ingest(new_v, new_m)
     assert eng.vocab_sizes[0] == new_code + 1
     assert index.vocab_sizes()[0] == new_code + 1
     new_ids = set(int(g) for g in gids)

@@ -12,9 +12,10 @@ import jax.numpy as jnp
 
 from repro.core.batched.bitmap import unpack_bits
 from repro.core.batched.engine import (LANE_FLOOR, TERM_MAXHOP,
-                                       BatchedEngine, stage_widths,
-                                       walk_batch)
+                                       stage_widths, walk_batch)
 from repro.core.config import FnsConfig
+
+from conftest import engine_over
 
 Q = 64
 EXACT = ("res_i", "term", "hops", "p1_hops", "visited_bm")
@@ -37,21 +38,22 @@ def lanes(sel_sweep):
     from the clusters the first did not use."""
     _, index, queries = sel_sweep
     cfg = FnsConfig().with_knobs({"walk.k": 10, "walk.beam_width": 4})
-    eng = BatchedEngine(index, cfg)
+    eng = engine_over(index, cfg)
+    ix = eng.shards[0]
     qs = (queries * 2)[:Q]
     q_vecs, fields, allowed, bounds = eng._pack_queries(qs)
     assert bounds is None
-    pass_bm = eng._passes(eng.metadata, fields, allowed, bounds)
-    passes = unpack_bits(pass_bm, eng.vectors.shape[0])
+    pass_bm = eng._passes(ix.metadata, fields, allowed, bounds)
+    passes = unpack_bits(pass_bm, ix.vectors.shape[0])
     p = eng.p
 
     def select(gate):
-        return eng.datlas.select_anchors_batch(
-            q_vecs, (fields, allowed), gate, eng.vectors, passes,
+        return ix.datlas.select_anchors_batch(
+            q_vecs, (fields, allowed), gate, ix.vectors, passes,
             n_seeds=p.n_seeds, c_max=p.c_max,
             disjunct_quota=p.disjunct_quota)
 
-    seeds, used = select(jnp.zeros((Q, eng.datlas.n_clusters), bool))
+    seeds, used = select(jnp.zeros((Q, ix.datlas.n_clusters), bool))
     restart, _ = select(used)
     return eng, q_vecs, pass_bm, seeds, restart
 
@@ -62,7 +64,8 @@ def _program(p):
 
 
 def _walk(eng, p, q_vecs, pass_bm, seeds, init):
-    return jax.device_get(_program(p)(eng.vectors, eng.adjacency, pass_bm,
+    ix = eng.shards[0]
+    return jax.device_get(_program(p)(ix.vectors, ix.adjacency, pass_bm,
                                       q_vecs, seeds, init_results=init))
 
 

@@ -29,18 +29,19 @@ except ImportError:  # container without hypothesis: deterministic shim
     from _hypothesis_fallback import example, given, settings, strategies as st
 
 from test_durability import _corpus, _labeled_queries, _query
-from test_insert import (GRAPH, PARAMS, _build_single_engine, _full_dataset,
+from test_insert import (CONFIG, GRAPH, _build_single_engine, _full_dataset,
                          _recall, _tiny_ds)
 
 from repro import faults
 from repro.core import AnchorAtlas, FiberIndex, build_alpha_knn
 from repro.core.batched import lifecycle
-from repro.core.batched.engine import BatchedEngine, BatchedParams
 from repro.core.config import FnsConfig
 from repro.core.search import SearchParams
 from repro.core.types import Dataset, FilterPredicate, Query
 from repro.serve.maintenance import MaintenanceLoop
-from repro.serve.retrieval import RetrievalService, _engine_state
+from repro.serve.retrieval import RetrievalService
+
+from conftest import engine_over, walk_config
 
 MULTI = len(jax.devices()) >= 4
 SELS = (0.5, 0.1, 0.02)
@@ -184,7 +185,8 @@ def test_lifecycle_rebuild_parity_sharded(full_ds):
     """The same schedule through the 4-shard mesh engine."""
     if not MULTI:
         pytest.skip("needs >= 4 devices (multi-device CI job)")
-    from repro.core.batched.sharded import ShardedEngine, build_sharded_index
+    from repro.core.batched.engine import Engine
+    from repro.core.batched.sharded import build_sharded_index
     from repro.launch.mesh import make_local_mesh
 
     mesh = make_local_mesh(data=4, model=1)
@@ -192,7 +194,7 @@ def test_lifecycle_rebuild_parity_sharded(full_ds):
     def make(vectors, metadata, vocab, capacity=None):
         sidx = build_sharded_index(vectors, metadata, 4, capacity=capacity,
                                    **GRAPH)
-        return ShardedEngine(sidx, mesh, PARAMS)
+        return Engine(sidx, CONFIG, mesh)
 
     _run_lifecycle_schedule(make, full_ds)
 
@@ -202,9 +204,10 @@ LIFECYCLE_SHARDED_SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import sys; sys.path.insert(0, "src"); sys.path.insert(0, "tests")
     import numpy as np
-    from test_insert import GRAPH, PARAMS, _full_dataset
+    from test_insert import CONFIG, GRAPH, _full_dataset
     from test_lifecycle import _run_lifecycle_schedule
-    from repro.core.batched.sharded import ShardedEngine, build_sharded_index
+    from repro.core.batched.engine import Engine
+    from repro.core.batched.sharded import build_sharded_index
     from repro.launch.mesh import make_local_mesh
 
     ds = _full_dataset()
@@ -213,7 +216,7 @@ LIFECYCLE_SHARDED_SCRIPT = textwrap.dedent("""
     def make(vectors, metadata, vocab, capacity=None):
         sidx = build_sharded_index(vectors, metadata, 4, capacity=capacity,
                                    **GRAPH)
-        return ShardedEngine(sidx, mesh, PARAMS)
+        return Engine(sidx, CONFIG, mesh)
 
     eng = _run_lifecycle_schedule(make, ds)
     assert eng.insert_stats["deleted_rows"] == 120
@@ -240,8 +243,9 @@ def _tiny_engine(vectors, metadata, vocab, capacity=None):
     graph = build_alpha_knn(ds.vectors, k=8, r_max=16)
     atlas = AnchorAtlas.build(ds, seed=0)
     index = FiberIndex(ds.vectors, ds.metadata, graph, atlas)
-    return BatchedEngine(index, BatchedParams(k=5, beam_width=2),
-                         vocab_sizes=vocab, capacity=capacity, graph_k=8)
+    cfg = walk_config(k=5, beam_width=2).with_knobs(
+        {"serve.capacity": capacity, "graph.graph_k": 8})
+    return engine_over(index, cfg, vocab_sizes=vocab)
 
 
 @settings(max_examples=3, deadline=None)
@@ -505,7 +509,7 @@ def test_service_ingest_validation_rejects_live_gids(ds):
         svc.delete([5])  # already dead
     # explicit reuse after the delete is the sanctioned path
     svc.ingest(ds.vectors[5:10], ds.metadata[5:10], gids=np.arange(5, 10))
-    assert _live_gids(_engine_state(svc._live_engine())) == set(
+    assert _live_gids(svc.engine().state) == set(
         range(BASE_N))
     # a re-introduced id occurs twice in the slab until compaction (dead
     # old slot + live row): the second delete must resolve to the LIVE
@@ -514,7 +518,7 @@ def test_service_ingest_validation_rejects_live_gids(ds):
     svc.ingest(ds.vectors[7:8], ds.metadata[7:8], gids=[7])
     assert svc.delete([7]) == 1
     svc.ingest(ds.vectors[7:8], ds.metadata[7:8], gids=[7])
-    assert _live_gids(_engine_state(svc._live_engine())) == set(
+    assert _live_gids(svc.engine().state) == set(
         range(BASE_N))
 
 
@@ -554,7 +558,7 @@ def test_service_delete_compact_and_stats(ds, labeled):
         pass
     assert svc.staleness()["repair_backlog_rows"] == 0
     # recall parity over the live rows vs a from-scratch service
-    st_live = _engine_state(svc._live_engine())
+    st_live = svc.engine().state
     lv, lm, lg = _live_view(st_live)
     ids = _query(svc, labeled)
     rec = _gid_recalls(labeled, ids, lv, lm, lg, tuple(ds.vocab_sizes))
@@ -627,8 +631,8 @@ def test_snapshot_format2_lifecycle_roundtrip(ds, labeled, tmp_path):
     for key in st0:  # the lazily-built sequential index is per-process
         if key != "sequential_index_stale_rows":
             assert st1[key] == st0[key], (key, st0, st1)
-    assert _live_gids(_engine_state(rcv._live_engine())) == \
-        _live_gids(_engine_state(svc._live_engine()))
+    assert _live_gids(rcv.engine().state) == \
+        _live_gids(svc.engine().state)
     for a, b in zip(ids0, _query(rcv, labeled)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     # the restored backlog drains to idle, and a journal replay on top of
@@ -639,8 +643,8 @@ def test_snapshot_format2_lifecycle_roundtrip(ds, labeled, tmp_path):
     rcv2 = RetrievalService.recover(str(tmp_path))
     assert rcv2.staleness()["corpus_rows"] == \
         rcv.staleness()["corpus_rows"]
-    assert _live_gids(_engine_state(rcv2._live_engine())) == \
-        _live_gids(_engine_state(rcv._live_engine()))
+    assert _live_gids(rcv2.engine().state) == \
+        _live_gids(rcv.engine().state)
 
 
 # -- fault injection: SIGKILL at the lifecycle/maintenance points ------------
@@ -706,14 +710,14 @@ def test_sigkill_at_lifecycle_points(ds, labeled, point, expect_live):
     assert "SURVIVED" not in proc.stdout
 
     svc = RetrievalService.recover(root)
-    assert _live_gids(_engine_state(svc._live_engine())) == expect_live
+    assert _live_gids(svc.engine().state) == expect_live
     # a second recovery replays to the identical state
     svc2 = RetrievalService.recover(root)
     assert svc2.staleness() == svc.staleness()
     # recovery + a maintenance drain is the steady state queries see
     while svc.maintenance_step()["kind"] != "idle":
         pass
-    assert _live_gids(_engine_state(svc._live_engine())) == expect_live
+    assert _live_gids(svc.engine().state) == expect_live
 
     ctrl = _mk_life_service(ds, BASE_N, defer=False)
     ctrl.ingest(ds.vectors[BASE_N:BASE_N + CHUNK],
@@ -724,7 +728,7 @@ def test_sigkill_at_lifecycle_points(ds, labeled, point, expect_live):
     ctrl.delete(np.arange(200, 220))
     if point == "lifecycle.post-tombstone":
         ctrl.delete(np.arange(300, 320))
-    lv, lm, lg = _live_view(_engine_state(ctrl._live_engine()))
+    lv, lm, lg = _live_view(ctrl.engine().state)
     assert set(lg.tolist()) == expect_live
     vocab = tuple(ds.vocab_sizes)
     rec_ctrl = _gid_recalls(labeled, _query(ctrl, labeled), lv, lm, lg,
@@ -739,5 +743,5 @@ def test_sigkill_at_lifecycle_points(ds, labeled, point, expect_live):
     svc.compact_now()
     svc.snapshot()
     svc3 = RetrievalService.recover(root)
-    assert len(_live_gids(_engine_state(svc3._live_engine()))) == \
+    assert len(_live_gids(svc3.engine().state)) == \
         len(expect_live) - 1

@@ -24,8 +24,9 @@ SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import sys; sys.path.insert(0, "src")
     import numpy as np, jax
-    from repro.core.batched.engine import BatchedParams
-    from repro.core.batched.sharded import ShardedEngine, build_sharded_index
+    from repro.core.batched.engine import Engine
+    from repro.core.batched.sharded import build_sharded_index
+    from repro.core.config import FnsConfig, WalkConfig
     from repro.data.synth import (make_selectivity_dataset,
                                   make_selectivity_queries)
     from repro.launch.mesh import make_serving_mesh
@@ -38,7 +39,7 @@ SCRIPT = textwrap.dedent("""
     sidx = build_sharded_index(ds.vectors, ds.metadata, 2, graph_k=8,
                                r_max=24)
     mesh = make_serving_mesh(data=2, query=4)
-    eng = ShardedEngine(sidx, mesh, BatchedParams(k=10, beam_width=4))
+    eng = Engine(sidx, FnsConfig(walk=WalkConfig(k=10, beam_width=4)), mesh)
     assert eng.q_axis == "query" and eng.q_lanes == 4, (eng.q_axis,
                                                        eng.q_lanes)
     ids_m, st_m = eng.search(queries)          # 12 queries = 3 per lane
@@ -75,15 +76,16 @@ def test_mesh2d_bit_identity_subprocess():
 def mesh2d_setup(sel_sweep):
     if not MESH2D:
         pytest.skip("needs >= 8 devices (2D-mesh CI job)")
-    from repro.core.batched.engine import BatchedParams
-    from repro.core.batched.sharded import ShardedEngine, build_sharded_index
+    from repro.core.batched.engine import Engine
+    from repro.core.batched.sharded import build_sharded_index
+    from repro.core.config import FnsConfig, WalkConfig
     from repro.launch.mesh import make_serving_mesh
 
     ds, index, queries = sel_sweep
     sidx = build_sharded_index(ds.vectors, ds.metadata, 2, graph_k=16,
                                r_max=48)
     mesh = make_serving_mesh(data=2, query=4)
-    eng = ShardedEngine(sidx, mesh, BatchedParams(k=10, beam_width=4))
+    eng = Engine(sidx, FnsConfig(walk=WalkConfig(k=10, beam_width=4)), mesh)
     return ds, index, queries, eng
 
 
@@ -133,8 +135,9 @@ def test_query_only_mesh_matches_reference():
     bit-identical to its own shard-at-a-time reference too."""
     if not MESH2D:
         pytest.skip("needs >= 8 devices (2D-mesh CI job)")
-    from repro.core.batched.engine import BatchedParams
-    from repro.core.batched.sharded import ShardedEngine, build_sharded_index
+    from repro.core.batched.engine import Engine
+    from repro.core.batched.sharded import build_sharded_index
+    from repro.core.config import FnsConfig, WalkConfig
     from repro.core.types import FilterPredicate, Query, normalize
     from repro.launch.mesh import make_serving_mesh
 
@@ -143,8 +146,8 @@ def test_query_only_mesh_matches_reference():
     vecs = normalize(rng.standard_normal((n, d)))
     meta = rng.integers(0, 5, (n, 3)).astype(np.int32)
     sidx = build_sharded_index(vecs, meta, 1, graph_k=8, r_max=24)
-    eng = ShardedEngine(sidx, make_serving_mesh(data=1, query=4),
-                        BatchedParams(k=5, beam_width=4))
+    eng = Engine(sidx, FnsConfig(walk=WalkConfig(k=5, beam_width=4)),
+                 make_serving_mesh(data=1, query=4))
     assert eng.n_shards == 1 and eng.q_lanes == 4
     queries = [Query(vector=normalize(rng.standard_normal(d)),
                      predicate=FilterPredicate.make({0: [int(i) % 5]}))
@@ -161,7 +164,8 @@ def test_query_parallel_off_keeps_1d_layout():
     the same 2D mesh — the off-switch for the new axis."""
     if not MESH2D:
         pytest.skip("needs >= 8 devices (2D-mesh CI job)")
-    from repro.core.batched.sharded import ShardedEngine, build_sharded_index
+    from repro.core.batched.engine import Engine
+    from repro.core.batched.sharded import build_sharded_index
     from repro.core.config import FnsConfig
     from repro.core.types import FilterPredicate, Query, normalize
     from repro.launch.mesh import make_serving_mesh
@@ -172,8 +176,7 @@ def test_query_parallel_off_keeps_1d_layout():
     cfg = FnsConfig().with_knobs({"walk.k": 5, "graph.graph_k": 8,
                                   "mesh.query_parallel": False})
     sidx = build_sharded_index(vecs, meta, 2, config=cfg)
-    eng = ShardedEngine(sidx, make_serving_mesh(data=2, query=4),
-                        config=cfg)
+    eng = Engine(sidx, cfg, make_serving_mesh(data=2, query=4))
     assert eng.q_axis is None and eng.q_lanes == 1
     q = Query(vector=normalize(rng.standard_normal(8)),
               predicate=FilterPredicate.make({}))
@@ -182,9 +185,10 @@ def test_query_parallel_off_keeps_1d_layout():
 
 
 def test_query_batch_routes_and_buckets_for_lanes():
-    """Serving on a 2D mesh: query_batch routes to the sharded engine and
-    the bucket former rounds the pad target up to a multiple of the lane
-    count, so the engine-level lane pad is a no-op."""
+    """Serving on a 2D mesh: the service's engine takes the mesh's two
+    data shards and four query lanes, and the bucket former rounds the
+    pad target up to a multiple of the lane count, so the engine-level
+    lane pad is a no-op."""
     if not MESH2D:
         pytest.skip("needs >= 8 devices (2D-mesh CI job)")
     from repro.core.search import SearchParams
@@ -200,8 +204,8 @@ def test_query_batch_routes_and_buckets_for_lanes():
     svc = RetrievalService.build(ds, graph_k=8, r_max=24,
                                  params=SearchParams(k=5, max_hops=40),
                                  mesh=make_serving_mesh(data=2, query=4))
-    eng = svc._live_engine()
-    assert svc._sharded is eng and eng.q_lanes == 4
+    eng = svc.engine()
+    assert eng.mesh is not None and eng.n_shards == 2 and eng.q_lanes == 4
     seen = []
     orig = eng.search
     eng.search = lambda qs, **k: seen.append(len(qs)) or orig(qs, **k)

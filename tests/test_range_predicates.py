@@ -19,7 +19,6 @@ import jax.numpy as jnp
 
 from repro.core import AnchorAtlas, FiberIndex, build_alpha_knn
 from repro.core.batched.bitmap import pack_bits
-from repro.core.batched.engine import BatchedEngine, BatchedParams
 from repro.core.device_atlas import pack_dnf
 from repro.core.predicate import (And, In, Interval, Not, Or, Range,
                                   compile_to_dnf, disjunct_selectivity)
@@ -27,6 +26,8 @@ from repro.core.types import Query
 from repro.data.ground_truth import attach_ground_truth, recall_at_k
 from repro.data.synth import (add_timestamp_field, make_range_queries,
                               make_selectivity_dataset)
+
+from conftest import engine_over, walk_config
 
 BIG = 100_000           # per-field domain the value-set path can't afford
 F = 3
@@ -183,8 +184,8 @@ def range_sweep():
 @pytest.fixture(scope="module")
 def range_engine(range_sweep):
     ds, index, _ = range_sweep
-    return BatchedEngine(index, BatchedParams(k=10, beam_width=4),
-                         vocab_sizes=ds.vocab_sizes)
+    return engine_over(index, walk_config(k=10, beam_width=4),
+                       vocab_sizes=ds.vocab_sizes)
 
 
 def test_range_batch_packs_interval_tables(range_sweep, range_engine):
@@ -207,8 +208,8 @@ def test_range_pass_bitmaps_match_tree_oracle_bitexact(range_sweep,
                                                        range_engine):
     ds, _, queries = range_sweep
     _, fields, allowed, bounds = range_engine._pack_queries(queries)
-    got = np.asarray(range_engine._passes(range_engine.metadata, fields,
-                                          allowed, bounds))
+    got = np.asarray(range_engine._passes(range_engine.shards[0].metadata,
+                                          fields, allowed, bounds))
     want = np.asarray(pack_bits(jnp.asarray(np.stack(
         [q.predicate.mask(ds.metadata, ds.vocab_sizes) for q in queries]))))
     np.testing.assert_array_equal(got, want)
@@ -286,7 +287,7 @@ def test_atlas_interval_cluster_match_is_conservative(range_sweep,
     host scan (never misses a candidate cluster), for every range query."""
     ds, index, queries = range_sweep
     from repro.core.predicate import as_dnf
-    datlas = range_engine.datlas
+    datlas = range_engine.shards[0].datlas
     for q in queries[::6]:
         dnf = as_dnf(q.predicate, ds.vocab_sizes, v_cap=datlas.v_cap)
         host = set(index.atlas.matching_clusters(dnf).tolist())
@@ -301,8 +302,9 @@ SHARDED_SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import sys; sys.path.insert(0, "src")
     import numpy as np, jax
-    from repro.core.batched.engine import BatchedParams
-    from repro.core.batched.sharded import ShardedEngine, build_sharded_index
+    from repro.core.batched.engine import Engine
+    from repro.core.batched.sharded import build_sharded_index
+    from repro.core.config import FnsConfig, WalkConfig
     from repro.core.predicate import Range
     from repro.core.types import Query
     from repro.data.synth import (add_timestamp_field, make_range_queries,
@@ -323,7 +325,7 @@ SHARDED_SCRIPT = textwrap.dedent("""
     sidx = build_sharded_index(ds.vectors, ds.metadata, 4, graph_k=8,
                                r_max=24)
     mesh = make_local_mesh(data=4, model=1)
-    eng = ShardedEngine(sidx, mesh, BatchedParams(k=10, beam_width=4))
+    eng = Engine(sidx, FnsConfig(walk=WalkConfig(k=10, beam_width=4)), mesh)
     ids_m, st_m = eng.search(queries)
     assert eng.dispatches == 1, eng.dispatches
     ids_r, st_r = eng.search_reference(queries)

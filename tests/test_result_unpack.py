@@ -11,8 +11,9 @@ import numpy as np
 import pytest
 
 from repro.core.batched import engine
-from repro.core.batched.engine import (INF, BatchedEngine, BatchedParams,
-                                       fetch_results)
+from repro.core.batched.engine import INF, fetch_results
+
+from conftest import engine_over, walk_config
 
 K = 6
 
@@ -136,7 +137,7 @@ def test_search_hostloop_unpacks_through_the_host_helper(
             return helper(*a, **k)
 
     monkeypatch.setattr(engine, "_found_ids", guarded)
-    eng = BatchedEngine(small_index, BatchedParams(k=10, beam_width=4))
+    eng = engine_over(small_index, walk_config(k=10, beam_width=4))
     queries = small_queries[:8]
     ids_h, _ = eng.search_hostloop(queries)
     ids_f, _ = eng.search(queries)

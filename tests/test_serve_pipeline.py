@@ -211,23 +211,23 @@ def test_stats_slice_only_per_query_axes_batched_route(pipe_svc):
 
 
 def test_stats_slice_only_per_query_axes_sharded_reference_route():
-    """Same contract through the OTHER engine route: a reference-mode
-    ShardedEngine (multi-shard state, no mesh) attached to the service."""
-    from repro.core.batched.sharded import (ShardedEngine,
-                                            build_sharded_index)
+    """Same contract through the shard-at-a-time program: an engine over
+    a 2-shard index without a mesh, attached to the service."""
+    from repro.core.batched.engine import Engine
+    from repro.core.batched.sharded import build_sharded_index
 
     _, ds = _corpus(seed=8)
     cfg = FnsConfig().with_knobs(_PIPE_KNOBS)
     sidx = build_sharded_index(ds.vectors, ds.metadata, 2, config=cfg)
-    eng = ShardedEngine(sidx, None, config=cfg)
+    eng = Engine(sidx, cfg)
     svc = RetrievalService(None, SearchParams(k=5, max_hops=40),
-                           config=cfg, _ds=ds, _sharded=eng)
+                           config=cfg, _ds=ds, _engine=eng)
     rng = np.random.default_rng(9)
     d0 = eng.dispatches
     ids, stats = svc.query_batch(
         rng.standard_normal((3, 16)),
         [FilterPredicate.make({0: [i]}) for i in range(3)])
-    assert eng.dispatches - d0 == eng.n_shards  # reference mode: per shard
+    assert eng.dispatches - d0 == eng.n_shards  # one per shard
     assert len(ids) == 3
     assert stats["walks"].shape == (3,)
     assert stats["hops"].shape == (3,)
@@ -247,7 +247,7 @@ def test_publish_generation_fence_interleaved_delete():
     pred = [FilterPredicate.make({0: [3]})]
     ids0, _ = svc.query_batch(vec, pred)
     target = int(np.asarray(ids0[0])[0])
-    eng = svc._live_engine()
+    eng = svc.engine()
     gen0 = eng.publish_generation
 
     def publish_mid_window():
@@ -275,7 +275,7 @@ def test_maintenance_step_reports_publish_generation():
     vecs = normalize(rng.standard_normal((8, 16)))
     meta = rng.integers(0, 5, (8, 4)).astype(np.int32)
     svc.ingest(vecs, meta)
-    eng = svc._live_engine()
+    eng = svc.engine()
     out = svc.maintenance_step()
     assert out["kind"] == "repair"
     assert out["generation"] == eng.publish_generation

@@ -17,6 +17,8 @@ import pytest
 
 import jax
 
+from conftest import engine_over, walk_config
+
 MULTI = len(jax.devices()) >= 4
 
 SCRIPT = textwrap.dedent("""
@@ -24,8 +26,9 @@ SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import sys; sys.path.insert(0, "src")
     import numpy as np, jax
-    from repro.core.batched.engine import BatchedParams
-    from repro.core.batched.sharded import ShardedEngine, build_sharded_index
+    from repro.core.batched.engine import Engine
+    from repro.core.batched.sharded import build_sharded_index
+    from repro.core.config import FnsConfig, WalkConfig
     from repro.data.synth import (make_selectivity_dataset,
                                   make_selectivity_queries)
     from repro.launch.mesh import make_local_mesh
@@ -38,7 +41,7 @@ SCRIPT = textwrap.dedent("""
     sidx = build_sharded_index(ds.vectors, ds.metadata, 4, graph_k=8,
                                r_max=24)
     mesh = make_local_mesh(data=4, model=1)
-    eng = ShardedEngine(sidx, mesh, BatchedParams(k=10, beam_width=4))
+    eng = Engine(sidx, FnsConfig(walk=WalkConfig(k=10, beam_width=4)), mesh)
     ids_m, st_m = eng.search(queries)
     assert eng.dispatches == 1, eng.dispatches
     ids_r, st_r = eng.search_reference(queries)
@@ -94,8 +97,9 @@ def test_tiny_corpus_many_shards_exact():
     is exhaustively seeded, the merged result IS the exact top-k."""
     if not MULTI:
         pytest.skip("needs >= 4 devices (multi-device CI job)")
-    from repro.core.batched.engine import BatchedParams
-    from repro.core.batched.sharded import ShardedEngine, build_sharded_index
+    from repro.core.batched.engine import Engine
+    from repro.core.batched.sharded import build_sharded_index
+    from repro.core.config import FnsConfig, WalkConfig
     from repro.core.types import FilterPredicate, Query, normalize
     from repro.launch.mesh import make_local_mesh
 
@@ -103,8 +107,8 @@ def test_tiny_corpus_many_shards_exact():
     vecs = normalize(rng.standard_normal((10, 8)))
     meta = rng.integers(0, 3, (10, 2)).astype(np.int32)
     sidx = build_sharded_index(vecs, meta, 4, graph_k=4, r_max=8)
-    eng = ShardedEngine(sidx, make_local_mesh(data=4, model=1),
-                        BatchedParams(k=3, beam_width=2))
+    eng = Engine(sidx, FnsConfig(walk=WalkConfig(k=3, beam_width=2)),
+                 make_local_mesh(data=4, model=1))
     q = Query(vector=normalize(rng.standard_normal(8)).astype(np.float32),
               predicate=FilterPredicate.make({}))
     ids, _ = eng.search([q])
@@ -116,15 +120,16 @@ def test_tiny_corpus_many_shards_exact():
 def sharded_setup(sel_sweep):
     if not MULTI:
         pytest.skip("needs >= 4 devices (multi-device CI job)")
-    from repro.core.batched.engine import BatchedParams
-    from repro.core.batched.sharded import ShardedEngine, build_sharded_index
+    from repro.core.batched.engine import Engine
+    from repro.core.batched.sharded import build_sharded_index
+    from repro.core.config import FnsConfig, WalkConfig
     from repro.launch.mesh import make_local_mesh
 
     ds, index, queries = sel_sweep
     sidx = build_sharded_index(ds.vectors, ds.metadata, 4, graph_k=16,
                                r_max=48)
     mesh = make_local_mesh(data=4, model=1)
-    eng = ShardedEngine(sidx, mesh, BatchedParams(k=10, beam_width=4))
+    eng = Engine(sidx, FnsConfig(walk=WalkConfig(k=10, beam_width=4)), mesh)
     return ds, index, queries, eng
 
 
@@ -172,12 +177,11 @@ def test_sharded_recall_parity_and_filters(sharded_setup):
     corpus: per-shard restarts may find different (not worse) neighbours,
     so compare recall, and check the hard invariants exactly — results
     pass their filters, ids unique, ids globally valid."""
-    from repro.core.batched.engine import BatchedEngine, BatchedParams
     from repro.data.ground_truth import recall_at_k
 
     ds, index, queries, eng = sharded_setup
     ids_s, _ = eng.search(queries)
-    geng = BatchedEngine(index, BatchedParams(k=10, beam_width=4))
+    geng = engine_over(index, walk_config(k=10, beam_width=4))
     ids_g, _ = geng.search(queries)
     rec_s = np.mean([recall_at_k(np.asarray(i), q.gt_ids)
                      for i, q in zip(ids_s, queries)])
@@ -195,9 +199,9 @@ def test_sharded_recall_parity_and_filters(sharded_setup):
 
 def test_query_batch_routes_to_sharded_engine():
     """Serving path: a RetrievalService built with a mesh whose data axis
-    spans >1 device must answer query_batch through the sharded engine
-    (the single-device engine is never built), with filter-valid
-    results."""
+    spans >1 device must answer query_batch through an engine over that
+    many shards, running the mesh program (the global graph and atlas are
+    never built), with filter-valid results."""
     if not MULTI:
         pytest.skip("needs >= 4 devices (multi-device CI job)")
     from repro.core.search import SearchParams
@@ -217,9 +221,10 @@ def test_query_batch_routes_to_sharded_engine():
              FilterPredicate.make({1: [2, 3]}),
              FilterPredicate.make({})]
     ids, stats = svc.query_batch(rng.standard_normal((3, d)), preds)
-    assert svc._sharded is not None and svc._engine is None
+    eng = svc.engine()
+    assert eng.n_shards == 4 and eng.stacked is not None and not eng.shards
     assert svc.index is None  # the global graph/atlas were never built
-    assert svc._sharded.dispatches == 1
+    assert eng.dispatches == 1
     assert stats["walks"].shape == (3,)
     for pred, row in zip(preds, ids):
         row = np.asarray(row)
@@ -234,7 +239,7 @@ def test_sharded_global_ids_cover_all_shards(sharded_setup):
     ds, _, queries, eng = sharded_setup
     broad = [q for q in queries if q.selectivity > 0.3]
     ids, _ = eng.search(broad)
-    gids = np.asarray(eng.global_ids)  # (S, m), -1 pads
+    gids = np.asarray(eng.stacked.global_ids)  # (S, m), -1 pads
     got = np.unique(np.concatenate([np.asarray(r) for r in ids]))
     shards = {s for s in range(gids.shape[0])
               if np.isin(got, gids[s]).any()}

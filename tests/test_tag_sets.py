@@ -280,13 +280,13 @@ def test_atlas_presence_is_the_or_of_row_masks(tagged):
     slab = RetrievalService.build(
         ds, config=FnsConfig().with_knobs(
             {**KNOBS, "serve.capacity": ds.n + 32})).engine()
-    np.testing.assert_array_equal(np.asarray(slab.datlas.presence[TAG]),
+    datlas = slab.shards[0].datlas
+    np.testing.assert_array_equal(np.asarray(datlas.presence[TAG]),
                                   np.asarray(dev.presence[TAG]))
     np.testing.assert_array_equal(
-        np.asarray(emit_device_atlas(slab.state.shards[0],
-                                     slab.datlas.v_cap,
+        np.asarray(emit_device_atlas(slab.state.shards[0], datlas.v_cap,
                                      ds.tag_fields).presence),
-        np.asarray(slab.datlas.presence))
+        np.asarray(datlas.presence))
 
 
 # -- ingest and snapshots ----------------------------------------------------
@@ -327,7 +327,7 @@ def test_snapshot_round_trip_keeps_the_tag_schema(tmp_path):
              for j in range(8)]
     ids0, _ = svc.query_batch(ds.vectors[:8], preds)
     svc2 = RetrievalService.recover(str(tmp_path))
-    eng = svc2._live_engine()
+    eng = svc2.engine()
     assert eng.tag_fields == ((TAG, V),)
     assert eng.state.tag_fields == ((TAG, V),)
     ids1, _ = svc2.query_batch(ds.vectors[:8], preds)
@@ -423,7 +423,8 @@ SHARDED = textwrap.dedent("""
     import sys; sys.path[:0] = ["src", "tests"]
     import numpy as np
     import test_tag_sets as T
-    from repro.core.batched.sharded import ShardedEngine, build_sharded_index
+    from repro.core.batched.engine import Engine
+    from repro.core.batched.sharded import build_sharded_index
     from repro.core.config import FnsConfig
     from repro.core.types import Query
     from repro.launch.mesh import make_local_mesh
@@ -432,7 +433,7 @@ SHARDED = textwrap.dedent("""
     cfg = FnsConfig().with_knobs(T.KNOBS)
     sidx = build_sharded_index(ds.vectors, ds.metadata, 4, config=cfg,
                                tag_fields=ds.tag_fields)
-    eng = ShardedEngine(sidx, make_local_mesh(data=4, model=1), config=cfg)
+    eng = Engine(sidx, cfg, make_local_mesh(data=4, model=1))
     rng = np.random.default_rng(8)
     preds = [T.HasAny(T.TAG, rng.choice(T.V, 4, replace=False))
              & T._window(int(rng.integers(0, 600)), 300) for _ in range(8)]
@@ -480,7 +481,7 @@ def test_value_bitmaps_hold_every_label_of_the_vocabulary(capacity):
                  [v] + [0] * (words.shape[1] - 1) + [5], tag_fields={0: v})
     cfg = FnsConfig().with_knobs({**KNOBS, "serve.capacity": capacity})
     svc = RetrievalService.build(ds, config=cfg)
-    assert svc.engine().datlas.v_cap >= v
+    assert svc.engine().v_cap >= v
     pred = HasAny(0, [550])
     ids, stats = svc.query_batch(ds.vectors[:2], [pred, HasAny(0, [5])])
     assert "errors" not in stats and len(ids[0]) == 0 and len(ids[1])

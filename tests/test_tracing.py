@@ -117,10 +117,11 @@ def test_search_program_carries_the_device_scopes(service):
     qs = [Query(vector=v, predicate=p)
           for v, p in zip(normalize(queries), preds)]
     q_vecs, fields, allowed, bounds = pack_query_batch(
-        qs, v_cap=eng.datlas.v_cap, vocab_sizes=eng.vocab_sizes)
-    hlo = eng._search.lower(eng.datlas, eng.vectors, eng.adjacency,
-                            eng.metadata, q_vecs, fields, allowed,
-                            valid_bm=eng._valid_bm,
+        qs, v_cap=eng.v_cap, vocab_sizes=eng.vocab_sizes)
+    ix = eng.shards[0]
+    hlo = eng._search.lower(ix.datlas, ix.vectors, ix.adjacency,
+                            ix.metadata, q_vecs, fields, allowed,
+                            valid_bm=ix.valid_bm,
                             bounds=bounds).compile().as_text()
     assert hlo.startswith("HloModule jit_search_batch")
     names = _op_names(hlo)

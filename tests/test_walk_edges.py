@@ -6,8 +6,9 @@ host-loop baseline in exact agreement inside one mixed batch.
 import numpy as np
 import pytest
 
-from repro.core.batched.engine import BatchedEngine, BatchedParams
 from repro.core.types import FilterPredicate, Query, normalize
+
+from conftest import engine_over, walk_config
 
 
 def _pred_with_count(meta: np.ndarray, lo: int, hi: int):
@@ -52,7 +53,7 @@ def test_edge_lanes_fused_vs_hostloop(small_ds, small_index):
     degenerate lanes idle)."""
     queries, few_c = _edge_queries(small_ds)
     k = 10
-    eng = BatchedEngine(small_index, BatchedParams(k=k, beam_width=4))
+    eng = engine_over(small_index, walk_config(k=k, beam_width=4))
     ids_f, st_f = eng.search(queries)
     ids_h, st_h = eng.search_hostloop(queries)
     for i, (a, b) in enumerate(zip(ids_f, ids_h)):
@@ -83,7 +84,7 @@ def test_all_lanes_degenerate_batch(small_ds, small_index):
     queries = [Query(vector=normalize(rng.standard_normal(small_ds.d))
                      .astype(np.float32), predicate=nomatch)
                for _ in range(4)]
-    eng = BatchedEngine(small_index, BatchedParams(k=5, beam_width=4))
+    eng = engine_over(small_index, walk_config(k=5, beam_width=4))
     ids_f, st_f = eng.search(queries)
     ids_h, st_h = eng.search_hostloop(queries)
     for a, b in zip(ids_f, ids_h):

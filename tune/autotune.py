@@ -41,10 +41,10 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from benchmarks.search_bench import (SELECTIVITIES, BatchedEngine,  # noqa: E402
+from benchmarks.search_bench import (SELECTIVITIES,  # noqa: E402
                                      bench_config, build_search_fixture,
-                                     device_info, make_query_pools,
-                                     measure_batch)
+                                     device_info, engine_over,
+                                     make_query_pools, measure_batch)
 
 # the searched subspace: every axis is a walk.* knob (runtime-tunable by
 # definition — see core/config.py SHAPE_BAKED), with a small monotone
@@ -67,7 +67,7 @@ FINAL_REPS = 20    # winner + baseline re-measurement (reporting)
 
 def measure_config(cfg, index, pools, q_n: int, reps: int) -> dict:
     """Per-selectivity rows for one config on the shared fixture."""
-    eng = BatchedEngine(index, config=cfg)
+    eng = engine_over(index, cfg)
     return {sel: measure_batch(eng, pools[sel][:q_n], reps)
             for sel in pools}
 
