@@ -34,14 +34,14 @@ def kernel_microbench():
     n, d, Q, R = 8192, 256, 32, 128
     corpus = jnp.asarray(rng.standard_normal((n, d)), jnp.float32)
     queries = jnp.asarray(rng.standard_normal((Q, d)), jnp.float32)
-    bitmap = jnp.asarray(rng.integers(0, 2**32, (Q, n // 32)), jnp.uint32)
     ids = jnp.asarray(rng.integers(0, n, (Q, R)), jnp.int32)
+    bits = jnp.asarray(rng.integers(0, 2, (Q, R)), bool)
     meta = jnp.asarray(rng.integers(-1, 40, (n, 24)), jnp.int32)
     fields = jnp.asarray([0, 5, -1, -1], jnp.int32)
     allowed = jnp.asarray(rng.integers(0, 2, (4, 256)), jnp.uint8)
     out = {}
     f2 = jax.jit(ref.fiber_expand_walk)
-    out["fiber_expand_walk"] = _time(f2, queries, corpus, ids, bitmap)
+    out["fiber_expand_walk"] = _time(f2, queries, corpus, ids, bits)
     f3 = jax.jit(ref.filter_eval)
     out["filter_eval_ref"] = _time(f3, meta, fields, allowed)
     return out

@@ -60,14 +60,14 @@ def kernel_oracle_parity(n: int = 800, d: int = 64) -> list[str]:
         if not ok:
             fails.append(f"{name} (n={n}, d={d}): kernel != oracle")
 
-    sims, sims_p = ref.fiber_expand_walk(
-        jnp.asarray(queries_np), jnp.asarray(corpus_np),
-        jnp.asarray(ids_np), jnp.asarray(bitmap_np))
     safe = np.maximum(ids_np, 0)
-    want = np.einsum("qrd,qd->qr", corpus_np[safe].astype(np.float64),
-                     queries_np.astype(np.float64))
     bits = (np.take_along_axis(bitmap_np, safe >> 5, axis=1)
             >> (safe & 31).astype(np.uint32)) & 1
+    sims, sims_p = ref.fiber_expand_walk(
+        jnp.asarray(queries_np), jnp.asarray(corpus_np),
+        jnp.asarray(ids_np), jnp.asarray(bits == 1))
+    want = np.einsum("qrd,qd->qr", corpus_np[safe].astype(np.float64),
+                     queries_np.astype(np.float64))
     _chk("fiber_expand_walk/sims", sims,
          np.where(ids_np >= 0, want, -np.inf))
     _chk("fiber_expand_walk/sims_pass", sims_p,

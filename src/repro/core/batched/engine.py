@@ -1,10 +1,11 @@
 """Batched TPU-native drift-guided search (beyond-paper engine).
 
 Runs Q queries in lockstep as one ``lax.while_loop``: all walk state is
-fixed-shape (packed uint32 visited/in-results/pass bitmaps, V-sorted
-fixed-capacity frontier/beam queues, running top-k results), one iteration
-expands one node per active query, and every expansion distance comes from
-one neighbour gather + full-f32 dot that also probes the packed pass bitmap
+fixed-shape (a packed uint32 state map of 2 bits a node, visited and
+filter-pass, V-sorted fixed-capacity frontier/beam queues, running top-k
+results), one iteration expands one node per active query, each
+neighbour's visited and pass bits come from one word gather of the map,
+and every expansion distance from one neighbour gather + full-f32 dot
 (``ref.fiber_expand_walk``, the same XLA program on every platform).
 
 A whole filtered search batch is ONE device dispatch (``search_batch``):
@@ -43,8 +44,10 @@ from jax.profiler import TraceAnnotation
 from jax.sharding import PartitionSpec as P
 
 from repro import faults
-from repro.core.batched.bitmap import (n_words, pack_bits, popcount,
-                                       set_bits, test_bits, unpack_bits)
+from repro.core.batched.bitmap import (PASS, STATE_STRIDE, VISITED,
+                                       bit_of, gather_words, pack_bits,
+                                       popcount, set_bits, state_map,
+                                       unpack_bits, visited_bits)
 from repro.core.batched.insert import emit_device_atlas
 from repro.core.batched.lifecycle import pad_state
 from repro.core.batched.scopes import dispatch_program
@@ -142,6 +145,14 @@ def _running_first(term, width: int):
     return jax.lax.top_k(key, width)[1]
 
 
+def _banked(ids, res0_i):
+    """``ids (Q, m)`` -> ``(Q, m) bool``: which ids a prior round already
+    banked among the lane's carried results ``res0_i (Q, k)`` (-1 pad;
+    an exact compare, no gather)."""
+    return ((ids[:, :, None] == res0_i[:, None, :])
+            & (res0_i >= 0)[:, None, :]).any(-1)
+
+
 def walk_batch(vectors, adjacency, pass_bm, q_vecs, seeds,
                p: WalkConfig, init_results=None):
     """One lockstep walk round.
@@ -149,11 +160,15 @@ def walk_batch(vectors, adjacency, pass_bm, q_vecs, seeds,
     vectors (n, d) f32; adjacency (n, R) i32 (-1 pad); pass_bm
     (Q, ceil(n/32)) uint32 packed filter bitmaps; q_vecs (Q, d); seeds
     (Q, S) i32 (-1 pad). Returns dict of results + diagnostics, among
-    them ``iters``, the lockstep iterations the loop ran, and ``slots``,
-    the lanes those iterations computed (Σ of each iteration's width).
-    All per-point walk state (visited / in-results / pass) is
-    bitmap-packed: O(Q*n/32) bytes instead of three dense (Q, n) bool
-    masks. Each iteration's ops sit under the device scope ``walk_hop``.
+    them ``iters``, the lockstep iterations the loop ran, ``slots``,
+    the lanes those iterations computed (Σ of each iteration's width), and
+    ``visited_bm``, the packed (Q, ceil(n/32)) visited set.
+    The per-point walk state is one packed state map (``state_map``,
+    (Q, ceil(n/16)) uint32: a visited and a pass bit a node), built from
+    ``pass_bm`` once a round, so a hop reads both bits of a neighbour with
+    one word gather. A node the carried ``init_results`` already hold is
+    found by an exact compare against their ids (``_banked``). Each
+    iteration's ops sit under the device scope ``walk_hop``.
 
     The walk narrows in stages (``stage_widths``): a stage at width W
     runs while more than the next stage's width of its lanes are running;
@@ -175,8 +190,9 @@ def walk_batch(vectors, adjacency, pass_bm, q_vecs, seeds,
                            precision=HIGHEST)
     seed_v = jnp.where(seed_valid, 1.0 - seed_sims, INF)
 
-    visited = set_bits(jnp.zeros((Q, n_words(n)), jnp.uint32),
-                       seeds, seed_valid)
+    sm0 = state_map(pass_bm, n)
+    seed_words = gather_words(sm0, seeds, STATE_STRIDE)
+    sm = set_bits(sm0, seeds, seed_valid, STATE_STRIDE, seed_words)
 
     frontier_v, frontier_i = _merge_queue(
         jnp.full((Q, F), INF), jnp.full((Q, F), -1, jnp.int32),
@@ -191,18 +207,16 @@ def walk_batch(vectors, adjacency, pass_bm, q_vecs, seeds,
     if init_results is None:
         res0_v = jnp.full((Q, k), INF)
         res0_i = jnp.full((Q, k), -1, jnp.int32)
-        in_res = jnp.zeros((Q, n_words(n)), jnp.uint32)
     else:
         res0_v, res0_i = init_results
-        in_res = set_bits(jnp.zeros((Q, n_words(n)), jnp.uint32),
-                          res0_i, res0_i >= 0)
 
-    seed_pass = test_bits(pass_bm, seeds) & ~test_bits(in_res, seeds)
+    seed_pass = (bit_of(seed_words, seeds, STATE_STRIDE, PASS)
+                 & ~_banked(seeds, res0_i))
     res_v, res_i = _merge_queue(res0_v, res0_i,
                                 jnp.where(seed_pass, seed_v, INF), seeds, k)
 
     lanes = dict(
-        visited=visited, frontier_v=frontier_v, frontier_i=frontier_i,
+        sm=sm, frontier_v=frontier_v, frontier_i=frontier_i,
         beam_v=beam_v, beam_i=beam_i, res_v=res_v, res_i=res_i,
         phase=jnp.ones((Q,), jnp.int32), stall=jnp.zeros((Q,), jnp.int32),
         # an unseeded lane would converge at its first check, untouched
@@ -211,10 +225,10 @@ def walk_batch(vectors, adjacency, pass_bm, q_vecs, seeds,
         hops=jnp.zeros((Q,), jnp.int32), p1_hops=jnp.zeros((Q,), jnp.int32),
     )
     # the per-lane constants the hop reads, gathered with the lanes
-    consts = dict(q_vecs=q_vecs, pass_bm=pass_bm, in_res=in_res)
+    consts = dict(q_vecs=q_vecs, res0_i=res0_i)
 
     def hop(s, c):
-        q_vecs, pass_bm, in_res = c["q_vecs"], c["pass_bm"], c["in_res"]
+        q_vecs, res0_i = c["q_vecs"], c["res0_i"]
         active = s["term"] == TERM_RUNNING
         phase = s["phase"]
         f_empty = s["frontier_v"][:, 0] >= INF / 2
@@ -246,19 +260,21 @@ def walk_batch(vectors, adjacency, pass_bm, q_vecs, seeds,
         nbrs = adjacency[xs]                                    # (Q, R)
         sn = jnp.maximum(nbrs, 0)
         nvalid = (nbrs >= 0) & live[:, None]
-        seen = test_bits(s["visited"], sn)
+        # the one probe: each neighbour's visited and pass bits
+        words = gather_words(s["sm"], sn, STATE_STRIDE)
+        seen = bit_of(words, sn, STATE_STRIDE, VISITED)
         new = nvalid & ~seen
-        visited = set_bits(s["visited"], sn, new)
+        sm = set_bits(s["sm"], sn, new, STATE_STRIDE, words)
         # one gather+dot yields both traversal distances and pass-masked
-        # candidates (the kernel probes the pass bitmap in the same pass)
-        sims, sims_p = ref.fiber_expand_walk(q_vecs, vectors, nbrs, pass_bm)
+        # candidates
+        sims, sims_p = ref.fiber_expand_walk(
+            q_vecs, vectors, nbrs, bit_of(words, nbrs, STATE_STRIDE, PASS))
         v_n = 1.0 - sims
         pass_r = jnp.isfinite(sims_p) & live[:, None]
         # results: merge new filtered, minus nodes a prior round already
-        # banked (in_res is static within the round: nodes merged this
-        # round are first-seen, so `new` already excludes them)
-        in_res_r = test_bits(in_res, sn)
-        cand_v = jnp.where(new & pass_r & ~in_res_r, v_n, INF)
+        # banked (the carried ids are static within the round: nodes
+        # merged this round are first-seen, so `new` already excludes them)
+        cand_v = jnp.where(new & pass_r & ~_banked(nbrs, res0_i), v_n, INF)
         res_v, res_i = _merge_queue(s["res_v"], s["res_i"], cand_v, nbrs, k)
         # local signals
         n_pass = pass_r.sum(1)
@@ -312,7 +328,7 @@ def walk_batch(vectors, adjacency, pass_bm, q_vecs, seeds,
         new_phase = jnp.where(reenter, 1, new_phase)
         hops = s["hops"] + live.astype(jnp.int32)
         p1_hops = s["p1_hops"] + (live & (phase == 1)).astype(jnp.int32)
-        return dict(visited=visited, frontier_v=frontier_v,
+        return dict(sm=sm, frontier_v=frontier_v,
                     frontier_i=frontier_i, beam_v=beam_v, beam_i=beam_i,
                     res_v=res_v, res_i=res_i, phase=new_phase, stall=stall,
                     term=term, hops=hops, p1_hops=p1_hops)
@@ -347,7 +363,7 @@ def walk_batch(vectors, adjacency, pass_bm, q_vecs, seeds,
     term = jnp.where(out["term"] == TERM_RUNNING, TERM_MAXHOP, out["term"])
     return dict(res_v=out["res_v"], res_i=out["res_i"], term=term,
                 hops=out["hops"], p1_hops=out["p1_hops"],
-                visited_bm=out["visited"], iters=t, slots=slots)
+                visited_bm=visited_bits(out["sm"], n), iters=t, slots=slots)
 
 
 def atlas_round(datlas: DeviceAtlas, vectors, adjacency, pass_bm, passes,

@@ -6,7 +6,8 @@ Semantics contract:
   gather + full-f32 dot on every platform): sims[q, r] = q_vec[q] .
   X[ids[q, r]], TWO outputs per (q, r) — sims masked only by id validity
   (the walk's traversal distances) and sims additionally masked by the
-  filter bit (the result-queue candidates).
+  id's filter bit, which the walk's state-map probe hands in (the
+  result-queue candidates).
 * filter_eval: packed uint32 bitmap of conjunctive predicate over int codes;
   code -1 (unpopulated) fails any clause on that field.
 * filter_eval_batch: filter_eval for Q queries at once, consuming the
@@ -36,17 +37,15 @@ def bitmap_get(bitmap: jax.Array, idx: jax.Array) -> jax.Array:
 
 
 def fiber_expand_walk(q_vecs: jax.Array, corpus: jax.Array, ids: jax.Array,
-                      bitmap: jax.Array):
-    """q_vecs (Q, d); corpus (n, d); ids (Q, R) i32 (-1 pad);
-    bitmap (Q, n_words) uint32. Returns (sims, sims_pass), both (Q, R) f32:
+                      bits: jax.Array):
+    """q_vecs (Q, d); corpus (n, d); ids (Q, R) i32 (-1 pad); bits (Q, R)
+    bool, each id's filter bit. Returns (sims, sims_pass), both (Q, R) f32:
     ``sims`` is -inf only for padded ids, ``sims_pass`` additionally -inf
     where the id's filter bit is 0."""
     safe = jnp.maximum(ids, 0)
     rows = corpus[safe].astype(jnp.float32)            # (Q, R, d)
     sims = jnp.einsum("qrd,qd->qr", rows, q_vecs.astype(jnp.float32),
                       precision=jax.lax.Precision.HIGHEST)
-    words = jnp.take_along_axis(bitmap, (safe >> 5).astype(jnp.int32), axis=1)
-    bits = ((words >> (safe & 31).astype(jnp.uint32)) & 1).astype(bool)
     valid = ids >= 0
     return jnp.where(valid, sims, NEG), jnp.where(valid & bits, sims, NEG)
 

@@ -8,6 +8,8 @@ permutations, composite, given, settings, example.
 """
 from __future__ import annotations
 
+import inspect
+
 import numpy as np
 
 
@@ -76,10 +78,17 @@ def example(**kwargs):
 
 
 def given(*strats):
+    """As hypothesis does, the strategies fill the test's last arguments;
+    any before them (``pytest.mark.parametrize``'s) stay the wrapper's own
+    and are passed through."""
     def deco(fn):
-        def run():
+        params = list(inspect.signature(fn).parameters.values())
+        lead, drawn = params[:len(params) - len(strats)], [
+            p.name for p in params[len(params) - len(strats):]]
+
+        def run(**lead_kwargs):
             for kwargs in getattr(fn, "_fallback_examples", []):
-                fn(**kwargs)
+                fn(**lead_kwargs, **kwargs)
             # read at call time: ``@settings`` is conventionally stacked
             # ABOVE ``@given``, so it decorates (and tags) the wrapper
             # after this closure is built
@@ -87,9 +96,11 @@ def given(*strats):
                         getattr(fn, "_fallback_max_examples", 20))
             for i in range(n):
                 rng = np.random.default_rng(i)
-                fn(*[s.example(rng) for s in strats])
+                fn(**lead_kwargs,
+                   **dict(zip(drawn, [s.example(rng) for s in strats])))
 
         run.__name__ = fn.__name__
         run.__doc__ = fn.__doc__
+        run.__signature__ = inspect.Signature(lead)
         return run
     return deco

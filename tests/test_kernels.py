@@ -57,18 +57,19 @@ def test_filter_eval_vs_ref_oracle():
 def test_fiber_expand_walk_sweep(n, d, Q, R):
     """The walk's expansion op: its first output is the plain gather+dot
     masked by id validity only, its second additionally by the id's pass
-    bit (checked against a float64 numpy gather+dot)."""
+    bit, as the walk's probe hands it in (checked against a float64 numpy
+    gather+dot)."""
     corpus, queries, bitmap = _mk(n, d, Q, seed=R + 1)
     rng = np.random.default_rng(R + 1)
     ids = rng.integers(-1, n, (Q, R)).astype(np.int32)
-    s_r, p_r = ref.fiber_expand_walk(jnp.asarray(queries), jnp.asarray(corpus),
-                                     jnp.asarray(ids), jnp.asarray(bitmap))
     safe = np.maximum(ids, 0)
+    bits = (np.take_along_axis(bitmap, safe >> 5, axis=1)
+            >> (safe & 31).astype(np.uint32)) & 1
+    s_r, p_r = ref.fiber_expand_walk(jnp.asarray(queries), jnp.asarray(corpus),
+                                     jnp.asarray(ids), jnp.asarray(bits == 1))
     want = np.einsum("qrd,qd->qr", corpus[safe].astype(np.float64),
                      queries.astype(np.float64))
     valid = ids >= 0
-    bits = (np.take_along_axis(bitmap, safe >> 5, axis=1)
-            >> (safe & 31).astype(np.uint32)) & 1
     np.testing.assert_allclose(np.asarray(s_r),
                                np.where(valid, want, -np.inf),
                                rtol=1e-5, atol=1e-5)
